@@ -14,6 +14,26 @@ Top-level namespace parity with ``import mxnet as mx``:
 """
 __version__ = "0.1.0"
 
+
+def _place_compile_cache():
+    """THE one place JAX's persistent compilation cache is given a
+    directory.  ``JAX_COMPILATION_CACHE_DIR`` (which JAX reads into
+    its own config) or a directory already configured wins and is
+    left alone; otherwise the cache goes to ``.jax_cache`` at the
+    root of the checkout — a fixed path, because the path is part of
+    what a later process must repeat to hit."""
+    import os
+    import jax
+    if jax.config.jax_compilation_cache_dir:
+        return
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache"))
+
+
+_place_compile_cache()
+
 from . import base
 from .base import MXNetError
 from .context import (Context, cpu, gpu, tpu, cpu_pinned, cpu_shared,

@@ -210,7 +210,7 @@ _CAST_CACHE: Dict[Any, bool] = {}
 
 
 def _sub_jaxprs(value):
-    core = jax.core
+    from jax.extend import core
     if isinstance(value, core.Jaxpr):
         yield value
     elif isinstance(value, core.ClosedJaxpr):
@@ -252,17 +252,16 @@ def _cast_decision(name: str, op, arrays, resolved) -> bool:
         return hit
     allow, deny, force = policy_sets()
     structs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays]
-    try:
-        closed = jax.make_jaxpr(
-            lambda *xs: op.fn(*xs, **resolved))(*structs)
-        opcodes: set = set()
-        _walk_opcodes(closed.jaxpr, opcodes)
-        # the policy drives the decision: cast only when the op lowers
-        # to allow-class contractions and nothing deny/fp32_force-class
-        decision = bool(opcodes) and opcodes <= allow
-        assert not (opcodes & (deny | force)) or not decision
-    except Exception:
-        decision = False
+    # no catch-all here: a trace that fails must surface, not turn
+    # autocast off for the op in silence
+    closed = jax.make_jaxpr(
+        lambda *xs: op.fn(*xs, **resolved))(*structs)
+    opcodes: set = set()
+    _walk_opcodes(closed.jaxpr, opcodes)
+    # the policy drives the decision: cast only when the op lowers
+    # to allow-class contractions and nothing deny/fp32_force-class
+    decision = bool(opcodes) and opcodes <= allow
+    assert not (opcodes & (deny | force)) or not decision
     _CAST_CACHE[key] = decision
     return decision
 
@@ -314,7 +313,8 @@ def _conv_bwd(strides, padding, rhs_dilation, dn, groups, res, g):
               lhs_dilation=(1,) * len(strides),
               rhs_dilation=rhs_dilation, dimension_numbers=dnums,
               feature_group_count=groups, batch_group_count=1,
-              precision=None, preferred_element_type=_F32)
+              precision=None, preferred_element_type=_F32,
+              out_sharding=None)
     dx = _convmod._conv_general_dilated_transpose_lhs(g, x, w, **kw)
     dw = _convmod._conv_general_dilated_transpose_rhs(g, x, w, **kw)
     return dx.astype(x.dtype), dw.astype(w.dtype)
@@ -347,15 +347,9 @@ def _dg_bwd(dnums, res, g):
     lhs, rhs = res
     g = g.astype(lhs.dtype)
     kw = dict(dimension_numbers=dnums, precision=None,
-              preferred_element_type=_F32)
-    try:
-        dl = _laxmod._dot_general_transpose_lhs(
-            g, lhs, rhs, out_type=None, **kw)
-        dr = _laxmod._dot_general_transpose_rhs(
-            g, lhs, rhs, out_type=None, **kw)
-    except TypeError:  # older jax: no out_type kwarg
-        dl = _laxmod._dot_general_transpose_lhs(g, lhs, rhs, **kw)
-        dr = _laxmod._dot_general_transpose_rhs(g, lhs, rhs, **kw)
+              preferred_element_type=_F32, out_sharding=None)
+    dl = _laxmod._dot_general_transpose_lhs(g, lhs, rhs, **kw)
+    dr = _laxmod._dot_general_transpose_rhs(g, lhs, rhs, **kw)
     return dl.astype(lhs.dtype), dr.astype(rhs.dtype)
 
 

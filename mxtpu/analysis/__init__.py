@@ -32,7 +32,7 @@ from __future__ import annotations
 import warnings
 from typing import Dict, List, Optional, Tuple
 
-from .hlo import HloProgram, parse_hlo
+from .hlo import HloProgram, inline_source_sites, parse_hlo
 from .summary import (BRACKET_OPS, COLLECTIVE_OPS, HOST_TRANSFER_OPS,
                       audit_findings, bracket_evidence,
                       format_evidence_table, summarize)
@@ -85,22 +85,9 @@ def lowered_text(fn, *args, **jit_kwargs) -> str:
     round-trip converts backend float normalization rewrites it
     into), which is the level an AMP policy must reason at."""
     import jax
-    from jax._src.lib import xla_extension as xe
     lowered = jax.jit(fn, **jit_kwargs).lower(*args)
-    asm = lowered.compiler_ir().operation.get_asm(
-        enable_debug_info=True)
-    try:
-        comp = xe.mlir.mlir_module_to_xla_computation(
-            asm, use_tuple_args=False, return_tuple=False)
-        opts = xe.HloPrintOptions()
-        opts.print_metadata = True
-        return comp.get_hlo_module().to_string(opts)
-    except AttributeError as e:  # jaxlib drift: report, don't crash
-        from mxtpu.base import MXNetError
-        raise MXNetError(
-            f"pre-optimization HLO conversion unavailable on this "
-            f"jaxlib ({e}) — mxprec needs "
-            f"xla_extension.mlir.mlir_module_to_xla_computation")
+    return inline_source_sites(
+        lowered.as_text(dialect="hlo", debug_info=True))
 
 
 def lowered_summary(fn, *args, **jit_kwargs) -> Dict:

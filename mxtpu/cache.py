@@ -104,14 +104,18 @@ class CacheKey:
     """An immutable, order-independent component dict plus its sha256
     digest (the entry filename).  Components are all strings; flipping
     ANY component — model fingerprint, bucket shape, mesh/topology,
-    jax version, backend, contract hash, salt — changes the digest,
-    and the full dict is ALSO stored in the entry header and
-    revalidated on load (a digest collision or a hand-renamed file can
-    never smuggle a stale executable in)."""
+    jax version, backend, device ids, contract hash, salt — changes
+    the digest, and the full dict is ALSO stored in the entry header
+    and revalidated on load (a digest collision or a hand-renamed file
+    can never smuggle a stale executable in).  ``devices`` are the
+    jax devices the keyed program runs on (their ids are a component;
+    the objects ride along because a serialized executable names its
+    devices by id and has to be loaded onto exactly those)."""
 
-    __slots__ = ("components", "digest")
+    __slots__ = ("components", "digest", "devices")
 
-    def __init__(self, components: Dict[str, Any]):
+    def __init__(self, components: Dict[str, Any], devices=()):
+        self.devices = tuple(devices)
         self.components = {str(k): str(v)
                            for k, v in sorted(components.items())}
         blob = json.dumps(self.components, sort_keys=True,
@@ -126,7 +130,7 @@ class CacheKey:
         miss-on-any-component contract through this)."""
         comps = dict(self.components)
         comps.update(changes)
-        return CacheKey(comps)
+        return CacheKey(comps, self.devices)
 
     def __repr__(self) -> str:
         return f"CacheKey({self.digest[:12]}…, {self.components})"
@@ -203,22 +207,28 @@ class ExecutableCache:
 
     # -- keys -----------------------------------------------------------
     def key(self, *, model: str, shape: Any, mesh: Any = "1dev",
-            **extra: Any) -> CacheKey:
+            devices=None, **extra: Any) -> CacheKey:
         """Compose a full cache key: the caller names WHAT was
         compiled (``model`` fingerprint, concrete ``shape``/bucket,
-        ``mesh`` topology, anything else via ``extra``); the cache
-        adds the environment components every entry must match — jax
-        version, backend, contract fingerprint, salt, format."""
+        ``mesh`` topology, anything else via ``extra``) and the
+        ``devices`` it runs on (default: the first device, where an
+        unplaced ``jax.jit`` runs); the cache adds the environment
+        components every entry must match — jax version, backend,
+        device kind and ids, contract fingerprint, salt, format."""
         import jax
+        devices = tuple(devices) if devices is not None \
+            else (jax.devices()[0],)
         comps: Dict[str, Any] = {
             "model": model, "shape": str(shape), "mesh": str(mesh),
             "jax": jax.__version__,
-            "backend": jax.default_backend(),
+            "backend": devices[0].platform,
+            "device": devices[0].device_kind,
+            "device_ids": ",".join(str(d.id) for d in devices),
             "contract": contract_fingerprint(),
             "salt": self.salt, "format": str(_FORMAT)}
         for k, v in extra.items():
             comps[k] = str(v)
-        return CacheKey(comps)
+        return CacheKey(comps, devices)
 
     def path_for(self, key: CacheKey) -> Path:
         return self.root / key.filename()
@@ -262,8 +272,12 @@ class ExecutableCache:
             # THE sanctioned raw-deserialize site (raw-deserialize
             # lint rule): the payload checksum was verified above.
             unloaded, in_tree, out_tree = pickle.loads(payload)
-            compiled = deserialize_and_load(unloaded, in_tree,
-                                            out_tree)
+            # without execution_devices the executable is loaded
+            # across EVERY local device and a one-device program then
+            # wants one argument shard per device
+            compiled = deserialize_and_load(
+                unloaded, in_tree, out_tree,
+                execution_devices=list(key.devices) or None)
         except Exception as e:  # jax/backend mismatch survives checksum
             self._quarantine(path, "deserialize", key, detail=repr(e))
             return None, {}

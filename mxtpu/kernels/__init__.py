@@ -60,8 +60,9 @@ def pallas_enabled() -> bool:
 
 
 def interpret_mode() -> bool:
-    return knobs.get("MXTPU_PALLAS") == "interpret" or \
-        jax.default_backend() != "tpu"
+    """Pallas interpreter mode exactly when the backend is not a TPU:
+    on the chip a kernel is always handed to the TPU compiler."""
+    return jax.default_backend() != "tpu"
 
 
 from .layer_norm import layer_norm, layer_norm_reference  # noqa: E402

@@ -125,23 +125,6 @@ def _mesh_is_multiprocess(mesh: Mesh) -> bool:
     return flag
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs,
-                     check=None):
-    """``shard_map`` across jax releases: new jax exposes
-    ``jax.shard_map`` (``check_vma``), older releases only
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``).
-    ``check=None`` keeps the library default."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check is None else {"check_vma": check}
-        return sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-    kw = {} if check is None else {"check_rep": check}
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
-
-
 def _device_put_global(raw, mesh: Mesh, spec) -> jax.Array:
     """Place a value onto a mesh sharding, including meshes that span
     processes.  Host values: every process passes the SAME full value
@@ -830,7 +813,6 @@ class TrainStep:
         reduce-scatter on every backend — the explicit collectives
         make the comm layout part of the program, testable from the
         HLO on the CPU virtual mesh."""
-        from jax.experimental.shard_map import shard_map
         mesh, dp_axis = self.mesh, self.dp_axis
         dp = self._zero_dp
         buckets = self._zero_buckets
@@ -1026,10 +1008,10 @@ class TrainStep:
             fn = body_amp
             in_specs = in_specs + (P(),)
             out_specs = out_specs + (P(),)
-        # check_rep=False: the rep checker can't infer that the tiled
+        # check_vma=False: the checker can't infer that the tiled
         # all_gather output is replicated
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # -- the hot call ----------------------------------------------------
     def _prep(self, x, y):
@@ -1075,8 +1057,8 @@ class TrainStep:
         the text only as shapes (they are runtime arguments), and
         debug locations stay off (``as_text()`` default) so the text
         is checkout-independent.  The environment components (jax
-        version, backend, contract hash, salt) are added by
-        ``ExecutableCache.key``."""
+        version, backend, device kind and ids, contract hash, salt)
+        are added by ``ExecutableCache.key``."""
         import hashlib
         prog = hashlib.sha256(
             lowered.as_text().encode()).hexdigest()[:24]
@@ -1086,9 +1068,10 @@ class TrainStep:
                      (tuple(y_raw.shape), str(y_raw.dtype))))
         # net/opt class names ride along as debuggable context in the
         # entry header (the program hash already subsumes them)
+        devices = tuple(x_raw.devices()) if self.mesh is None \
+            else tuple(self.mesh.devices.flat)
         return self._cache.key(
-            model=prog, shape=shape, mesh=mesh,
-            device=getattr(jax.devices()[0], "device_kind", "unknown"),
+            model=prog, shape=shape, mesh=mesh, devices=devices,
             net=type(self.net).__name__,
             opt=type(self.optimizer).__name__)
 

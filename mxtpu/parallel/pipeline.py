@@ -98,11 +98,10 @@ def spmd_pipeline(stage_fn: Callable, stage_params: Any, x: jax.Array,
     x_spec = P(*((None,) + bspec))
     p_specs = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
     out_spec = x_spec
-    from . import shard_map_compat
-    fn = shard_map_compat(
-        local_fn, mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
         in_specs=(p_specs, x_spec, P()),
-        out_specs=out_spec, check=False)
+        out_specs=out_spec, check_vma=False)
     key_data = key if key is not None else jnp.zeros((), jnp.uint32)
     from . import _device_put_global, _mesh_is_multiprocess
     if _mesh_is_multiprocess(mesh):

@@ -78,13 +78,9 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         acc = jnp.zeros(q_loc.shape[:3] + (q_loc.shape[3],),
                         jnp.float32)
         # mark the zero-init carries as device-varying so the fori_loop
-        # carry types line up with the per-device accumulation (pcast
-        # belongs to the new-jax VMA checker; older releases neither
-        # have it nor need it — their check_rep pass is disabled below)
-        pcast = getattr(lax, "pcast", None)
-        if pcast is not None:
-            m, l, acc = (pcast(a, (axis,), to="varying")
-                         for a in (m, l, acc))
+        # carry types line up with the per-device accumulation
+        m, l, acc = (lax.pcast(a, (axis,), to="varying")
+                     for a in (m, l, acc))
         perm = [(j, (j + 1) % p_size) for j in range(p_size)]
 
         def body(i, carry):
@@ -111,7 +107,6 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
         # implicitly device_put onto non-addressable shardings)
         q, k, v = (_device_put_global(a, mesh, spec)
                    for a in (q, k, v))
-    from . import shard_map_compat
-    fn = shard_map_compat(local_fn, mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

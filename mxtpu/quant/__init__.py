@@ -321,15 +321,14 @@ def _quant_decision(name: str, op, arrays, resolved) -> bool:
         return hit
     allow, deny = policy_sets()
     structs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays]
-    try:
-        closed = jax.make_jaxpr(
-            lambda *xs: op.fn(*xs, **resolved))(*structs)
-        opcodes: set = set()
-        _amp._walk_opcodes(closed.jaxpr, opcodes)
-        decision = bool(opcodes) and opcodes <= allow
-        assert not (opcodes & deny) or not decision
-    except Exception:
-        decision = False
+    # no catch-all here: a trace that fails must surface, not turn
+    # the int8 rewrite off for the op in silence
+    closed = jax.make_jaxpr(
+        lambda *xs: op.fn(*xs, **resolved))(*structs)
+    opcodes: set = set()
+    _amp._walk_opcodes(closed.jaxpr, opcodes)
+    decision = bool(opcodes) and opcodes <= allow
+    assert not (opcodes & deny) or not decision
     _DECISION_CACHE[key] = decision
     return decision
 

@@ -127,7 +127,10 @@ class Autoscaler:
     >>> router.add_controller(scaler.tick)   # router tick drives it
 
     ``make_worker(name)`` must return a fresh, un-attached
-    :class:`~.router.FleetWorker` sharing the fleet's bucket ladder.
+    :class:`~.router.FleetWorker` sharing the fleet's bucket ladder —
+    and should build its runner with an explicit ``device=``: a runner
+    built without one lands on ``jax.devices()[0]``, so a fleet scaled
+    out that way stacks every replica on the first chip.
     """
 
     def __init__(self, router, make_worker: Callable[[str], Any], *,

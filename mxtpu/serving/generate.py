@@ -402,8 +402,7 @@ class GenerateRunner:
         return self._cache.key(
             model=self._fingerprint,
             shape=f"gen:{kind}:{tuple(shp)}", mesh="1dev",
-            device=getattr(self._device, "device_kind", "unknown"),
-            **extra)
+            devices=(self._device,), **extra)
 
     def cached_buckets(self) -> List[Tuple]:
         """Subset of the ladder present in the persistent cache right

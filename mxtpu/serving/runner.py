@@ -352,8 +352,7 @@ class ModelRunner:
             extra["quant"] = "int8"
         return self._cache.key(
             model=self._fingerprint, shape=str(sorted(shapes.items())),
-            mesh="1dev", device=getattr(self._device, "device_kind",
-                                        "unknown"), **extra)
+            mesh="1dev", devices=(self._device,), **extra)
 
     def cached_buckets(self) -> List[Tuple]:
         """The subset of this runner's ladder present in the

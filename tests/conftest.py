@@ -1,10 +1,19 @@
 """Test configuration.
 
-Tests run on a virtual 8-device CPU mesh (the reference's trick of testing
-distributed paths with local multiprocess, SURVEY.md §4.5, maps to XLA's
-host-platform device-count flag).  Set MXTPU_TEST_PLATFORM=tpu to run the
-suite against the real chip instead (the check_consistency harness then
-compares cpu↔tpu).
+Tests run on the CPU, on a virtual 8-device mesh (the reference's trick
+of testing distributed paths with local multiprocess, SURVEY.md §4.5,
+maps to XLA's host-platform device-count flag): this file adds the
+device-count flag and pins ``jax_platforms=cpu`` before the first
+backend use, so meshes, ZeRO-1 collectives and one-replica-per-device
+fleets need no chip.  Pallas kernels take their lax reference here;
+``test_chip_compile.py`` hands them to the TPU compiler for a described
+chip, and ``test_chip_smoke.py`` rehearses ``chip_smoke.py`` at a toy
+size.  The chip itself is reached with ``python chip_smoke.py``.
+
+``MXTPU_TEST_PLATFORM=tpu`` leaves the platform to JAX, for running
+``test_tpu_consistency.py`` / ``test_tpu_sweep.py`` (cpu<->tpu
+comparisons) in ONE pytest process on a machine with a chip — a chip
+belongs to one process at a time, so no xdist workers there.
 """
 import os
 import sys

@@ -105,6 +105,82 @@ def test_parser_structure():
     assert {i.name for i in main.consumers("cc")} == {"ct"}
 
 
+def _scaled_rowsum(x):
+    import jax
+    import jax.numpy as jnp
+    return jax.lax.reduce(x * 2.0, jnp.float32(0), jax.lax.add, (1,))
+
+
+def test_parser_reads_the_preopt_dialect():
+    """``lowered.as_text(dialect="hlo")`` prints bare names (no ``%``),
+    no operand types and headers without a parameter list; operands,
+    called computations and source sites must still resolve."""
+    from mxtpu.analysis.dtypeflow import instr_site
+    text = analysis.lowered_text(_scaled_rowsum,
+                                 np.ones((4, 8), np.float32))
+    assert "%" not in text and "stack_frame_id" not in text
+    prog = analysis.parse_hlo(text)
+    entry = prog.entry
+    (red,) = [i for i in entry.instructions if i.opcode == "reduce"]
+    assert len(red.operands) == 2
+    assert all(o in entry.by_name for o in red.operands)
+    assert entry.by_name[red.operands[0]].opcode == "multiply"
+    (region,) = red.calls
+    assert [i.opcode for i in prog.computations[region].instructions
+            if i.root] == ["add"]
+    op_name, site = instr_site(red)
+    assert op_name.endswith("reduce")
+    assert site.startswith("tests/test_analysis.py:")
+    # literals name no operand
+    assert all(not i.operands for i in prog.all_instructions()
+               if i.opcode in ("parameter", "constant"))
+
+
+def test_lowered_text_does_not_depend_on_the_call_site():
+    """The dump's location tables hold every outer frame, the caller
+    of ``lower()`` included; ``inline_source_sites`` keeps only each
+    instruction's own site, so one program lowered from two lines is
+    one text (what the kill-switch bit-identity tests compare)."""
+    x = np.ones((4, 8), np.float32)
+    a = analysis.lowered_text(_scaled_rowsum, x)
+    b = analysis.lowered_text(_scaled_rowsum, x)
+    assert a == b
+    assert "FileLocations" not in a and 'source_file="' in a
+
+
+def test_inline_source_sites_resolves_innermost_frame():
+    from mxtpu.analysis.hlo import inline_source_sites
+    text = """HloModule m
+
+FileNames
+1 "/a/outer.py"
+2 "/a/inner.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=3 end_line=3 column=1 end_column=2}
+2 {file_name_id=2 function_name_id=1 line=41 end_line=42 column=1 end_column=2}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+
+ENTRY main {
+  a = f32[4]{0} parameter(0), metadata={op_name="a"}
+  ROOT n = f32[4]{0} negate(a), metadata={op_name="neg" stack_frame_id=2}
+}
+"""
+    out = inline_source_sites(text)
+    assert 'metadata={op_name="neg" source_file="/a/inner.py" ' \
+           'source_line=41}' in out
+    assert "outer.py" not in out and "StackFrames" not in out
+    # a frame id with no table row is dropped, not kept dangling
+    assert "stack_frame_id" not in inline_source_sites(
+        text.replace("stack_frame_id=2", "stack_frame_id=9"))
+
+
 def test_summary_families():
     s = _summ(SYNTH)
     assert s["collectives"] == {
@@ -238,7 +314,7 @@ def test_compiled_bracket_perturbation_trips():
 
 
 def test_compiled_dtype_perturbation_trips():
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     def f32_step(x):
         return (x * 2.0).sum()

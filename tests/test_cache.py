@@ -317,6 +317,57 @@ def test_runner_warms_full_ladder_from_disk_zero_compiles(tmp_path):
     assert runner.num_compiled() == nbuckets  # serving added nothing
 
 
+@pytest.mark.parametrize("dev", [1, 5])
+def test_replica_off_device_zero_warms_from_its_own_entries(tmp_path,
+                                                            dev):
+    """A serialized executable names its device by id: a replica on
+    device N loads what a replica on device N stored (onto exactly
+    that device — not across all local devices, where a one-device
+    program then wants a shard per device), and a replica elsewhere
+    MISSES those entries instead of quarantining them."""
+    import jax
+    device = jax.devices()[dev]
+    donor = _mul_runner(cache=ExecutableCache(tmp_path), device=device)
+    donor.warmup()
+    nbuckets = donor.num_compiled()
+    x = _payload(3)
+    bucket = donor.bucket_for(1)
+    want = np.asarray(donor.run_raw(donor._pad_stack([x], bucket),
+                                    bucket)[0])
+
+    fresh = ExecutableCache(tmp_path)
+    twin = _mul_runner(cache=fresh, device=device)
+    assert sorted(twin.cached_buckets()) == sorted(twin.buckets())
+    twin.warm_from_disk()
+    assert fresh.stats()["hit"] == nbuckets
+    out = twin.run_raw(twin._pad_stack([x], bucket), bucket)[0]
+    assert out.devices() == {device}
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+    other = ExecutableCache(tmp_path)
+    elsewhere = _mul_runner(cache=other, device=jax.devices()[0])
+    assert elsewhere.cached_buckets() == []
+    elsewhere.warmup()
+    st = other.stats()
+    assert st["hit"] == 0 and st["quarantined"] == 0
+    assert st["store"] == nbuckets
+
+
+def test_key_carries_the_devices_it_loads_onto():
+    import jax
+    cache = ExecutableCache("/nonexistent")
+    devs = tuple(jax.devices()[2:4])
+    key = cache.key(model="m", shape="s", mesh="dp2", devices=devs)
+    assert key.devices == devs
+    assert key.components["device_ids"] == "2,3"
+    assert key.replace(model="other").devices == devs
+    assert key.digest != cache.key(model="m", shape="s", mesh="dp2",
+                                   devices=devs[::-1]).digest
+    # no devices named: the first device, where an unplaced jit runs
+    assert cache.key(model="m", shape="s").devices == \
+        (jax.devices()[0],)
+
+
 def _fc_quant_runner(cache, quant=False):
     """One FullyConnected — the smallest graph the INT8 calibration
     pass accepts (test_quant.py owns the numerics; here it only has

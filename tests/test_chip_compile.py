@@ -1,0 +1,142 @@
+"""Compile the main path's Pallas kernels for the real chip, here.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+chip that is *described*, not attached (``v5e:2x2``), so every case
+below hands a kernel at its production width to the same compiler the
+chip run uses: a slice misaligned to the tiling, too much VMEM, or an
+op Mosaic cannot lower fails here at no chip time.  Nothing runs —
+results and times come only from ``chip_smoke.py`` on the chip.
+
+This is the ONE test file that loads the TPU compiler (only one
+process at a time may hold libtpu, and a second file could land on a
+second xdist worker).  The topology is described inside a fixture,
+never at import, so every worker collects the same tests.
+
+``pallas_enabled()``/``interpret_mode()`` ask ``jax.default_backend()``
+and see the CPU here, so the tests steer them to the TPU answer by
+monkeypatch — the program grows no option for it.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described device is written to JAX's persistent
+    cache but cannot be read back without the chip (the next run warns
+    and recompiles), so the cache is off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Kernel dispatch as it is on the chip: Pallas on, interpreter
+    off."""
+    from mxtpu import kernels
+    monkeypatch.setattr(kernels, "pallas_enabled", lambda: True)
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+
+
+def _attention(q_shape, k_shape, causal, grad):
+    from mxtpu.kernels import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return (fwd_bwd if grad else fwd), \
+        [(q_shape, jnp.bfloat16), (k_shape, jnp.bfloat16),
+         (k_shape, jnp.bfloat16)]
+
+
+def _layer_norm():
+    from mxtpu.kernels import layer_norm
+
+    def fwd_bwd(x, g, b):
+        return jax.grad(
+            lambda *a: layer_norm(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(x, g, b)
+
+    return fwd_bwd, [((4096, 1024), jnp.bfloat16),
+                     ((1024,), jnp.float32), ((1024,), jnp.float32)]
+
+
+def _fused_residual_ln(p):
+    from mxtpu.kernels.layer_norm import fused_residual_layer_norm
+
+    def fwd_bwd(h, bias, res, g, b, key_data):
+        return jax.grad(
+            lambda *a: fused_residual_layer_norm(
+                *a, key_data, p=p, training=True)
+            .astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3, 4))(h, bias, res, g, b)
+
+    return fwd_bwd, [((4096, 1024), jnp.bfloat16),
+                     ((1024,), jnp.bfloat16),
+                     ((4096, 1024), jnp.bfloat16),
+                     ((1024,), jnp.float32), ((1024,), jnp.float32),
+                     ((2,), jnp.uint32)]
+
+
+# name -> builder of (fn, [(shape, dtype), ...]); the widths are
+# BERT-Large's (16 heads x 64, hidden 1024) at the benchmark shapes
+# b32 x s128 and b8 x s512, plus the long-context and decode shapes
+_CASES = {
+    "flash_fwd_b32_h16_t128_d64": lambda: _attention(
+        (32, 16, 128, 64), (32, 16, 128, 64), False, grad=False),
+    "flash_fwd_b8_h16_t512_d64": lambda: _attention(
+        (8, 16, 512, 64), (8, 16, 512, 64), False, grad=False),
+    "flash_fwd_bwd_b2_h16_t1024_d64": lambda: _attention(
+        (2, 16, 1024, 64), (2, 16, 1024, 64), False, grad=True),
+    "flash_fwd_bwd_b2_h16_t1024_d64_causal": lambda: _attention(
+        (2, 16, 1024, 64), (2, 16, 1024, 64), True, grad=True),
+    "flash_fwd_bwd_padded_t12": lambda: _attention(
+        (2, 16, 12, 64), (2, 16, 12, 64), False, grad=True),
+    "flash_fwd_tq1_tk512_causal": lambda: _attention(
+        (4, 16, 1, 64), (4, 16, 512, 64), True, grad=False),
+    "layer_norm_fwd_bwd_4096x1024": _layer_norm,
+    "fused_residual_ln_fwd_bwd_4096x1024_keep0.9":
+        lambda: _fused_residual_ln(0.1),
+    "fused_residual_ln_fwd_bwd_4096x1024_keep1.0":
+        lambda: _fused_residual_ln(0.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, on_tpu,
+                                 no_persistent_cache):
+    fn, specs = _CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    from mxtpu import analysis
+    calls = analysis.compiled_summary(fn, *args)["custom_calls"]
+    assert calls.get("tpu_custom_call", {}).get("count", 0) >= 1, \
+        f"{case}: no Pallas custom call in the compiled program — " \
+        f"the lax reference was taken: {calls}"

@@ -284,7 +284,6 @@ def test_sync_batch_norm_cross_device():
     if len(devs) < 2:
         pytest.skip("needs the virtual multi-device mesh")
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     x = _rand(8, 6, 4, 4)
     gamma = np.ones(6, np.float32)
     beta = np.zeros(6, np.float32)
@@ -296,7 +295,7 @@ def test_sync_batch_norm_cross_device():
     def local(xb, g, b, m, v):
         return fn(xb, g, b, m, v, axis_name="dp")
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("dp"), P(), P(), P(), P()),
         out_specs=(P("dp"), P(), P()))

@@ -160,7 +160,7 @@ def test_seeded_sub_f32_matmul():
 
 def test_seeded_f64_creep():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         led = analysis.lowered_summary(

@@ -7,16 +7,22 @@ Runs only when the session's default backend is a TPU
 (``MXTPU_TEST_PLATFORM=tpu``); on the CPU-mesh CI config every test
 skips (the cpu↔cpu comparison would be vacuous).
 """
-import jax
 import numpy as np
 import pytest
 
 import mxtpu as mx
 from mxtpu.test_utils import check_consistency
 
-pytestmark = pytest.mark.skipif(
-    jax.default_backend() == "cpu",
-    reason="needs a real accelerator backend (MXTPU_TEST_PLATFORM=tpu)")
+
+@pytest.fixture(autouse=True)
+def _needs_accelerator():
+    """Skip from inside the test, not at import: the backend is asked
+    for only once a test of this file runs, so every xdist worker
+    collects the same tests."""
+    import jax
+    if jax.default_backend() == "cpu":
+        pytest.skip("needs a real accelerator backend "
+                    "(MXTPU_TEST_PLATFORM=tpu)")
 
 
 def _ctxs(extra_bf16=False):

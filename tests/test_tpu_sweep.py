@@ -3,8 +3,9 @@
 ~300 auto-synthesized + curated one-op cases (incl. a bf16 tier)
 over ~280 distinct registry rules run fwd+bwd on BOTH backends and cross-compare — the reference's
 ``tests/python/gpu/test_operator_gpu.py``† pattern at registry scale.
-Groups of ~25 cases compile as ONE program per backend in an isolated
-subprocess (see tests/tpu_sweep_runner.py for why).
+Groups of ~25 cases compile as ONE program per backend, in this
+process: it already holds the chip, and a chip belongs to one process
+at a time (see tests/tpu_sweep_runner.py).
 
 ``test_sweep_covers_registry`` runs everywhere and pins the contract:
 every registered op is either swept or ledgered with a reason — a new
@@ -12,14 +13,9 @@ op cannot silently dodge the sweep.  The hardware groups run only
 under MXTPU_TEST_PLATFORM=tpu, like test_tpu_consistency.py.
 """
 import json
-import os
-import subprocess
-import sys
 
-import jax
 import pytest
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
 GROUP_SIZE = 25
 N_GROUPS = 13  # must satisfy N_GROUPS*GROUP_SIZE >= len(cases)
 
@@ -66,21 +62,14 @@ def test_sweep_covers_registry():
     assert all(len(r) > 10 for r in skipped.values())
 
 
-@pytest.mark.skipif(
-    jax.default_backend() == "cpu",
-    reason="needs a real accelerator backend (MXTPU_TEST_PLATFORM=tpu)")
 @pytest.mark.parametrize("group", range(N_GROUPS))
 def test_registry_sweep_group(group):
-    env = dict(os.environ)
-    env.pop("MXTPU_TEST_PLATFORM", None)
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(_HERE, "tpu_sweep_runner.py"),
-         str(group), str(GROUP_SIZE)],
-        capture_output=True, text=True, timeout=1200, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = proc.stdout.strip().splitlines()[-1]
-    results = json.loads(line)["results"]
+    import jax
+    if jax.default_backend() == "cpu":
+        pytest.skip("needs a real accelerator backend "
+                    "(MXTPU_TEST_PLATFORM=tpu)")
+    from tests.tpu_sweep_runner import run_group
+    results = run_group(group, GROUP_SIZE)
     bad = []
     for r in results:
         if r["status"] != "ok":

@@ -13,6 +13,12 @@ Local simulation of an N-process cluster (the reference's
 
   python tools/launch.py -n 4 --launcher local python train.py
 
+On a host with TPU chips a chip belongs to one process at a time, so
+``local`` gives each child its own chip (N must then be 1 or the
+host's chip count; anything else is refused) — unless the children are
+pinned to the CPU with ``JAX_PLATFORMS=cpu``, the test harness's case.
+The launcher itself never imports JAX: it would take the chips.
+
 Real multi-host: run on each host with --host-rank set (or under your
 scheduler, e.g. one task per host):
 
@@ -23,6 +29,34 @@ import argparse
 import os
 import subprocess
 import sys
+
+
+# chips of one host -> the process grid libtpu is told to form when
+# each process drives one chip (v5e hosts: 1, 2x2, 2x4)
+_PROCESS_BOUNDS = {1: "1,1,1", 4: "2,2,1", 8: "2,4,1"}
+_TPU_PORT_BASE = 8476
+
+
+def local_tpu_chips():
+    """TPU chips on this host, counted from their device files —
+    asking JAX would make this process hold them."""
+    import glob
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_env(rank, n):
+    """libtpu settings that hand child ``rank`` of ``n`` exactly one
+    chip and let the ``n`` one-chip processes form one slice."""
+    return {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _PROCESS_BOUNDS[n],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{_TPU_PORT_BASE + r}" for r in range(n)),
+        "TPU_PROCESS_PORT": str(_TPU_PORT_BASE + rank),
+        "TPU_VISIBLE_CHIPS": str(rank),
+        "CLOUD_TPU_TASK_ID": str(rank),
+    }
 
 
 def main():
@@ -53,11 +87,22 @@ def main():
     if args.launcher == "local":
         # N local processes, each pretending to be one host — the
         # distributed test harness (no real multi-chip needed)
+        n = args.num_processes
+        on_cpu = base_env.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+        chips = 0 if on_cpu else local_tpu_chips()
+        if chips and n > 1 and (n != chips or n not in _PROCESS_BOUNDS):
+            p.error(f"--launcher local: this host has {chips} TPU "
+                    f"chip(s) and a chip belongs to one process at a "
+                    f"time, so -n must be 1 or {chips} (got {n}); pin "
+                    f"the children to the CPU with JAX_PLATFORMS=cpu "
+                    f"to simulate more hosts")
         procs = []
-        for rank in range(args.num_processes):
+        for rank in range(n):
             env = dict(base_env)
             env["JAX_PROCESS_ID"] = str(rank)
             env["MXTPU_PROCESS_ID"] = str(rank)
+            if chips and n > 1:
+                env.update(chip_env(rank, n))
             procs.append(subprocess.Popen(args.command, env=env))
         rc = 0
         for proc in procs:

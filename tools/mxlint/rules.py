@@ -758,7 +758,7 @@ class DtypeHygiene(Rule):
                     "jax_enable_x64 toggled in library code — x64 is "
                     "process-global and breaks the bf16/f32 policy "
                     "contracts/prec/ pins; scope it to the caller "
-                    "(jax.experimental.enable_x64) or waive with a "
+                    "(jax.enable_x64) or waive with a "
                     "pragma"))
                 continue
             if isinstance(node.func, ast.Attribute) and \
