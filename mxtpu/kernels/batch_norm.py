@@ -328,6 +328,7 @@ def _fwd_call(x3, gamma, beta, resid3, eps, act, cb, interpret):
     y, mean, var = pl.pallas_call(
         functools.partial(_fwd_kernel, n=n, eps=eps, act=act,
                           add=resid3 is not None),
+        name="batch_norm_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[_blk3(N, cb, S), _blkc(cb), _blkc(cb)],
@@ -361,6 +362,7 @@ def _bwd_call(x3, resid3, dy3, gamma, beta, mean, rstd, act, cb,
          jax.ShapeDtypeStruct((C, 1), jnp.float32)]
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel, n=n, act=act, add=add),
+        name="batch_norm_bwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -402,6 +404,7 @@ def _fwd_call_cm(x2, gamma, beta, resid2, eps, act, cbl, interpret):
     y, mean, var = pl.pallas_call(
         functools.partial(_fwd_kernel_cm, n=n, eps=eps, act=act,
                           add=add),
+        name="batch_norm_fwd_cm",
         grid=grid,
         in_specs=in_specs,
         out_specs=[_blk2(R, cbl), _blkc_cm(cbl), _blkc_cm(cbl)],
@@ -436,6 +439,7 @@ def _bwd_call_cm(x2, resid2, dy2, gamma, beta, mean, rstd, act, cbl,
          jax.ShapeDtypeStruct((1, C), jnp.float32)]
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel_cm, n=n, act=act, add=add),
+        name="batch_norm_bwd_cm",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

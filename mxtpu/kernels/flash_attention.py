@@ -206,6 +206,7 @@ def _flash_forward(q3, k3, v3, causal, sm_scale, interpret,
                                precision=_precision_for(q3.dtype))
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
@@ -349,6 +350,7 @@ def _flash_backward(q3, k3, v3, do3, lse, delta_rows, causal, sm_scale,
                           causal=causal, bq=bq, bk=bk, nk=nk, delta=d,
                           valid_kv=valid_kv,
                           precision=_precision_for(q3.dtype)),
+        name="flash_attention_dq",
         grid=(BH, nq, nk),
         in_specs=[q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, row_spec_i,
                   row_spec_i],
@@ -370,6 +372,7 @@ def _flash_backward(q3, k3, v3, do3, lse, delta_rows, causal, sm_scale,
                           causal=causal, bq=bq, bk=bk, nq=nq, delta=d,
                           valid_kv=valid_kv,
                           precision=_precision_for(q3.dtype)),
+        name="flash_attention_dkv",
         grid=(BH, nk, nq),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],

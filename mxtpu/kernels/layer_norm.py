@@ -107,6 +107,7 @@ def _pallas_ln_fwd(x2, gamma, beta, eps, interpret):
     grid = (R // BR,)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="layer_norm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BR, C), lambda i: (i, 0),
@@ -140,6 +141,7 @@ def _pallas_ln_bwd(x2, gamma, mean, rstd, dy2, interpret):
     grid = (R // BR,)
     dx, dg_part, db_part = pl.pallas_call(
         _ln_bwd_kernel,
+        name="layer_norm_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BR, C), lambda i: (i, 0),
@@ -364,6 +366,7 @@ def _pallas_frln_fwd(h2, bias, res2, gamma, beta, seed, keep, eps,
     y, mean, rstd = pl.pallas_call(
         functools.partial(_frln_fwd_kernel, eps=eps, keep=keep,
                           thresh=_keep_thresh(keep), block_rows=BR),
+        name="fused_residual_layer_norm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -394,6 +397,7 @@ def _pallas_frln_bwd(h2, bias, res2, gamma, seed, mean, rstd, dy2,
     dh, dres, dg_p, db_p, dbias_p = pl.pallas_call(
         functools.partial(_frln_bwd_kernel, keep=keep,
                           thresh=_keep_thresh(keep), block_rows=BR),
+        name="fused_residual_layer_norm_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

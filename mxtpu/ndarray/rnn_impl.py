@@ -246,19 +246,20 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
     L = k_cache.shape[2]
     scale = (1.0 / float(np.sqrt(D))) \
         if (sm_scale is None or sm_scale < 0) else float(sm_scale)
-    s = jnp.asarray(step).astype(jnp.int32)
-    scores = jnp.einsum("bhtd,bhld->bhtl", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32),
-                        preferred_element_type=jnp.float32) * scale
-    pos_q = s[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    pos_k = jnp.arange(L, dtype=jnp.int32)
-    mask = pos_k[None, None, :] <= pos_q[:, :, None]
-    scores = jnp.where(mask[:, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhtl,bhld->bhtd", probs,
-                     v_cache.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    with jax.named_scope("cached_attention"):
+        s = jnp.asarray(step).astype(jnp.int32)
+        scores = jnp.einsum("bhtd,bhld->bhtl", q.astype(jnp.float32),
+                            k_cache.astype(jnp.float32),
+                            preferred_element_type=jnp.float32) * scale
+        pos_q = s[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        pos_k = jnp.arange(L, dtype=jnp.int32)
+        mask = pos_k[None, None, :] <= pos_q[:, :, None]
+        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhtl,bhld->bhtd", probs,
+                         v_cache.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        return out.astype(q.dtype)
 
 
 register_op("cached_attention", num_inputs=4, differentiable=False,

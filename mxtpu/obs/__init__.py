@@ -10,7 +10,9 @@ Three surfaces behind one switch (``MXTPU_OBS``, default on):
   and ``TrainStep`` all publish here.
 * **Per-request tracing** (:mod:`.trace`) — trace ids minted at
   submit, phase spans through the chrome-trace profiler,
-  :func:`trace_of` to rebuild one request's timeline.
+  :func:`trace_of` to rebuild one request's timeline; :func:`region`
+  writes the program's layer-boundary spans into the ``jax.profiler``
+  trace too, on the device events' clock.
 * **Flight recorder** (:mod:`.recorder`) — bounded per-worker ring of
   structured events (health transitions, canary results, compile
   misses, evictions, fault firings), dumped on worker death or
@@ -51,18 +53,25 @@ from .slo import (DEFAULT_RULES, NULL_SLO_ENGINE, AvailabilitySLO,
                   BurnRateRule, LatencySLO, SLOEngine,
                   parse_slo_classes)
 from .timeseries import NULL_SAMPLER, Sampler
-from .trace import (SPAN_BACKOFF, SPAN_EXECUTE, SPAN_HEDGE,
-                    SPAN_PAD_SCATTER, SPAN_PREFILL, SPAN_QUEUE_WAIT,
-                    SPAN_REDISPATCH, SPAN_REPLAY, SPAN_REQUEUE,
-                    SPAN_RUN, SPAN_SCALE, SPAN_SHED, SPAN_STEAL,
-                    SPAN_SUBMIT, SPAN_TOKEN,
-                    new_trace_id, span, trace_of)
+from .trace import (NULL_REGION, SPAN_BACKOFF, SPAN_COMPILE,
+                    SPAN_DECODE, SPAN_DISPATCH, SPAN_DONE,
+                    SPAN_EXECUTE, SPAN_FETCH, SPAN_FIRE,
+                    SPAN_GEN_ADMIT, SPAN_GEN_STEP, SPAN_HEDGE,
+                    SPAN_PAD_SCATTER, SPAN_PREFILL, SPAN_PREFILL_CALL,
+                    SPAN_QUEUE_WAIT, SPAN_REDISPATCH, SPAN_REPLAY,
+                    SPAN_REQUEUE, SPAN_RUN, SPAN_SAMPLE, SPAN_SCALE,
+                    SPAN_SHED, SPAN_STAGE, SPAN_STEAL, SPAN_SUBMIT,
+                    SPAN_TOKEN, SPAN_TRAIN_DISPATCH, SPAN_TRAIN_PREP,
+                    SPAN_TRAIN_STEP, SPAN_TRAIN_WRITEBACK,
+                    new_trace_id, region, region_writer, span,
+                    trace_of)
 
 __all__ = [
     "enabled", "registry", "counter", "gauge", "histogram",
     "prometheus_text", "snapshot", "summary", "reset",
     "flight", "flight_recorders", "dump_all", "dump_on_error_path",
-    "new_trace_id", "span", "trace_of", "self_check",
+    "new_trace_id", "span", "region", "region_writer", "trace_of",
+    "self_check",
     "sampler", "slo_engine", "debug_server",
     "MetricsRegistry", "FlightRecorder", "Sampler", "SLOEngine",
     "DebugServer", "AvailabilitySLO", "LatencySLO", "BurnRateRule",
@@ -74,6 +83,11 @@ __all__ = [
     "SPAN_STEAL", "SPAN_REDISPATCH", "SPAN_HEDGE", "SPAN_PAD_SCATTER",
     "SPAN_RUN", "SPAN_REQUEUE", "SPAN_SHED", "SPAN_SCALE",
     "SPAN_PREFILL", "SPAN_TOKEN", "SPAN_REPLAY",
+    "SPAN_GEN_STEP", "SPAN_GEN_ADMIT", "SPAN_PREFILL_CALL",
+    "SPAN_DECODE", "SPAN_SAMPLE", "SPAN_FIRE", "SPAN_COMPILE",
+    "SPAN_TRAIN_STEP", "SPAN_TRAIN_PREP", "SPAN_TRAIN_DISPATCH",
+    "SPAN_TRAIN_WRITEBACK", "SPAN_STAGE", "SPAN_DISPATCH",
+    "SPAN_FETCH", "SPAN_DONE", "NULL_REGION",
 ]
 
 _REGISTRY = MetricsRegistry()

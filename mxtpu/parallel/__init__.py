@@ -244,6 +244,13 @@ def plan_zero_buckets(sigs, dp: int, stack_axis_only: bool = False):
     return buckets
 
 
+# the two phases of every step body, by the names their operations
+# carry in the program's op_name metadata (bucket packing and unpacking
+# belong to the optimizer's)
+_SCOPE_FWD_BWD = "train/forward_backward"
+_SCOPE_OPTIMIZER = "train/optimizer"
+
+
 def _mem_stats(compiled):
     """``memory_analysis()`` of a compiled program as a plain dict
     (None when the backend doesn't report) — delegates to the ONE
@@ -339,12 +346,18 @@ class TrainStep:
         # ISSUE 8: obs registry instruments — step wall time, compile
         # events, and the compiler-estimated FLOPs/step (the MFU
         # numerator, set by cost_analysis).  Same cached-bool contract
-        # as _guards: MXTPU_OBS=0 costs one bool test per step.
+        # as _guards: MXTPU_OBS=0 costs one bool test per step, and the
+        # step's four regions one call each of a writer that does
+        # nothing (bound here, once; obs.region itself reads no knob).
         self._obs = obs.enabled()
-        _entry = f"TrainStep[{type(net).__name__}]"
+        self._region = obs.region_writer(self._obs)
+        _entry = self._entry_label = f"TrainStep[{type(net).__name__}]"
         self._m_step = obs.histogram(
             "mxtpu_train_step_seconds",
-            "Wall time per optimizer step (dispatch + writeback).",
+            "Host time of one TrainStep call from the executable's "
+            "dispatch to the end of write-back: the enqueue, not the "
+            "step's completion on the device (run_steps: per step of "
+            "the scan).",
             labels=("entry",)).labels(entry=_entry)
         self._m_compile = obs.counter(
             "mxtpu_train_compile_total",
@@ -671,11 +684,13 @@ class TrainStep:
 
         def step(train_vals, frozen_vals, opt_state, key_data, lrs, wds,
                  x, y):
-            (loss, raw_aux), grads = jax.value_and_grad(
-                loss_flat, has_aux=True)(train_vals, frozen_vals,
-                                         key_data, x, y)
-            new_vals, new_state = apply_updates(train_vals, grads,
-                                                opt_state, lrs, wds)
+            with jax.named_scope(_SCOPE_FWD_BWD):
+                (loss, raw_aux), grads = jax.value_and_grad(
+                    loss_flat, has_aux=True)(train_vals, frozen_vals,
+                                             key_data, x, y)
+            with jax.named_scope(_SCOPE_OPTIMIZER):
+                new_vals, new_state = apply_updates(
+                    train_vals, grads, opt_state, lrs, wds)
             return loss, new_vals, new_state, raw_aux
 
         if amp_on and not self.zero:
@@ -690,35 +705,41 @@ class TrainStep:
                         l, aux = loss_flat(tv, fv, kd, xx, yy)
                         return l * scale.astype(l.dtype), (l, aux)
 
-                    (_, (loss, raw_aux)), grads = jax.value_and_grad(
-                        scaled, has_aux=True)(train_vals, frozen_vals,
-                                              key_data, x, y)
-                    # grads reach the param edge in bf16 (AD transpose
-                    # of the entry upcast); unscale in f32 so the
-                    # finite test and the optimizer see full range
-                    grads = tuple(g.astype(jnp.float32) / scale
-                                  for g in grads)
-                    finite = _amp_mod.all_finite(grads)
-                    new_vals, new_state = apply_updates(
-                        train_vals, grads, opt_state, lrs, wds)
-                    # skipped step: keep params AND state, back off
-                    keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
-                    new_vals = tuple(map(keep, new_vals, train_vals))
-                    new_state = jax.tree_util.tree_map(
-                        keep, new_state, opt_state)
-                    scaler2 = _amp_mod.scaler_update(scaler, finite,
-                                                     window)
+                    with jax.named_scope(_SCOPE_FWD_BWD):
+                        (_, (loss, raw_aux)), grads = \
+                            jax.value_and_grad(scaled, has_aux=True)(
+                                train_vals, frozen_vals, key_data, x, y)
+                    with jax.named_scope(_SCOPE_OPTIMIZER):
+                        # grads reach the param edge in bf16 (AD
+                        # transpose of the entry upcast); unscale in
+                        # f32 so the finite test and the optimizer see
+                        # full range
+                        grads = tuple(g.astype(jnp.float32) / scale
+                                      for g in grads)
+                        finite = _amp_mod.all_finite(grads)
+                        new_vals, new_state = apply_updates(
+                            train_vals, grads, opt_state, lrs, wds)
+                        # skipped step: keep params AND state, back off
+                        keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
+                        new_vals = tuple(map(keep, new_vals,
+                                             train_vals))
+                        new_state = jax.tree_util.tree_map(
+                            keep, new_state, opt_state)
+                        scaler2 = _amp_mod.scaler_update(
+                            scaler, finite, window)
                     return loss, new_vals, new_state, raw_aux, scaler2
             else:
                 def step(train_vals, frozen_vals, opt_state, key_data,  # noqa: F811
                          lrs, wds, x, y):
-                    (loss, raw_aux), grads = jax.value_and_grad(
-                        loss_flat, has_aux=True)(train_vals,
-                                                 frozen_vals, key_data,
-                                                 x, y)
-                    grads = tuple(g.astype(jnp.float32) for g in grads)
-                    new_vals, new_state = apply_updates(
-                        train_vals, grads, opt_state, lrs, wds)
+                    with jax.named_scope(_SCOPE_FWD_BWD):
+                        (loss, raw_aux), grads = jax.value_and_grad(
+                            loss_flat, has_aux=True)(
+                                train_vals, frozen_vals, key_data, x, y)
+                    with jax.named_scope(_SCOPE_OPTIMIZER):
+                        grads = tuple(g.astype(jnp.float32)
+                                      for g in grads)
+                        new_vals, new_state = apply_updates(
+                            train_vals, grads, opt_state, lrs, wds)
                     return loss, new_vals, new_state, raw_aux
 
         if self.zero:
@@ -759,16 +780,21 @@ class TrainStep:
                           jax.random.key_data(key), zeros, zeros,
                           x_raw, y_raw) + self._amp_extra()
             t0 = _prof._now_us()
-            lowered = fitted.lower(*lower_args)
-            source, ckey, loaded, cmeta = "cold", None, None, {}
-            if self._cache is not None:
-                ckey = self._train_cache_key(lowered, x_raw, y_raw)
-                loaded, cmeta = self._cache.load(ckey, with_meta=True)
-            if loaded is not None:
-                source = "disk"
-                fn = loaded
-            else:
-                fn = lowered.compile()
+            with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
+                              kind="train",
+                              bucket=str(x_raw.shape)) as rg:
+                lowered = fitted.lower(*lower_args)
+                source, ckey, loaded, cmeta = "cold", None, None, {}
+                if self._cache is not None:
+                    ckey = self._train_cache_key(lowered, x_raw, y_raw)
+                    loaded, cmeta = self._cache.load(ckey,
+                                                     with_meta=True)
+                if loaded is not None:
+                    source = "disk"
+                    fn = loaded
+                else:
+                    fn = lowered.compile()
+                rg.set(source=source)
             mem = _mem_stats(fn)
             self._last_mem = mem
             from mxtpu import analysis
@@ -804,7 +830,11 @@ class TrainStep:
         return {"fn": fn, "raw_step": step,
                 "aux_params": aux_box["aux_params"],
                 "frozen_idx": frozen_idx, "aux_pos": aux_pos,
-                "mem": mem}
+                "mem": mem,
+                # what one call hands the executable (train/dispatch)
+                "leaves": 5 + len(jax.tree_util.tree_leaves(
+                    (train_vals, frozen_vals, self._opt_state,
+                     self._amp_extra())))}
 
     def _build_zero_step(self, loss_flat, x_raw, y_raw):
         """The ZeRO-1 step body: an explicit ``shard_map`` over
@@ -955,20 +985,22 @@ class TrainStep:
             me = lax.axis_index(dp_axis)
             # decorrelate dropout across shards (the GSPMD path gets
             # this for free from its globally-sharded RNG)
-            kd = jax.random.key_data(jax.random.fold_in(
-                jax.random.wrap_key_data(key_data), me))
-            (loss, raw_aux), grads = jax.value_and_grad(
-                loss_flat, has_aux=True)(train_vals, frozen_vals, kd,
-                                         x, y)
-            # loss_flat reduces over the LOCAL shard; equal shard
-            # sizes make the mean of shard means the global mean
-            loss = lax.psum(loss, dp_axis) / dp
-            raw_aux = tuple(
-                lax.pmean(a, dp_axis)
-                if jnp.issubdtype(a.dtype, jnp.inexact) else a
-                for a in raw_aux)
-            new_vals, new_state = apply_zero(train_vals, grads,
-                                             opt_state, lrs, wds)
+            with jax.named_scope(_SCOPE_FWD_BWD):
+                kd = jax.random.key_data(jax.random.fold_in(
+                    jax.random.wrap_key_data(key_data), me))
+                (loss, raw_aux), grads = jax.value_and_grad(
+                    loss_flat, has_aux=True)(train_vals, frozen_vals,
+                                             kd, x, y)
+                # loss_flat reduces over the LOCAL shard; equal shard
+                # sizes make the mean of shard means the global mean
+                loss = lax.psum(loss, dp_axis) / dp
+                raw_aux = tuple(
+                    lax.pmean(a, dp_axis)
+                    if jnp.issubdtype(a.dtype, jnp.inexact) else a
+                    for a in raw_aux)
+            with jax.named_scope(_SCOPE_OPTIMIZER):
+                new_vals, new_state = apply_zero(
+                    train_vals, grads, opt_state, lrs, wds)
             return loss, new_vals, new_state, raw_aux
 
         def body_amp(train_vals, frozen_vals, opt_state, key_data,
@@ -982,17 +1014,20 @@ class TrainStep:
                 l, aux = loss_flat(tv, fv, k2, xx, yy)
                 return l * scale.astype(l.dtype), (l, aux)
 
-            (_, (loss, raw_aux)), grads = jax.value_and_grad(
-                scaled, has_aux=True)(train_vals, frozen_vals, kd,
-                                      x, y)
-            loss = lax.psum(loss, dp_axis) / dp
-            raw_aux = tuple(
-                lax.pmean(a, dp_axis)
-                if jnp.issubdtype(a.dtype, jnp.inexact) else a
-                for a in raw_aux)
-            new_vals, new_state, finite = apply_zero_amp(
-                train_vals, grads, opt_state, lrs, wds, scale)
-            scaler2 = _amp_mod.scaler_update(scaler, finite, window)
+            with jax.named_scope(_SCOPE_FWD_BWD):
+                (_, (loss, raw_aux)), grads = jax.value_and_grad(
+                    scaled, has_aux=True)(train_vals, frozen_vals, kd,
+                                          x, y)
+                loss = lax.psum(loss, dp_axis) / dp
+                raw_aux = tuple(
+                    lax.pmean(a, dp_axis)
+                    if jnp.issubdtype(a.dtype, jnp.inexact) else a
+                    for a in raw_aux)
+            with jax.named_scope(_SCOPE_OPTIMIZER):
+                new_vals, new_state, finite = apply_zero_amp(
+                    train_vals, grads, opt_state, lrs, wds, scale)
+                scaler2 = _amp_mod.scaler_update(scaler, finite,
+                                                 window)
             return loss, new_vals, new_state, raw_aux, scaler2
 
         xspec = [None] * x_raw.ndim
@@ -1097,35 +1132,41 @@ class TrainStep:
         return tuple(jax.device_put(v, rs) for v in vals)
 
     def __call__(self, x, y):
-        x_raw, y_raw, sig = self._prep(x, y)
-        key = _rnd._next_key(None)
-        entry = self._entry_for(x_raw, y_raw, sig, key)
-        self._t += 1
-        lrs, wds = self._lrs_wds()
-        lrs, wds, kd = self._commit_small(lrs, wds,
-                                          jax.random.key_data(key))
-        params = self._params
-        train_vals = tuple(params[i]._data._data for i in self._train_idx)
-        frozen_vals = tuple(params[i]._data._data
-                            for i in entry["frozen_idx"])
-        if self._guards:
-            self._churn.note_call()
-        t0 = _prof._now_us() if self._obs else 0.0
-        with guards.no_implicit_transfers(self._guards):
-            out = entry["fn"](
-                train_vals, frozen_vals, self._opt_state,
-                kd, lrs, wds, x_raw, y_raw, *self._amp_extra())
-        loss, new_vals, new_state, raw_aux = out[:4]
-        if self._amp_scaler:
-            self._amp_state = out[4]
-        for i, v in zip(self._train_idx, new_vals):
-            params[i]._data._data = v
-        self._opt_state = new_state
-        for p, v in zip(entry["aux_params"], raw_aux):
-            p._data._data = v
-        if self._obs:
-            self._m_step.observe((_prof._now_us() - t0) / 1e6)
-        return NDArray(loss, None, _placed=True)
+        with self._region(obs.SPAN_TRAIN_STEP, t=self._t + 1):
+            with self._region(obs.SPAN_TRAIN_PREP):
+                x_raw, y_raw, sig = self._prep(x, y)
+                key = _rnd._next_key(None)
+                entry = self._entry_for(x_raw, y_raw, sig, key)
+                self._t += 1
+                lrs, wds = self._lrs_wds()
+                lrs, wds, kd = self._commit_small(
+                    lrs, wds, jax.random.key_data(key))
+                params = self._params
+                train_vals = tuple(params[i]._data._data
+                                   for i in self._train_idx)
+                frozen_vals = tuple(params[i]._data._data
+                                    for i in entry["frozen_idx"])
+            if self._guards:
+                self._churn.note_call()
+            t0 = _prof._now_us() if self._obs else 0.0
+            with self._region(obs.SPAN_TRAIN_DISPATCH,
+                              leaves=entry["leaves"]), \
+                    guards.no_implicit_transfers(self._guards):
+                out = entry["fn"](
+                    train_vals, frozen_vals, self._opt_state,
+                    kd, lrs, wds, x_raw, y_raw, *self._amp_extra())
+            with self._region(obs.SPAN_TRAIN_WRITEBACK):
+                loss, new_vals, new_state, raw_aux = out[:4]
+                if self._amp_scaler:
+                    self._amp_state = out[4]
+                for i, v in zip(self._train_idx, new_vals):
+                    params[i]._data._data = v
+                self._opt_state = new_state
+                for p, v in zip(entry["aux_params"], raw_aux):
+                    p._data._data = v
+            if self._obs:
+                self._m_step.observe((_prof._now_us() - t0) / 1e6)
+            return NDArray(loss, None, _placed=True)
 
     # -- bulked execution -------------------------------------------------
     def run_steps(self, x, y, steps: int, reuse_batch: bool = False):
@@ -1142,6 +1183,43 @@ class TrainStep:
         microbatches would waste HBM).  lr/wd schedules are sampled
         once per call (per-``steps`` granularity).  Returns the
         per-step losses as a ``(steps,)`` NDArray."""
+        with self._region(obs.SPAN_TRAIN_STEP, t=self._t + steps,
+                          steps=steps):
+            with self._region(obs.SPAN_TRAIN_PREP):
+                (entry, multi, train_vals, frozen_vals, keys, lrs, wds,
+                 xs, ys) = self._scan_prep(x, y, steps, reuse_batch)
+            if self._guards:
+                self._churn.note_call()
+            t0 = _prof._now_us() if self._obs else 0.0
+            with self._region(obs.SPAN_TRAIN_DISPATCH,
+                              leaves=entry["leaves"]), \
+                    guards.no_implicit_transfers(self._guards):
+                out = multi(
+                    train_vals, frozen_vals, self._opt_state, keys,
+                    lrs, wds, xs, ys, *self._amp_extra())
+            with self._region(obs.SPAN_TRAIN_WRITEBACK):
+                losses, tv, frozen, st = out[:4]
+                if self._amp_scaler:
+                    self._amp_state = out[4]
+                params = self._params
+                for i, v in zip(self._train_idx, tv):
+                    params[i]._data._data = v
+                for j, i in enumerate(entry["frozen_idx"]):
+                    params[i]._data._data = frozen[j]
+                self._opt_state = st
+            if self._obs:
+                # one sample of amortized per-step host time —
+                # dispatch is paid once for the whole scan, which is
+                # the point
+                self._m_step.observe(
+                    (_prof._now_us() - t0) / 1e6 / steps)
+            return NDArray(losses, None, _placed=True)
+
+    def _scan_prep(self, x, y, steps: int, reuse_batch: bool):
+        """Everything of :meth:`run_steps` before the dispatch: the
+        microbatches placed, the one-step entry and the scanned
+        program built (once per signature), and the call's
+        arguments."""
         if steps <= 0:
             raise MXNetError("run_steps needs steps >= 1")
         x_raw = x.data if isinstance(x, NDArray) else jnp.asarray(x)
@@ -1251,35 +1329,21 @@ class TrainStep:
                      or not _mesh_is_multiprocess(self.mesh))):
                 # AOT (as in _build): the scanned program's memory
                 # stats are what bench.py's hbm_peak reports
-                multi = multi.lower(
-                    train_vals, frozen_vals, self._opt_state, keys,
-                    lrs, wds, xs, ys, *self._amp_extra()).compile()
+                with self._region(obs.SPAN_COMPILE,
+                                  entry=self._entry_label,
+                                  kind="train_scan", bucket=str(msig),
+                                  source="cold"):
+                    multi = multi.lower(
+                        train_vals, frozen_vals, self._opt_state,
+                        keys, lrs, wds, xs, ys,
+                        *self._amp_extra()).compile()
                 self._last_mem = _mem_stats(multi)
                 from mxtpu import analysis
                 analysis.maybe_audit(multi, label="TrainStep.run_steps",
                                      mem=self._last_mem)
             self._compiled[msig] = multi
-        if self._guards:
-            self._churn.note_call()
-        t0 = _prof._now_us() if self._obs else 0.0
-        with guards.no_implicit_transfers(self._guards):
-            out = multi(
-                train_vals, frozen_vals, self._opt_state, keys, lrs, wds,
-                xs, ys, *self._amp_extra())
-        losses, tv, frozen, st = out[:4]
-        if self._amp_scaler:
-            self._amp_state = out[4]
-        for i, v in zip(self._train_idx, tv):
-            params[i]._data._data = v
-        for j, i in enumerate(entry["frozen_idx"]):
-            params[i]._data._data = frozen[j]
-        self._opt_state = st
-        if self._obs:
-            # one sample of amortized per-step wall time — dispatch is
-            # paid once for the whole scan, which is the point
-            self._m_step.observe(
-                (_prof._now_us() - t0) / 1e6 / steps)
-        return NDArray(losses, None, _placed=True)
+        return (entry, multi, train_vals, frozen_vals, keys, lrs, wds,
+                xs, ys)
 
     # -- introspection ----------------------------------------------------
     def cost_analysis(self, x, y):

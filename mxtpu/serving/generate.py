@@ -286,6 +286,7 @@ class GenerateRunner:
         self._churn = guards.ChurnDetector(
             self._entry_label, limit=len(self.buckets()) + 4)
         self._obs = obs.enabled()
+        self._region = obs.region_writer(self._obs)
         self._m_compile = obs.counter(
             "mxtpu_serving_compile_total",
             "Bucket executables actually compiled by XLA (cold "
@@ -492,15 +493,17 @@ class GenerateRunner:
         at its own step offset, and written back — so chunked prefill
         of a long prompt+prefix is just repeated calls at advancing
         step offsets.  Padding rows target the scratch slot."""
+        import jax
         import jax.numpy as jnp
 
         def fn(tokens, step, lane_idx, kv_big, param_vals):
-            idx = lane_idx.astype(jnp.int32)
-            kv_small = kv_big[:, :, idx]
-            logits, new_small = self._eval_incremental(
-                tokens, step, kv_small, param_vals)
-            kv_big = kv_big.at[:, :, idx].set(
-                new_small.astype(kv_big.dtype))
+            with jax.named_scope("gen/prefill_program"):
+                idx = lane_idx.astype(jnp.int32)
+                kv_small = kv_big[:, :, idx]
+                logits, new_small = self._eval_incremental(
+                    tokens, step, kv_small, param_vals)
+                kv_big = kv_big.at[:, :, idx].set(
+                    new_small.astype(kv_big.dtype))
             return logits, kv_big
 
         return fn
@@ -510,9 +513,12 @@ class GenerateRunner:
         (logits (slots,1,V), kv_big') — THE decode step: every slot
         advances one position; inactive slots compute ignored rows
         (masked attention keeps them finite)."""
+        import jax
+
         def fn(tokens, step, kv_big, param_vals):
-            return self._eval_incremental(tokens, step, kv_big,
-                                          param_vals)
+            with jax.named_scope("gen/decode_program"):
+                return self._eval_incremental(tokens, step, kv_big,
+                                              param_vals)
 
         return fn
 
@@ -554,34 +560,35 @@ class GenerateRunner:
             t0 = time.perf_counter()
             from mxtpu import analysis
             compiled, source, ckey, cmeta = None, "cold", None, {}
-            if self._cache is not None:
-                ckey = self._cache_key(bucket)
-                compiled, cmeta = self._cache.load(ckey, with_meta=True)  # mxlint: sync-point — disk, pre-serving
-                if compiled is not None:
-                    source = "disk"
-            if compiled is None:
-                fn = self._prefill_pure() if kind == "prefill" \
-                    else self._decode_pure()
-                # donation applied only where XLA honors it; on cpu
-                # it is a silent no-op, so skipping it keeps that
-                # backend's programs byte-identical
-                apply_donate = (self._donate and
-                                jax.default_backend() != "cpu")
-                with profiler.Task(f"generate:compile:{kind}"
-                                   f"{bucket[1]}"):
+            with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
+                              kind=kind, bucket=str(bucket[1])) as rg:
+                if self._cache is not None:
+                    ckey = self._cache_key(bucket)
+                    compiled, cmeta = self._cache.load(ckey, with_meta=True)  # mxlint: sync-point — disk, pre-serving
+                    if compiled is not None:
+                        source = "disk"
+                if compiled is None:
+                    fn = self._prefill_pure() if kind == "prefill" \
+                        else self._decode_pure()
+                    # donation applied only where XLA honors it; on
+                    # cpu it is a silent no-op, so skipping it keeps
+                    # that backend's programs byte-identical
+                    apply_donate = (self._donate and
+                                    jax.default_backend() != "cpu")
                     jitted = jax.jit(
                         fn, donate_argnums=(kv_argnum,)
                         if apply_donate else ())
                     compiled = jitted.lower(
                         *in_structs, self._param_structs).compile()
-                analysis.maybe_audit(compiled,
-                                     label=f"GenerateRunner{bucket}")
-                if ckey is not None:
-                    self._cache.store(ckey, compiled,
-                                      meta=analysis.audit_stamp())
-            elif analysis.needs_reaudit(cmeta):
-                analysis.maybe_audit(compiled,
-                                     label=f"GenerateRunner{bucket}")
+                    analysis.maybe_audit(
+                        compiled, label=f"GenerateRunner{bucket}")
+                    if ckey is not None:
+                        self._cache.store(ckey, compiled,
+                                          meta=analysis.audit_stamp())
+                elif analysis.needs_reaudit(cmeta):
+                    analysis.maybe_audit(
+                        compiled, label=f"GenerateRunner{bucket}")
+                rg.set(source=source)
             self.compile_seconds[bucket] = time.perf_counter() - t0
             entry = {"compiled": compiled, "in_structs": in_structs}
             self._entries[bucket] = entry
@@ -624,41 +631,44 @@ class GenerateRunner:
         match a ladder rung exactly (the batcher pads).  Returns
         (host logits (b, s, V), new device KV table) — the passed
         table is consumed (donated on accelerator backends)."""
-        import jax
         b, s = tokens.shape
-        entry = self._entry(("prefill", (b, s)))
-        tok = jax.device_put(np.asarray(tokens, np.float32),  # mxlint: sync-point — staging host rows for device_put
-                             self._device)
-        stp = jax.device_put(np.asarray(step, np.float32),  # mxlint: sync-point — staging host rows for device_put
-                             self._device)
-        idx = jax.device_put(np.asarray(lane_idx, np.float32),  # mxlint: sync-point — staging host rows for device_put
-                             self._device)
-        if self._guards:
-            self._churn.note_call()
-        with guards.no_implicit_transfers(self._guards):
-            logits, kv = entry["compiled"](tok, stp, idx, kv,
-                                           self._param_vals)
-        # mxlint: sync-point — deliberate D2H: the batcher samples on host
-        return np.asarray(logits), kv
+        return self._call(obs.SPAN_PREFILL_CALL, ("prefill", (b, s)),
+                          (tokens, step, lane_idx), kv,
+                          {"rows": b, "bucket": s})
 
     def decode(self, tokens: np.ndarray, step: np.ndarray, kv
                ) -> Tuple[np.ndarray, Any]:
         """THE decode step: ``tokens (slots, 1)`` / ``step (slots,)``
         advance every slot one position.  Returns (host logits
         (slots, 1, V), new device KV table)."""
+        return self._call(obs.SPAN_DECODE, ("decode", (self._slots,)),
+                          (tokens, step), kv, {"slots": self._slots})
+
+    def _call(self, name: str, bucket: Tuple,
+              host_rows: Sequence[np.ndarray], kv,
+              counts: Dict[str, int]) -> Tuple[np.ndarray, Any]:
+        """One executable call, in the region ``name`` with its three
+        children: the host rows staged on the device, the call itself,
+        the logits brought back."""
         import jax
-        entry = self._entry(("decode", (self._slots,)))
-        tok = jax.device_put(np.asarray(tokens, np.float32),  # mxlint: sync-point — staging host rows for device_put
-                             self._device)
-        stp = jax.device_put(np.asarray(step, np.float32),  # mxlint: sync-point — staging host rows for device_put
-                             self._device)
-        if self._guards:
-            self._churn.note_call()
-        with guards.no_implicit_transfers(self._guards):
-            logits, kv = entry["compiled"](tok, stp, kv,
-                                           self._param_vals)
-        # mxlint: sync-point — deliberate D2H: the batcher samples on host
-        return np.asarray(logits), kv
+        with self._region(name, **counts) as rg:
+            entry = self._entry(bucket)
+            with self._region(name + obs.SPAN_STAGE):
+                staged = [
+                    jax.device_put(np.asarray(a, np.float32),  # mxlint: sync-point — staging host rows for device_put
+                                   self._device)
+                    for a in host_rows]
+            if self._guards:
+                self._churn.note_call()
+            with self._region(name + obs.SPAN_DISPATCH), \
+                    guards.no_implicit_transfers(self._guards):
+                logits, kv = entry["compiled"](*staged, kv,
+                                               self._param_vals)
+            with self._region(name + obs.SPAN_FETCH):
+                # mxlint: sync-point — deliberate D2H: the batcher samples on host
+                logits = np.asarray(logits)
+            rg.set(logits_bytes=logits.nbytes)
+        return logits, kv
 
     # -- introspection / contracts ----------------------------------------
     def default_bucket(self, kind: str = "decode") -> Tuple:
@@ -813,6 +823,25 @@ class GenerateBatcher:
         # the slot table lives here; only the stepping thread touches
         # it (single stepper enforced by _step_lock)
         self._kv = None  # guarded-by: _step_lock
+        self._step_no = 0  # guarded-by: _step_lock — step() calls
+        # the operator's view of what the gen/* regions count
+        self._obs = obs.enabled()
+        self._region = obs.region_writer(self._obs)
+        self._m_admitted = obs.counter(
+            "mxtpu_gen_admitted_total",
+            "Generation requests that claimed a lane (joined the "
+            "running decode batch).")
+        self._m_evicted = obs.counter(
+            "mxtpu_gen_evicted_total",
+            "Lanes freed by a deadline that expired mid-decode.")
+        self._m_rung = obs.counter(
+            "mxtpu_gen_prefill_rung_total",
+            "Prefill groups by the ladder rung they ran on (rows = "
+            "batch rung, bucket = prompt bucket).",
+            labels=("rows", "bucket"))
+        self._m_lanes = obs.gauge(
+            "mxtpu_gen_lanes_active",
+            "Lanes occupied at the last decode step.")
 
     # -- submit side ------------------------------------------------------
     def submit(self, prompt: Sequence[int], *,
@@ -904,44 +933,63 @@ class GenerateBatcher:
     # -- the step ---------------------------------------------------------
     def step(self, now: Optional[float] = None) -> Dict[str, int]:
         """Advance the whole batch one decode step; returns counters
-        ``{"admitted", "active", "emitted", "finished"}``.  The join
+        ``{"admitted", "active", "emitted", "finished", "queued"}``
+        (``queued``: the queue's depth after admission).  The join
         point for queued requests AND the eviction point for finished/
         expired ones — continuous batching is exactly this loop."""
         with self._step_lock:
-            now = self._clock() if now is None else now
-            # (req, token, stream index, is_first, seconds since the
-            # request's previous emission) — fired outside all locks
-            emissions: List[Tuple[GenerateRequest, int, int, bool,
-                                  float]] = []
-            finished: List[GenerateRequest] = []
-            # (req, final value): resolved AFTER _fire so the future's
-            # done-callbacks (the fleet watcher) observe a fully
-            # delivered stream — completing first would let a watcher
-            # snapshot the ledger one token short of the final emission
-            completions: List[Tuple[GenerateRequest, List[int]]] = []
+            self._step_no += 1
+            with self._region(obs.SPAN_GEN_STEP, step=self._step_no,
+                              max_lanes=self.max_lanes) as rg:
+                out = self._step_locked(
+                    self._clock() if now is None else now)
+                rg.set(**out)
+            return out
+
+    def _step_locked(self, now: float) -> Dict[str, int]:
+        """The step proper, under ``_step_lock``."""
+        # (req, token, stream index, is_first, seconds since the
+        # request's previous emission) — fired outside all locks
+        emissions: List[Tuple[GenerateRequest, int, int, bool,
+                              float]] = []
+        finished: List[GenerateRequest] = []
+        # (req, final value): resolved AFTER _fire so the future's
+        # done-callbacks (the fleet watcher) observe a fully
+        # delivered stream — completing first would let a watcher
+        # snapshot the ledger one token short of the final emission
+        completions: List[Tuple[GenerateRequest, List[int]]] = []
+        with self._region(obs.SPAN_GEN_ADMIT, step=self._step_no) as rg:
             with self._cond:
                 if self._closed:
                     return {"admitted": 0, "active": 0, "emitted": 0,
-                            "finished": 0}
+                            "finished": 0, "queued": 0}
                 self._expire_queued_locked(now)
-                self._evict_deadlines_locked(now, finished)
+                evicted = self._evict_deadlines_locked(now, finished)
                 admitted = self._admit_locked(now)
-            if admitted:
-                self._prefill_locked(admitted, now, emissions, finished,
-                              completions)
-            with self._cond:
-                active = [(i, l) for i, l in enumerate(self._lanes)
-                          if l is not None]
-            n_active = len(active)
-            if active:
-                self._decode_locked(active, now, emissions, finished,
-                             completions)
-            self._fire(emissions, now)
-            for r, value in completions:
-                r._complete(value, now)
-            return {"admitted": len(admitted), "active": n_active,
-                    "emitted": len(emissions),
-                    "finished": len(finished)}
+                queued = len(self._queue)
+            rg.set(admitted=len(admitted), evicted=evicted,
+                   wait_us_sum=int(sum(now - r.t_submit
+                                       for _, r in admitted) * 1e6))
+        if self._obs:
+            self._m_admitted.inc(len(admitted))
+            self._m_evicted.inc(evicted)
+        if admitted:
+            self._prefill_locked(admitted, now, emissions, finished,
+                                 completions)
+        with self._cond:
+            active = [(i, l) for i, l in enumerate(self._lanes)
+                      if l is not None]
+        if self._obs:
+            self._m_lanes.set(len(active))
+        if active:
+            self._decode_locked(active, emissions, finished,
+                                completions)
+        self._fire(emissions, self._step_no)
+        for r, value in completions:
+            r._complete(value, now)
+        return {"admitted": len(admitted), "active": len(active),
+                "emitted": len(emissions),
+                "finished": len(finished), "queued": queued}
 
     def _finish_reason(self, r: GenerateRequest, lane: _Lane
                        ) -> Optional[str]:
@@ -971,10 +1019,10 @@ class GenerateBatcher:
 
     def _evict_deadlines_locked(self, now: float,
                                 finished: List[GenerateRequest]
-                                ) -> None:
+                                ) -> int:
         """Mid-decode deadline eviction: an expired lane frees at the
         step boundary — its caller gets RequestTimeout, never a late
-        stream."""
+        stream.  Returns how many lanes it freed."""
         n_evicted = 0
         for i, lane in enumerate(self._lanes):
             if lane is None:
@@ -989,6 +1037,7 @@ class GenerateBatcher:
                 finished.append(r)
         if n_evicted and self._on_timeout is not None:
             self._on_timeout(n_evicted)
+        return n_evicted
 
     def _admit_locked(self, now: float
                       ) -> List[Tuple[int, GenerateRequest]]:
@@ -1012,63 +1061,87 @@ class GenerateBatcher:
         return pairs
 
     def _prefill_locked(self, pairs: List[Tuple[int, GenerateRequest]],
-                 now: float, emissions, finished,
-                 completions) -> None:
+                        now: float, emissions, finished,
+                        completions) -> None:
         """Prefill the joiners' prompts (+ replay prefixes) into their
         claimed lanes and sample each one's first token.  Prompts
         longer than the bucket chunk at bucket width; batch padding
         rows target the scratch slot.  Device dispatches run outside
         ``_cond``; the lane-table commit reacquires it."""
         runner = self.runner
-        if self._kv is None:
-            self._kv = runner.new_cache()
         s = pairs[0][1].group
         b = runner.batch_rung_for(len(pairs))
         full = [r.prompt + r.prefix for _, r in pairs]
         need = [len(f) for f in full]
         chunks = max(1, math.ceil(max(need) / s))
-        first_logits: List[Optional[np.ndarray]] = [None] * len(pairs)
-        t0 = now * 1e6
-        for c in range(chunks):
-            base = c * s
-            tokens = np.zeros((b, s), np.float32)
-            step = np.zeros((b,), np.float32)
-            lidx = np.full((b,), runner.scratch_slot, np.float32)
-            for row, (lane, r) in enumerate(pairs):
-                if base >= need[row]:
-                    continue  # this row finished in an earlier chunk
-                valid = min(s, need[row] - base)
-                tokens[row, :valid] = full[row][base:base + valid]
-                step[row] = base
-                lidx[row] = lane
-            logits, self._kv = runner.prefill(tokens, step, lidx,
-                                              self._kv)
-            for row in range(len(pairs)):
-                last = need[row] - 1
-                if base <= last < base + s:
-                    first_logits[row] = logits[row, last - base]
-        with self._cond:
-            if self._closed:
-                # the batcher died between admit and commit: these
-                # joiners were already off the queue, so close()
-                # could not see them — fail them here, with partial
-                # state (nothing emitted yet) for replay
-                err = WorkerLost("generate: batcher closed during "
-                                 "prefill")
-                for _, r in pairs:
-                    if not r.done():
-                        r._fail(_lost_for(r, err), now)
-                        finished.append(r)
-                return
+        if self._obs:
+            self._m_rung.labels(rows=b, bucket=s).inc()
+        # one event for the group; trace_of finds it under each of its
+        # requests' ids, as it does every batch-level span
+        with self._region(obs.SPAN_PREFILL, step=self._step_no,
+                          rows=len(pairs), rung=b, bucket=s,
+                          chunks=chunks,
+                          trace_ids=[r.trace_id for _, r in pairs
+                                     if r.trace_id is not None]):
+            if self._kv is None:
+                self._kv = runner.new_cache()
+            first_logits: List[Optional[np.ndarray]] = \
+                [None] * len(pairs)
+            for c in range(chunks):
+                base = c * s
+                tokens = np.zeros((b, s), np.float32)
+                step = np.zeros((b,), np.float32)
+                lidx = np.full((b,), runner.scratch_slot, np.float32)
+                for row, (lane, r) in enumerate(pairs):
+                    if base >= need[row]:
+                        continue  # this row finished in an earlier chunk
+                    valid = min(s, need[row] - base)
+                    tokens[row, :valid] = full[row][base:base + valid]
+                    step[row] = base
+                    lidx[row] = lane
+                logits, self._kv = runner.prefill(tokens, step, lidx,
+                                                  self._kv)
+                for row in range(len(pairs)):
+                    last = need[row] - 1
+                    if base <= last < base + s:
+                        first_logits[row] = logits[row, last - base]
+            with self._cond:
+                if self._closed:
+                    # the batcher died between admit and commit: these
+                    # joiners were already off the queue, so close()
+                    # could not see them — fail them here, with
+                    # partial state (nothing emitted yet) for replay
+                    err = WorkerLost("generate: batcher closed during "
+                                     "prefill")
+                    for _, r in pairs:
+                        if not r.done():
+                            r._fail(_lost_for(r, err), now)
+                            finished.append(r)
+                    return
+                self._commit_first_tokens_locked(
+                    pairs, need, first_logits, emissions, finished,
+                    completions)
+                self._cond.notify_all()
+
+    def _commit_first_tokens_locked(self, pairs, need, first_logits,
+                                    emissions, finished, completions
+                                    ) -> None:
+        """Sample each joiner's first token and seat it in its lane
+        (under ``_cond``).  The tokens exist only now, after the
+        prefill: TTFT and the lanes' next gaps count from this reading
+        of the batcher's clock, not from the step's start."""
+        t_emit = self._clock()
+        with self._region(obs.SPAN_SAMPLE, step=self._step_no,
+                          lanes=len(pairs)):
             for row, (lane, r) in enumerate(pairs):
                 pos = need[row]  # absolute position of the 1st new token
                 tok = sample_token(first_logits[row], position=pos,
                                    seed=r.seed, top_k=r.top_k)
                 ln = _Lane(r, frontier=need[row], last_token=tok,
-                           t_last=now)
+                           t_last=t_emit)
                 r.tokens.append(tok)
                 emissions.append((r, tok, len(r.prefix), True,
-                                  now - r.t_submit))
+                                  t_emit - r.t_submit))
                 reason = self._finish_reason(r, ln)
                 if reason is not None:
                     r.finish_reason = reason
@@ -1077,15 +1150,9 @@ class GenerateBatcher:
                     finished.append(r)
                 else:
                     self._lanes[lane] = ln
-                if r.trace_id is not None and profiler.is_active():
-                    obs.span(obs.SPAN_PREFILL, t0, now * 1e6 - t0,
-                             trace_id=r.trace_id, cat="gen",
-                             lane=lane, prompt=len(r.prompt),
-                             prefix=len(r.prefix))
-            self._cond.notify_all()
 
-    def _decode_locked(self, active: List[Tuple[int, _Lane]], now: float,
-                emissions, finished, completions) -> None:
+    def _decode_locked(self, active: List[Tuple[int, _Lane]],
+                       emissions, finished, completions) -> None:
         """ONE decode dispatch over the whole slot table (each lane's
         last token written at its own frontier), then per-lane
         sampling, finish evaluation, and lane release."""
@@ -1097,21 +1164,26 @@ class GenerateBatcher:
             tokens[i, 0] = lane.last_token
             steps[i] = lane.frontier
         logits, self._kv = runner.decode(tokens, steps, self._kv)
+        # the tokens exist only now, after the decode: gaps are read
+        # off the batcher's clock here, not at the step's start
+        t_emit = self._clock()
         done: List[Tuple[int, _Lane, str]] = []
-        for i, lane in active:
-            r = lane.req
-            lane.frontier += 1   # last_token is now in the cache
-            dt = now - lane.t_last
-            pos = lane.frontier  # absolute position of the new token
-            tok = sample_token(logits[i, 0], position=pos,
-                               seed=r.seed, top_k=r.top_k)
-            lane.last_token = tok
-            lane.t_last = now
-            r.tokens.append(tok)
-            emissions.append((r, tok, r.emitted - 1, False, dt))
-            reason = self._finish_reason(r, lane)
-            if reason is not None:
-                done.append((i, lane, reason))
+        with self._region(obs.SPAN_SAMPLE, step=self._step_no,
+                          lanes=len(active)):
+            for i, lane in active:
+                r = lane.req
+                lane.frontier += 1   # last_token is now in the cache
+                dt = t_emit - lane.t_last
+                pos = lane.frontier  # absolute position of the new token
+                tok = sample_token(logits[i, 0], position=pos,
+                                   seed=r.seed, top_k=r.top_k)
+                lane.last_token = tok
+                lane.t_last = t_emit
+                r.tokens.append(tok)
+                emissions.append((r, tok, r.emitted - 1, False, dt))
+                reason = self._finish_reason(r, lane)
+                if reason is not None:
+                    done.append((i, lane, reason))
         with self._cond:
             self._steps += 1
             for i, lane, reason in done:
@@ -1124,27 +1196,29 @@ class GenerateBatcher:
                 finished.append(r)
             self._cond.notify_all()
 
-    def _fire(self, emissions, now: float) -> None:
+    def _fire(self, emissions, step_no: int) -> None:
         """Stream callbacks + per-token stats/spans, OUTSIDE every
         lock (on_token is arbitrary user code)."""
         stats = self._stats
         active = profiler.is_active()
-        for r, tok, index, is_first, dt in emissions:
-            if stats is not None:
-                if is_first and not r.prefix:
-                    # true time-to-first-token: submit -> first emit
-                    stats.record_ttft(max(0.0, dt) * 1e6)
-                else:
-                    stats.record_token(max(0.0, dt) * 1e6)
-            if active and r.trace_id is not None:
-                obs.span(obs.SPAN_TOKEN, now * 1e6, 0.0,
-                         trace_id=r.trace_id, cat="gen", token=tok,
-                         index=index)
-            if self.stream and r.on_token is not None:
-                try:
-                    r.on_token(tok, index)
-                except Exception:  # noqa: BLE001 — a stream consumer
-                    pass           # must never poison the decode loop
+        with self._region(obs.SPAN_FIRE, step=step_no,
+                          tokens=len(emissions)):
+            for r, tok, index, is_first, dt in emissions:
+                if stats is not None:
+                    if is_first and not r.prefix:
+                        # true time-to-first-token: submit -> first emit
+                        stats.record_ttft(max(0.0, dt) * 1e6)
+                    else:
+                        stats.record_token(max(0.0, dt) * 1e6)
+                if active and r.trace_id is not None:
+                    obs.span(obs.SPAN_TOKEN, profiler._now_us(), 0.0,
+                             trace_id=r.trace_id, cat="gen", token=tok,
+                             index=index)
+                if self.stream and r.on_token is not None:
+                    try:
+                        r.on_token(tok, index)
+                    except Exception:  # noqa: BLE001 — a stream
+                        pass  # consumer must never poison the loop
 
     # -- wind-down ---------------------------------------------------------
     def drain(self) -> bool:

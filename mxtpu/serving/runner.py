@@ -192,6 +192,7 @@ class ModelRunner:
         # compile events feed the registry AND the "compile" flight
         # recorder so a postmortem shows every cache miss with timing.
         self._obs = obs.enabled()
+        self._region = obs.region_writer(self._obs)
         self._m_compile = obs.counter(
             "mxtpu_serving_compile_total",
             "Bucket executables actually compiled by XLA (cold "
@@ -537,14 +538,14 @@ class ModelRunner:
             # load() and we fall through to the cold path.
             from mxtpu import analysis
             compiled, source, ckey, cmeta = None, "cold", None, {}
-            if self._cache is not None:
-                ckey = self._cache_key(bucket)
-                compiled, cmeta = self._cache.load(ckey, with_meta=True)  # mxlint: sync-point — disk, pre-serving
-                if compiled is not None:
-                    source = "disk"
-            if compiled is None:
-                with profiler.Task(f"serving:compile:b{batch}"
-                                   f"{'' if seq is None else f's{seq}'}"):
+            with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
+                              kind="serve", bucket=str(bucket)) as rg:
+                if self._cache is not None:
+                    ckey = self._cache_key(bucket)
+                    compiled, cmeta = self._cache.load(ckey, with_meta=True)  # mxlint: sync-point — disk, pre-serving
+                    if compiled is not None:
+                        source = "disk"
+                if compiled is None:
                     # donation applied only where XLA honors it; on
                     # cpu it is a silent no-op, so skipping it keeps
                     # that backend's programs byte-identical
@@ -553,28 +554,32 @@ class ModelRunner:
                     jitted = jax.jit(
                         self._pure_fn(),
                         donate_argnums=(0,) if apply_donate else ())
-                    compiled = jitted.lower(in_structs,
-                                            self._param_structs).compile()
-                # MXTPU_HLO_AUDIT: static hygiene pass over every
-                # bucket executable as it is born (warmup() therefore
-                # audits the whole ladder) — no host transfers, no f64
-                # creep, no layout-bracketed custom calls.  Audit
-                # BEFORE the store so a program that fails a raising
-                # audit never reaches disk.
-                analysis.maybe_audit(compiled,
-                                     label=f"ModelRunner{bucket}")
-                if ckey is not None:
-                    # serialize for the next process, stamped with
-                    # this process's audit modes; failures degrade to
-                    # a flight-recorder event inside store()
-                    self._cache.store(ckey, compiled,
-                                      meta=analysis.audit_stamp())
-            elif analysis.needs_reaudit(cmeta):
-                # the audit knobs are per-process: the writer audited
-                # less strictly than this process asks for (or not at
-                # all), so the reloaded program is audited here
-                analysis.maybe_audit(compiled,
-                                     label=f"ModelRunner{bucket}")
+                    compiled = jitted.lower(
+                        in_structs, self._param_structs).compile()
+                    # MXTPU_HLO_AUDIT: static hygiene pass over every
+                    # bucket executable as it is born (warmup()
+                    # therefore audits the whole ladder) — no host
+                    # transfers, no f64 creep, no layout-bracketed
+                    # custom calls.  Audit BEFORE the store so a
+                    # program that fails a raising audit never
+                    # reaches disk.
+                    analysis.maybe_audit(compiled,
+                                         label=f"ModelRunner{bucket}")
+                    if ckey is not None:
+                        # serialize for the next process, stamped
+                        # with this process's audit modes; failures
+                        # degrade to a flight-recorder event inside
+                        # store()
+                        self._cache.store(ckey, compiled,
+                                          meta=analysis.audit_stamp())
+                elif analysis.needs_reaudit(cmeta):
+                    # the audit knobs are per-process: the writer
+                    # audited less strictly than this process asks
+                    # for (or not at all), so the reloaded program is
+                    # audited here
+                    analysis.maybe_audit(compiled,
+                                         label=f"ModelRunner{bucket}")
+                rg.set(source=source)
             self.compile_seconds[bucket] = time.perf_counter() - t0
             entry = {"compiled": compiled, "in_structs": in_structs}
             self._entries[bucket] = entry

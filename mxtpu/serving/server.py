@@ -11,7 +11,8 @@ are uploaded once per replica, buckets share them, see runner.py).
 Every executed batch emits a chrome-trace span through
 ``mxtpu.profiler.record_span`` (cat ``serving``) so serving traffic
 lines up with training ops in trace dumps, and feeds the endpoint's
-Speedometer-style periodic log line.
+Speedometer-style periodic log line.  A generation endpoint's steps
+write their own spans (``obs.region``, ``gen/*``).
 """
 from __future__ import annotations
 
@@ -66,22 +67,16 @@ class _GenEndpoint:
                 # idle: no lanes, no queue — park briefly
                 self._stop.wait(0.005)
                 continue
-            t0 = profiler._now_us()
             try:
-                out = self.batcher.step()
+                # the step writes its own span (gen/step, with the
+                # lanes, admissions and tokens it counted)
+                self.batcher.step()
             except Exception:  # noqa: BLE001 — a failed decode step
                 # leaves every lane's state intact; back off and retry
                 # (a persistent failure surfaces as caller deadlines)
                 self.stats.bump("step_failures")
                 self._stop.wait(0.01)
                 continue
-            if out["emitted"] and profiler.is_active():
-                profiler.record_span(
-                    f"serve/{self.name}:v{self.version}:gen", t0,
-                    profiler._now_us() - t0, cat="serving",
-                    args={"lanes": out["active"],
-                          "admitted": out["admitted"],
-                          "tokens": out["emitted"]})
             self.stats.maybe_log()
 
     def stop(self) -> None:
