@@ -29,10 +29,11 @@ class MultiHeadAttention(HybridBlock):
     op.  Pass a second input (``memory``) at call time for
     cross-attention: queries come from ``x``, keys/values from
     ``memory`` (the decoder->encoder path of the seq2seq
-    transformer)."""
+    transformer).  ``cache_layer`` names the planes of the KV slot
+    table this block writes and reads in incremental mode."""
 
     def __init__(self, units, num_heads, dropout=0.0, causal=False,
-                 proj_bias=True, **kwargs):
+                 proj_bias=True, cache_layer=0, **kwargs):
         super().__init__(**kwargs)
         if units % num_heads:
             raise MXNetError(f"units {units} not divisible by "
@@ -40,6 +41,7 @@ class MultiHeadAttention(HybridBlock):
         self._units = units
         self._heads = num_heads
         self._causal = causal
+        self._cache_layer = int(cache_layer)
         self.qkv = nn.Dense(3 * units, flatten=False, use_bias=True)
         # proj_bias=False when a FusedResidualLayerNorm epilogue folds
         # the output bias (and dropout) into its fused kernel
@@ -57,27 +59,28 @@ class MultiHeadAttention(HybridBlock):
         split = lambda t: self._split_heads(F, t)
         if len(args) == 2:
             # incremental decode: (x, step, cache) — x holds the T new
-            # tokens, cache is (2, B, H, L, u/h) [k; v], step (B,) is
-            # each lane's write frontier.  Returns (out, new_cache).
+            # tokens, cache is the WHOLE slot table (layers, 2, B, H,
+            # L, u/h), step (B,) is each lane's write frontier.  The
+            # table is written in place and handed on, never taken
+            # apart: returns (out, the same table).
             step, cache = args
+            at = self._cache_layer
             qkv = self.qkv(x)
             q = split(F.slice_axis(qkv, axis=-1, begin=0, end=u))
             k = split(F.slice_axis(qkv, axis=-1, begin=u, end=2 * u))
             v = split(F.slice_axis(qkv, axis=-1, begin=2 * u,
                                    end=3 * u))
-            k_cache = F.squeeze(
-                F.slice_axis(cache, axis=0, begin=0, end=1), axis=0)
-            v_cache = F.squeeze(
-                F.slice_axis(cache, axis=0, begin=1, end=2), axis=0)
-            k_cache = F.kv_cache_write(k_cache, k, step)
-            v_cache = F.kv_cache_write(v_cache, v, step)
-            out = F.cached_attention(q, k_cache, v_cache, step)
+            cache = F.kv_cache_write(cache, k, step, layer=at, plane=0)
+            cache = F.kv_cache_write(cache, v, step, layer=at, plane=1)
+            out = F.cached_attention(
+                q, F.kv_cache_read(cache, layer=at, plane=0),
+                F.kv_cache_read(cache, layer=at, plane=1), step)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, -1, u))
             out = self.proj(out)
             if self.drop is not None:
                 out = self.drop(out)
-            return out, F.stack(k_cache, v_cache, axis=0)
+            return out, cache
         memory = args[0] if args else None
         if memory is None:
             qkv = self.qkv(x)
@@ -125,13 +128,14 @@ class TransformerEncoderCell(HybridBlock):
     LN(x + ffn)."""
 
     def __init__(self, units, hidden_size, num_heads, dropout=0.0,
-                 causal=False, **kwargs):
+                 causal=False, cache_layer=0, **kwargs):
         super().__init__(**kwargs)
         # output bias + dropout + residual + LN run as ONE fused
         # epilogue (kernels/layer_norm.py), so the sub-blocks emit the
         # raw GEMM output: no proj bias, no separate Dropout
         self.attn = MultiHeadAttention(units, num_heads, 0.0, causal,
-                                       proj_bias=False)
+                                       proj_bias=False,
+                                       cache_layer=cache_layer)
         self.ffn = PositionwiseFFN(units, hidden_size, 0.0,
                                    out_bias=False)
         self.ln1 = nn.FusedResidualLayerNorm(dropout)
@@ -158,7 +162,8 @@ class TransformerEncoder(HybridBlock):
         self.layers = nn.HybridSequential()
         for i in range(num_layers):
             cell = TransformerEncoderCell(
-                units, hidden_size, num_heads, dropout, causal)
+                units, hidden_size, num_heads, dropout, causal,
+                cache_layer=i)
             if remat:
                 # per-layer activation rematerialization: O(sqrt)-style
                 # memory for deep stacks (SURVEY §0)
@@ -167,17 +172,13 @@ class TransformerEncoder(HybridBlock):
 
     def hybrid_forward(self, F, x, *args):
         if args:
-            # incremental: cache is (num_layers, 2, B, H, L, u/h);
-            # per-layer slices are static (python loop over cells), so
-            # the whole stack still traces into one XLA program
+            # incremental: the slot table (num_layers, 2, B, H, L,
+            # u/h) is threaded whole through the cells; cell i writes
+            # and reads its own planes of it
             step, cache = args
-            outs = []
-            for i, cell in enumerate(self.layers):
-                c = F.squeeze(F.slice_axis(cache, axis=0, begin=i,
-                                           end=i + 1), axis=0)
-                x, c = cell(x, step, c)
-                outs.append(c)
-            return x, F.stack(*outs, axis=0)
+            for cell in self.layers:
+                x, cache = cell(x, step, cache)
+            return x, cache
         return self.layers(x)
 
 
@@ -188,11 +189,12 @@ class TransformerDecoderCell(HybridBlock):
     seq2seq line)."""
 
     def __init__(self, units, hidden_size, num_heads, dropout=0.0,
-                 **kwargs):
+                 cache_layer=0, **kwargs):
         super().__init__(**kwargs)
         self.self_attn = MultiHeadAttention(units, num_heads, 0.0,
                                             causal=True,
-                                            proj_bias=False)
+                                            proj_bias=False,
+                                            cache_layer=cache_layer)
         self.cross_attn = MultiHeadAttention(units, num_heads, 0.0,
                                              proj_bias=False)
         self.ffn = PositionwiseFFN(units, hidden_size, 0.0,
@@ -225,9 +227,10 @@ class TransformerDecoder(HybridBlock):
                  dropout=0.0, remat=False, **kwargs):
         super().__init__(**kwargs)
         self.layers = nn.HybridSequential()
-        for _ in range(num_layers):
+        for i in range(num_layers):
             cell = TransformerDecoderCell(units, hidden_size,
-                                          num_heads, dropout)
+                                          num_heads, dropout,
+                                          cache_layer=i)
             if remat:
                 cell.set_remat(True)
             self.layers.add(cell)
@@ -235,13 +238,9 @@ class TransformerDecoder(HybridBlock):
     def hybrid_forward(self, F, x, memory, *args):
         if args:
             step, cache = args
-            outs = []
-            for i, cell in enumerate(self.layers):
-                c = F.squeeze(F.slice_axis(cache, axis=0, begin=i,
-                                           end=i + 1), axis=0)
-                x, c = cell(x, memory, step, c)
-                outs.append(c)
-            return x, F.stack(*outs, axis=0)
+            for cell in self.layers:
+                x, cache = cell(x, memory, step, cache)
+            return x, cache
         for cell in self.layers:
             x = cell(x, memory)
         return x
@@ -306,8 +305,11 @@ class TransformerModel(HybridBlock):
         return x
 
     def kv_cache_spec(self, batch_size, max_len=None):
-        """Shape of the stacked decoder self-attention KV cache this
-        model consumes/returns in incremental mode."""
+        """Shape of the decoder self-attention KV slot table this model
+        takes and hands back (written in place, layer by layer) in
+        incremental mode: (num_layers, 2, B, num_heads, L,
+        units // num_heads) — one array, plane ``[i, 0]`` layer i's
+        keys and ``[i, 1]`` its values."""
         L = self._max_length if max_len is None else int(max_len)
         return (self._num_layers, 2, int(batch_size), self._num_heads,
                 L, self._units // self._num_heads)
@@ -317,7 +319,7 @@ class TransformerModel(HybridBlock):
             # incremental decode: (src, tgt_new, step, cache).  The
             # encoder runs full on src each call (prefill recomputes
             # it; the decode path feeds the same bucketed src), the
-            # decoder consumes/returns per-layer KV state.
+            # decoder writes its layers' planes of the one KV table.
             step, cache = args
             memory = self.encoder(self._embed(F, src, pos_embed))
             x = self._embed_at(F, tgt, step, pos_embed,
@@ -358,9 +360,11 @@ class BERTModel(HybridBlock):
         self.mlm = nn.Dense(vocab_size, flatten=False)
 
     def kv_cache_spec(self, batch_size, max_len=None):
-        """Shape of the stacked per-layer KV cache this model
-        consumes/returns in incremental mode:
-        (num_layers, 2, B, num_heads, L, units // num_heads)."""
+        """Shape of the KV slot table this model takes and hands back
+        (written in place, layer by layer) in incremental mode:
+        (num_layers, 2, B, num_heads, L, units // num_heads) — one
+        array, plane ``[i, 0]`` layer i's keys and ``[i, 1]`` its
+        values."""
         L = self._max_length if max_len is None else int(max_len)
         return (self._num_layers, 2, int(batch_size), self._num_heads,
                 L, self._units // self._num_heads)

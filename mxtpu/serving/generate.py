@@ -9,11 +9,17 @@ Three pieces:
   (batch-rung x prompt-bucket) and ONE incremental *decode-step*
   executable over a preallocated bucket-paged KV cache.  The cache is
   a slot table: each in-flight request owns a cache *lane* (axis 2 of
-  the stacked ``(num_layers, 2, slots, heads, L, head_dim)`` array);
-  ``kv_cache_write`` (``lax.dynamic_update_slice`` under vmap) writes
-  each lane at its OWN step index and ``cached_attention`` masks
-  scores to each lane's valid prefix, so stale cache beyond a lane's
-  frontier is unreachable and lane reuse needs no zeroing.  Both
+  the one ``(num_layers, 2, slots, heads, L, head_dim)`` array).  The
+  graph threads the whole table through its layers: layer i's
+  ``kv_cache_write`` (a loop over the lanes, each turn one
+  ``lax.dynamic_update_slice`` on the table) puts each lane's new
+  rows at its OWN step index of planes
+  ``(i, 0)`` and ``(i, 1)``, ``kv_cache_read`` hands those planes to
+  ``cached_attention``, which masks scores to each lane's valid
+  prefix, so stale cache beyond a lane's frontier is unreachable and
+  lane reuse needs no zeroing.  Nothing cuts the table apart or stacks
+  it anew, so the donated table is updated in place: the table a
+  program returns is the buffer it was given.  Both
   executables load-or-compile through the persistent disk cache
   (ISSUE 13) under generation-specific keys, so a rollout's first
   token on a warmed worker is never a compile.
@@ -306,6 +312,12 @@ class GenerateRunner:
             "In-process compile-cache misses served from the "
             "persistent disk cache instead of XLA.",
             labels=("entry",)).labels(entry=self._entry_label)
+        self._m_temp_bytes = obs.gauge(
+            "mxtpu_gen_program_temp_bytes",
+            "Temporary bytes the compiled generation program needs "
+            "beside its arguments and outputs (a rebuilt KV table "
+            "shows here as a table's worth).",
+            labels=("kind", "bucket"))
 
         from .. import cache as cache_mod
         self._cache = cache_mod.default_cache() if cache == "auto" \
@@ -589,11 +601,18 @@ class GenerateRunner:
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
                 rg.set(source=source)
+                temp_bytes = (analysis.mem_stats(compiled) or {}).get(
+                    "temp_size_in_bytes")
+                if temp_bytes is not None:
+                    rg.set(temp_bytes=temp_bytes)
             self.compile_seconds[bucket] = time.perf_counter() - t0
             entry = {"compiled": compiled, "in_structs": in_structs}
             self._entries[bucket] = entry
             self._compile_sources[bucket] = source
             if self._obs:
+                if temp_bytes is not None:
+                    self._m_temp_bytes.labels(
+                        kind=kind, bucket=str(bucket[1])).set(temp_bytes)
                 if source == "cold":
                     self._m_compile.inc()
                 else:

@@ -140,3 +140,56 @@ def test_kernel_compiles_for_v5e(case, one_chip, on_tpu,
     assert calls.get("tpu_custom_call", {}).get("count", 0) >= 1, \
         f"{case}: no Pallas custom call in the compiled program — " \
         f"the lax reference was taken: {calls}"
+
+
+def test_kv_table_is_written_in_place_on_v5e(one_chip, monkeypatch,
+                                             no_persistent_cache,
+                                             request):
+    """The serve cell's slot table at its real widths (32 slots, 16
+    heads, 512 positions, head_dim 64, float32; two layers of the 24):
+    written by ``kv_cache_write``, read by ``cached_attention``, and
+    handed back.  The chip's compiler must alias the donated table to
+    the result and need less than one cache plane of temporaries — a
+    table copied, or carried through the lanes' loop in another
+    layout, shows as a table's worth."""
+    from jax.experimental.layout import Layout
+    from mxtpu.ndarray import rnn_impl
+    # the default device here is the CPU; this is the chip's answer
+    # for the table's shape (PERF.md, PR 26)
+    monkeypatch.setattr(
+        rnn_impl, "_resident_layout",
+        lambda x: Layout(major_to_minor=(0, 1, 2, 3, 5, 4),
+                         tiling=((8, 128),)))
+    # the lanes' loop keeps its traces: none made with the other
+    # answer may serve here, nor this one serve a later test
+    rnn_impl._write_lanes.clear_cache()
+    request.addfinalizer(rnn_impl._write_lanes.clear_cache)
+    layers, slots, heads, cap, dim = 2, 32, 16, 512, 64
+
+    def step(table, k, v, q, at):
+        # as in the model, a layer's keys and values come from the
+        # layer below: its reads of the table end before they are made
+        out = jnp.zeros_like(q)
+        for i in range(layers):
+            table = rnn_impl._kv_cache_write_op(table, k[i] + out, at,
+                                                layer=i, plane=0)
+            table = rnn_impl._kv_cache_write_op(table, v[i] + out, at,
+                                                layer=i, plane=1)
+            out = rnn_impl._cached_attention_op(
+                q + out,
+                rnn_impl._kv_cache_read_op(table, layer=i, plane=0),
+                rnn_impl._kv_cache_read_op(table, layer=i, plane=1), at)
+        return out, table
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    from mxtpu import analysis
+    _, mem = analysis.compiled_artifact(
+        step, sds(layers, 2, slots, heads, cap, dim),
+        sds(layers, slots, heads, 1, dim),
+        sds(layers, slots, heads, 1, dim), sds(slots, heads, 1, dim),
+        sds(slots), donate_argnums=0)
+    plane = slots * heads * cap * dim * 4
+    assert mem["alias_size_in_bytes"] == layers * 2 * plane
+    assert mem["temp_size_in_bytes"] < plane, mem

@@ -11,7 +11,10 @@ yield ZERO wrong or duplicated tokens and an exactly resumed stream,
 reconstructable from the request's one trace id.
 """
 import json
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,7 +22,9 @@ import mxtpu as mx
 from mxtpu import obs, profiler
 from mxtpu.base import MXNetError
 from mxtpu.cache import ExecutableCache
-from mxtpu.models.transformer import BERTModel
+from mxtpu.gluon.block import HybridBlock
+from mxtpu.models.transformer import BERTModel, TransformerModel
+from mxtpu.ops.registry import get_op
 from mxtpu.serving import (FleetGenerateRequest, FleetRouter,
                            FleetWorker, GenerateBatcher,
                            GenerateRunner, InferenceServer,
@@ -41,10 +46,14 @@ class FakeClock:
         self.t += dt
 
 
+def _bert():
+    return BERTModel(V, U, HID, NL, NH, max_length=L, dropout=0.0,
+                     use_token_type=False, causal=True)
+
+
 @pytest.fixture(scope="module")
 def net():
-    n = BERTModel(V, U, HID, NL, NH, max_length=L, dropout=0.0,
-                  use_token_type=False, causal=True)
+    n = _bert()
     n.initialize()
     n.hybridize()
     # trace the incremental signature once so export carries the
@@ -67,9 +76,7 @@ def export(net, tmp_path_factory):
 
 def _runner(export, **kw):
     sym_file, param_file = export
-    net_spec = BERTModel(V, U, HID, NL, NH, max_length=L, dropout=0.0,
-                         use_token_type=False,
-                         causal=True).kv_cache_spec(LANES, L)
+    net_spec = _bert().kv_cache_spec(LANES, L)
     kw.setdefault("prompt_buckets", (4, 8))
     kw.setdefault("cache", None)
     return GenerateRunner.from_export(sym_file, param_file, net_spec,
@@ -184,13 +191,16 @@ def test_runner_rejects_bad_kv_spec(export):
 
 def test_decode_program_contains_kv_update_write(runner):
     """The decode-step program writes the KV cache IN PLACE at each
-    lane's own step index — per-lane ``lax.dynamic_update_slice``
-    vmapped over lanes, which lowers to scatter in the as-written HLO
-    (hlocheck pins the compiled artifact).  The cache must thread
-    through as an updated operand, never be rebuilt from scratch."""
+    lane's own step index — a loop over the lanes round one
+    ``lax.dynamic_update_slice`` on the whole slot table, in the
+    as-written HLO and in the compiled artifact alike (hlocheck pins
+    the latter).  The cache must thread through as an updated operand,
+    never be rebuilt from scratch."""
     text = runner.lowered_program_text(("decode", (LANES + 1,)))
-    assert "scatter" in text or "dynamic-update-slice" in text or \
+    assert "dynamic-update-slice" in text or \
         "dynamic_update_slice" in text
+    compiled, _ = runner.program_artifact(("decode", (LANES + 1,)))
+    assert "dynamic-update-slice" in compiled
 
 
 def test_greedy_decode_matches_full_forward(net, runner):
@@ -589,3 +599,224 @@ def test_server_generator_registry_guards(export):
     with pytest.raises(MXNetError):
         srv.generate("g", [1], max_tokens=1)
     srv.close()
+
+
+# ------------------------------- the slot table, in place (ISSUE 26)
+
+class _TargetOnly(HybridBlock):
+    """``TransformerModel`` as the 3-input incremental graph a
+    ``GenerateRunner`` takes: the source sentence is token 0 at the
+    target's own shape, so only the decoder's cached self-attention
+    tells one call from the next."""
+
+    def __init__(self, model, **kwargs):
+        super().__init__(**kwargs)
+        self.model = model
+
+    def kv_cache_spec(self, *args):
+        return self.model.kv_cache_spec(*args)
+
+    def hybrid_forward(self, F, tgt, step, cache):
+        return self.model(F.zeros_like(tgt), tgt, step, cache)
+
+
+def _seq2seq():
+    return _TargetOnly(TransformerModel(V, U, HID, NL, NH, max_length=L,
+                                        dropout=0.0))
+
+
+@pytest.fixture(scope="module", params=[_bert, _seq2seq],
+                ids=["BERTModel", "TransformerModel"])
+def any_export(request, tmp_path_factory):
+    """(symbol file, params file, kv spec) of either model family's
+    incremental graph: they share ``MultiHeadAttention``'s branch."""
+    n = request.param()
+    n.initialize()
+    n.hybridize()
+    n(mx.nd.array(np.ones((1, 3))), mx.nd.array(np.zeros(1)),
+      mx.nd.array(np.zeros(n.kv_cache_spec(1), np.float32)))
+    d = tmp_path_factory.mktemp("inplace")
+    return n.export(str(d / "gen")) + (n.kv_cache_spec(LANES, L),)
+
+
+def _any_runner(any_export):
+    sym_file, param_file, spec = any_export
+    return GenerateRunner.from_export(sym_file, param_file, spec,
+                                      prompt_buckets=(4, 8), cache=None)
+
+
+@pytest.fixture(scope="module")
+def any_runner(any_export):
+    return _any_runner(any_export)
+
+
+def _moves_of_a_plane(text, plane):
+    """``[(opcode, elements)]`` of the instructions of a compiled
+    program that move ``plane`` elements or more by ``copy``,
+    ``concatenate`` or ``transpose``: what a table that is taken apart
+    and re-stacked leaves behind."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* "
+                      r"(copy|concatenate|transpose)\(", line)
+        if m:
+            n = int(np.prod([int(d) for d in m.group(1).split(",")]))
+            if n >= plane:
+                out.append((m.group(2), n))
+    return out
+
+
+@pytest.mark.parametrize("bucket", [("decode", (LANES + 1,)),
+                                    ("prefill", (2, 4))],
+                         ids=["decode", "prefill"])
+def test_compiled_programs_never_move_a_cache_plane(any_runner, bucket):
+    """The table that leaves a generation program is the table that
+    entered it, written in place.  The compiled text holds no ``copy``,
+    ``concatenate`` or ``transpose`` of a piece of the table from one
+    cache plane ``(lanes, heads, L, head_dim)`` up — the cut and the
+    re-stack of the layers.  Only the program's whole table may move:
+    in decode once, at the entry, because the CPU backend does not
+    donate (on the chip the table's parameter is aliased to the
+    result); in prefill also where the admitted lanes are gathered and
+    scattered back, which is the runner's and stays."""
+    r = any_runner
+    text, _ = r.program_artifact(bucket)
+    lanes = r._slots if bucket[0] == "decode" else bucket[1][0]
+    plane = lanes * int(np.prod(r._kv_shape[3:]))
+    moves = _moves_of_a_plane(text, plane)
+    assert [m for m in moves if m[1] < NL * 2 * plane] == []
+    assert not [m for m in moves if m[0] == "concatenate"]
+    if bucket[0] == "decode":
+        assert len(moves) <= 1, moves
+
+
+def _write_by_slice_and_stack(table, new, step, layer=0, plane=0):
+    """The plain formulation the programs had before ISSUE 26, kept as
+    the reference: cut the plane out, write each lane's rows at its own
+    frontier, stack the planes into a new table."""
+    idx = jnp.asarray(step).astype(jnp.int32)
+    cut = jax.vmap(
+        lambda c, n, s: jax.lax.dynamic_update_slice(
+            c, n.astype(c.dtype), (0, s, 0)))(table[layer, plane], new,
+                                              idx)
+    return jnp.stack([
+        jnp.stack([cut if (i, w) == (layer, plane) else table[i, w]
+                   for w in range(table.shape[1])], axis=0)
+        for i in range(table.shape[0])], axis=0)
+
+
+def _serve_by_hand(r):
+    """Every logits array and the final table of: two lanes prefilled
+    together, lane 0's prompt continued by a second chunk, two decode
+    steps at frontiers (8, 3), lane 1 handed to a new prompt (its stale
+    rows stay), three more decode steps at (10, 4)."""
+    f32 = np.float32
+    out = []
+    kv = r.new_cache()
+
+    def prefill(tokens, step, lanes):
+        nonlocal kv
+        logits, kv = r.prefill(np.array(tokens, f32), np.array(step, f32),
+                               np.array(lanes, f32), kv)
+        out.append(logits)
+
+    def decode(tokens, step):
+        nonlocal kv
+        logits, kv = r.decode(np.array(tokens, f32)[:, None],
+                              np.array(step, f32), kv)
+        out.append(logits)
+
+    prefill([[3, 7, 1, 4], [5, 2, 6, 0]], [0, 0], [0, 1])
+    prefill([[9, 8, 2, 2]], [4], [0])
+    decode([11, 12, 0], [8, 3, 0])
+    decode([13, 14, 0], [9, 4, 0])
+    prefill([[21, 22, 23, 24]], [0], [1])
+    for t in range(3):
+        decode([15 + t, 25 + t, 0], [10 + t, 4 + t, 0])
+    return out, np.asarray(kv)
+
+
+def test_in_place_table_equals_slice_and_stack(any_export, any_runner,
+                                               monkeypatch):
+    """Lanes at different frontiers, a chunked prefill and a reused
+    lane: the programs that write the table in place give the logits
+    and the table of programs traced from the same graph with the
+    slice/write/stack reference in the write's place."""
+    got, got_kv = _serve_by_hand(any_runner)
+    ref = _any_runner(any_export)
+    with monkeypatch.context() as m:
+        m.setattr(get_op("kv_cache_write"), "fn",
+                  _write_by_slice_and_stack)
+        ref.warmup([("prefill", (2, 4)), ("prefill", (1, 4)),
+                    ("decode", (LANES + 1,))])
+    # the reference is what it says: its table is stacked anew
+    assert "concatenate" in ref.program_artifact()[0]
+    want, want_kv = _serve_by_hand(ref)
+    assert len(got) == len(want) == 8
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_kv, want_kv, rtol=1e-6, atol=1e-6)
+    # the scratch slot aside, the two lanes did get rows
+    assert np.abs(got_kv[:, :, :LANES, :, :10]).sum() > 0
+
+
+def test_kv_cache_write_rows_frontiers_cast_and_clamp():
+    """The write alone against a loop over lanes: T rows at each
+    lane's own frontier of the named plane and nowhere else, cast to
+    the table's dtype (a bf16 table stays bf16 whatever the compute
+    dtype), a write past the end of ``L`` clamped to end there."""
+    rng = np.random.RandomState(0)
+    layers, B, H, cap, D, T = 3, 4, 2, 8, 4, 3
+    table = rng.randn(layers, 2, B, H, cap, D).astype(np.float32)
+    new = rng.randn(B, H, T, D).astype(np.float32)
+    step = np.array([0, 2, 5, 7], np.float32)     # 7 + 3 > 8: clamps to 5
+    for dtype in (jnp.float32, jnp.bfloat16):
+        t0 = jnp.asarray(table).astype(dtype)
+        out = mx.nd.kv_cache_write(
+            mx.nd.NDArray(t0, None, _placed=True), mx.nd.array(new),
+            mx.nd.array(step), layer=1, plane=1)
+        assert out.data.dtype == dtype
+        want = np.array(t0.astype(jnp.float32))
+        for b, s in enumerate([0, 2, 5, 5]):
+            want[1, 1, b, :, s:s + T] = np.asarray(
+                jnp.asarray(new[b]).astype(dtype).astype(jnp.float32))
+        np.testing.assert_array_equal(
+            np.asarray(out.data.astype(jnp.float32)), want)
+        plane = mx.nd.kv_cache_read(out, layer=1, plane=1)
+        np.testing.assert_array_equal(
+            np.asarray(plane.data.astype(jnp.float32)), want[1, 1])
+
+
+def test_write_casts_to_a_bf16_table_under_amp(net):
+    """Under ``amp`` the cache may be bfloat16: the incremental forward
+    hands back a bfloat16 table with the new rows in it, and logits
+    near the float32 run's."""
+    from mxtpu import amp
+    tokens = mx.nd.array(np.array([[3, 7, 1, 4]], np.float32))
+    step = mx.nd.array(np.zeros(1))
+    spec = net.kv_cache_spec(1)
+    ref, _ = net(tokens, step, mx.nd.array(np.zeros(spec, np.float32)))
+    with amp.autocast():
+        out, cache = net(tokens, step, mx.nd.NDArray(
+            jnp.zeros(spec, jnp.bfloat16), None, _placed=True))
+    assert cache.data.dtype == jnp.bfloat16
+    rows = np.asarray(cache.data.astype(jnp.float32))
+    assert np.abs(rows[:, :, 0, :, :4]).min(axis=(2, 3, 4)).all()
+    assert not rows[:, :, 0, :, 4:].any()
+    np.testing.assert_allclose(out.asnumpy(), ref.asnumpy(), atol=0.1)
+
+
+def test_cache_attributes_survive_the_symbol_json(any_export):
+    """``layer`` and ``plane`` are static attributes of the write and
+    of the read: the graph a runner loads from ``-symbol.json`` names
+    every (layer, k|v) plane once on either."""
+    sym_file = any_export[0]
+    loaded = mx.sym.load(sym_file)
+    again = mx.sym.load_json(loaded.tojson())
+    planes = sorted((i, w) for i in range(NL) for w in (0, 1))
+    for sym in (loaded, again):
+        for op in ("kv_cache_write", "kv_cache_read"):
+            seen = sorted(
+                (int(n.attrs["layer"]), int(n.attrs["plane"]))
+                for n in sym._topo() if n.op == op)
+            assert seen == planes, (op, seen)

@@ -329,8 +329,16 @@ def test_compile_regions_count_new_buckets_only(export, tmp_path):
     assert sorted((e[3]["kind"], e[3]["bucket"]) for e in built) == \
         [("decode", f"({LANES + 1},)"), ("prefill", "(1, 4)")]
     assert all(e[3]["entry"].startswith("GenerateRunner") for e in built)
-    assert [e[3]["source"] for e in s.named("compile/done")] == \
-        ["cold", "cold"]
+    done = s.named("compile/done")
+    assert [e[3]["source"] for e in done] == ["cold", "cold"]
+    # each program's temporary bytes: the count a rebuilt KV table
+    # shows in, and the operator's gauge of the same number
+    temps = sorted(int(e[3]["temp_bytes"]) for e in done)
+    assert all(t > 0 for t in temps)
+    gauge = obs.snapshot()["mxtpu_gen_program_temp_bytes"]["series"]
+    assert sorted(int(v["value"]) for v in gauge
+                  if v["labels"]["bucket"] in
+                  (f"({LANES + 1},)", "(1, 4)")) == temps
     with _Session(tmp_path / "second") as s:
         _serve(r, prompts=((1, 2, 3),), max_tokens=2)
     assert s.named("compile") == [] and s.named("gen/decode")

@@ -382,8 +382,8 @@ def _generate_runner(amp: bool = False):
     19): hybrid-forward with (step, cache) extra inputs exported, then
     a GenerateRunner over a 2-lane bucket-paged KV cache.  The decode
     contract this pins: the per-lane ``dynamic-update-slice`` KV
-    write + masked cached attention, single fused device program, no
-    host round-trips inside the step."""
+    write into the whole slot table + masked cached attention, single
+    fused device program, no host round-trips inside the step."""
     import os
     import tempfile
     from mxtpu import nd
@@ -411,8 +411,9 @@ def generate_decode() -> Dict[str, Artifact]:
     """Generation ladder: every (batch-rung x prompt-bucket) prefill
     executable plus THE decode-step executable.  The decode entry is
     the per-token serving contract — its compiled text must carry the
-    slot-table ``dynamic-update-slice`` KV writes (one per layer per
-    k/v) and no host transfer."""
+    slot-table ``dynamic-update-slice`` KV writes (one per layer and
+    per k/v, in a loop over the slots, each on the whole table, which
+    no ``copy`` or ``concatenate`` rebuilds) and no host transfer."""
     runner = _generate_runner()
     runner.warmup()
     out: Dict[str, Artifact] = {}
