@@ -602,13 +602,28 @@ def generate_record(runner, target: str = "generate",
     data arg of every entry); the kv section pins table bytes ==
     declared ``kv_cache_spec`` geometry + 1 scratch slot — the
     equality the kv-overcommit rule guards."""
+    import jax.numpy as jnp
     import numpy as np
     weight_bytes = runner.weight_bytes()
-    itemsize = 4  # the slot table is float32 (new_cache)
-    table_bytes = int(np.prod(runner._kv_shape,
-                              dtype=np.int64)) * itemsize
+    # every state table the runner declares (one float32 KV table for
+    # a 6-tuple kv_spec; a state spec names several, each in its own
+    # dtype): allocated bytes against the declared lanes + 1 scratch
+    allocated = runner.state_bytes()
+    table_bytes = sum(allocated.values())
+    tables = []
+    for t in runner.state_spec:
+        shape = t.shape[:t.lane_axis] + (t.shape[t.lane_axis] + 1,) \
+            + t.shape[t.lane_axis + 1:]
+        tables.append({"name": t.name, "spec": list(t.shape),
+                       "lane_axis": t.lane_axis, "dtype": t.dtype,
+                       "table_bytes": allocated[t.name],
+                       "expected_bytes": int(np.prod(
+                           shape, dtype=np.int64))
+                       * jnp.dtype(t.dtype).itemsize})
     spec = tuple(runner.kv_spec)
-    expected = kv_expected_bytes(spec, itemsize)
+    itemsize = next(jnp.dtype(t.dtype).itemsize
+                    for t in runner.state_spec if t.name == "kv")
+    expected = sum(t["expected_bytes"] for t in tables)
     programs: Dict[str, Dict] = {}
     for bucket in (buckets if buckets is not None
                    else runner.buckets()):
@@ -617,8 +632,9 @@ def generate_record(runner, target: str = "generate",
             else f"prefill_b{shp[0]}_s{shp[1]}"
         text, mem = runner.program_artifact(bucket)
         mem = mem or {}
-        # the kv table is the LAST data operand of every entry
-        kv_argnum = 2 if kind == "decode" else 3
+        # the state is the LAST data operand of every entry
+        kv_argnum = (2 if kind == "decode" else 3) \
+            + (1 if runner.last_logits_only else 0)
         programs[name] = {
             "mem": mem,
             "collective_scratch": collective_scratch_bytes(text),
@@ -633,7 +649,7 @@ def generate_record(runner, target: str = "generate",
         "kv": {"spec": list(spec), "itemsize": itemsize,
                "slots": int(runner._kv_shape[2]),
                "table_bytes": table_bytes,
-               "expected_bytes": expected},
+               "expected_bytes": expected, "tables": tables},
     }
 
 

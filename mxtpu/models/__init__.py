@@ -9,11 +9,13 @@ from __future__ import annotations
 
 from ..gluon import nn
 
-__all__ = ["lenet", "mlp", "resnet50", "rcnn", "ssd", "transformer"]
+__all__ = ["lenet", "mlp", "resnet50", "rcnn", "ssd", "transformer",
+           "hybrid"]
 
 from . import rcnn  # noqa: E402,F401  (Faster R-CNN family)
 from . import ssd  # noqa: E402,F401  (SSD detector family)
 from . import transformer  # noqa: E402,F401  (BERT/Transformer family)
+from . import hybrid  # noqa: E402,F401  (Mamba-2 / attention decoders)
 
 
 def resnet50(classes: int = 1000, thumbnail: bool = False):

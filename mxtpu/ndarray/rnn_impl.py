@@ -271,6 +271,30 @@ def _write_lanes(table, new, idx, layer, plane):
     return lax.fori_loop(0, new.shape[0], one_lane, table)
 
 
+def write_whole_lanes(table, rows, lanes, axis):
+    """``table`` with lane ``lanes[r]`` (along ``axis``) replaced whole
+    by row ``r`` of ``rows``, for every r in turn: the way a prefill's
+    rows go back into a slot table whose lanes are megabytes each.  As
+    in ``_write_lanes``, a loop over the rows, each turn one
+    ``lax.dynamic_update_slice`` on the whole table with the carry
+    held to the table's device layout, so XLA writes into the donated
+    buffer and no second table exists.  Rows that name one lane (the
+    padding rows' scratch slot) land in order, the last one staying."""
+    from jax.experimental.layout import with_layout_constraint
+    held = _resident_layout(table)
+    zero = jnp.int32(0)
+
+    def one_row(r, t):
+        t = with_layout_constraint(t, held)
+        row = lax.dynamic_slice_in_dim(rows, r, 1, axis=axis)
+        at = [zero] * t.ndim
+        at[axis] = lanes[r]
+        t = lax.dynamic_update_slice(t, row.astype(t.dtype), at)
+        return with_layout_constraint(t, held)
+
+    return lax.fori_loop(0, rows.shape[axis], one_row, table)
+
+
 register_op("kv_cache_write", num_inputs=3, differentiable=False,
             params=[Param("layer", int, 0, lower=0),
                     Param("plane", int, 0, enum=(0, 1))],
@@ -295,7 +319,10 @@ register_op("kv_cache_read", num_inputs=1, differentiable=False,
 def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
     """Decode-step attention over a preallocated KV cache.  ``q``:
     (B, H, T, D) — the T new query tokens of each lane sit at absolute
-    positions ``step_b + t``; ``k_cache``/``v_cache``: (B, H, L, D).
+    positions ``step_b + t``; ``k_cache``/``v_cache``: (B, H_kv, L, D)
+    of any float dtype, with ``H = g * H_kv``: query head ``h`` reads
+    key/value head ``h // g`` (grouped-query attention; ``g = 1`` is
+    the equal-heads case and lowers to the program it always did).
     Causal masking against valid lengths (key position l attends iff
     ``l <= step_b + t``), so stale cache contents beyond a lane's
     frontier — including leftovers from a previous occupant of a
@@ -305,28 +332,230 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
     bf16-decode/f32-accum recipe contracts/prec/generate_decode.json
     pins.  ``sm_scale < 0`` means 1/sqrt(D)."""
     B, H, T, D = q.shape
-    L = k_cache.shape[2]
+    Hk, L = k_cache.shape[1], k_cache.shape[2]
+    if H % Hk:
+        raise MXNetError(f"cached_attention: {H} query heads over "
+                         f"{Hk} key/value heads")
     scale = (1.0 / float(np.sqrt(D))) \
         if (sm_scale is None or sm_scale < 0) else float(sm_scale)
     with jax.named_scope("cached_attention"):
         s = jnp.asarray(step).astype(jnp.int32)
-        scores = jnp.einsum("bhtd,bhld->bhtl", q.astype(jnp.float32),
-                            k_cache.astype(jnp.float32),
-                            preferred_element_type=jnp.float32) * scale
         pos_q = s[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
         pos_k = jnp.arange(L, dtype=jnp.int32)
         mask = pos_k[None, None, :] <= pos_q[:, :, None]
-        scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+        q32 = q.astype(jnp.float32)
+        k32, v32 = k_cache.astype(jnp.float32), v_cache.astype(jnp.float32)
+        if H == Hk:
+            scores = jnp.einsum("bhtd,bhld->bhtl", q32, k32,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bhtl,bhld->bhtd", probs, v32,
+                             preferred_element_type=jnp.float32)
+            return out.astype(q.dtype)
+        # grouped: the g query heads of a group share one read of
+        # their key/value head, which is never repeated in memory
+        qg = q32.reshape(B, Hk, H // Hk, T, D)
+        scores = jnp.einsum("bkgtd,bkld->bkgtl", qg, k32,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhtl,bhld->bhtd", probs,
-                         v_cache.astype(jnp.float32),
+        out = jnp.einsum("bkgtl,bkld->bkgtd", probs, v32,
                          preferred_element_type=jnp.float32)
-        return out.astype(q.dtype)
+        return out.reshape(B, H, T, D).astype(q.dtype)
 
 
 register_op("cached_attention", num_inputs=4, differentiable=False,
             params=[Param("sm_scale", float, -1.0)],
             doc=_cached_attention_op.__doc__)(_cached_attention_op)
+
+
+# ----------------------------------------------------------------------
+# state-space (Mamba-2) layers of incremental decode: a lane's state is
+# not a row per token but one array rewritten at every token
+# ----------------------------------------------------------------------
+def _rms(x32, weight, eps):
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return x32 * lax.rsqrt(ms + eps) * weight.astype(jnp.float32)
+
+
+def _rms_norm_op(x, weight, eps=1e-5):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
+    statistics in float32 whatever ``x`` is."""
+    with jax.named_scope("rms_norm"):
+        return _rms(x.astype(jnp.float32), weight, eps).astype(x.dtype)
+
+
+register_op("rms_norm", num_inputs=2,
+            params=[Param("eps", float, 1e-5)],
+            doc=_rms_norm_op.__doc__)(_rms_norm_op)
+
+
+def _gated_rms_norm_op(y, z, weight, eps=1e-5):
+    """``rms_norm(y * silu(z), weight)``: the Mamba-2 mixer's output
+    gate and norm, over the whole last axis (one group)."""
+    with jax.named_scope("rms_norm"):
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return _rms(g, weight, eps).astype(y.dtype)
+
+
+register_op("gated_rms_norm", num_inputs=3,
+            params=[Param("eps", float, 1e-5)],
+            doc=_gated_rms_norm_op.__doc__)(_gated_rms_norm_op)
+
+
+def _fresh(state, step, length):
+    """A lane that takes its first tokens (``step`` 0, ``length`` > 0)
+    starts from zero state whatever it held: admission needs no zeroing
+    of the table from the host.  A row with nothing valid keeps what
+    its lane holds."""
+    keep = (jnp.asarray(step).astype(jnp.int32) > 0) \
+        | (jnp.asarray(length).astype(jnp.int32) <= 0)
+    return jnp.where(keep.reshape((-1,) + (1,) * (state.ndim - 1)),
+                     state, jnp.zeros((), state.dtype))
+
+
+def _ssm_conv_op(table, x, weight, bias, step, length, layer=0):
+    """Causal depthwise convolution with carried state, then silu.
+    ``table``: (layers, B, K-1, C), lane b's last K-1 inputs of each
+    layer; ``x``: (B, T, C) new inputs, of which row b's first
+    ``length_b`` are valid; ``weight``: (C, K); ``bias``: (C,).
+    ``y_t = silu(bias + sum_j weight[:, j] * in_{t-K+1+j})`` over the
+    carried inputs followed by the new ones.  Returns ``(y, table)``
+    with plane ``layer`` replaced whole by each lane's last K-1 VALID
+    inputs (a lane with ``length`` 0 keeps what it had), so padded
+    positions never enter the state.  A lane with ``step`` 0 and
+    something valid starts from zeros.  ``layer`` is a static attribute."""
+    with jax.named_scope("ssm/conv"):
+        T, K = x.shape[1], weight.shape[1]
+        state = _fresh(table[layer], step, length).astype(jnp.float32)
+        full = jnp.concatenate([state, x.astype(jnp.float32)], axis=1)
+        w = weight.astype(jnp.float32)
+        y = bias.astype(jnp.float32)
+        for j in range(K):
+            y = y + full[:, j:j + T] * w[:, j]
+        y = jax.nn.silu(y)
+        n = jnp.asarray(length).astype(jnp.int32)
+        last = jax.vmap(lambda f, at: lax.dynamic_slice_in_dim(
+            f, at, K - 1, axis=0))(full, n)
+        table = table.at[layer].set(last.astype(table.dtype))
+    return y.astype(x.dtype), table
+
+
+register_op("ssm_conv", num_inputs=6, num_outputs=2, differentiable=False,
+            params=[Param("layer", int, 0, lower=0)],
+            doc=_ssm_conv_op.__doc__)(_ssm_conv_op)
+
+
+def _ssd_chunked(x, dt, a_head, b_mat, c_mat, s0, chunk):
+    """The selective scan ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
+    B_t``, ``y_t = S_t C_t`` in its chunked (state-space dual) form
+    (Dao & Gu 2024, sec. 6): inside a chunk of ``chunk`` positions the
+    outputs are one masked matrix product, between chunks only the
+    state is carried.  ``x`` (B, T, H, P), ``dt`` (B, T, H) already
+    positive (0 at a padded position: no decay, no input), ``a_head``
+    (H,) negative, ``b_mat``/``c_mat`` (B, T, N), ``s0`` (B, H, P, N).
+    Returns ``(y (B, T, H, P), S_T)``.  What feeds the carried state
+    is contracted at HIGHEST precision: the state is kept in float32
+    and a default-precision product would round it to bfloat16."""
+    B, T, H, P = x.shape
+    N = b_mat.shape[-1]
+    Q = min(int(chunk), T)
+    pad = (-T) % Q
+    if pad:
+        grow = lambda z: jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
+        x, dt, b_mat, c_mat = grow(x), grow(dt), grow(b_mat), grow(c_mat)
+    nc = (T + pad) // Q
+    xc = x.reshape(B, nc, Q, H, P)
+    dtc = dt.reshape(B, nc, Q, H)
+    bc = b_mat.reshape(B, nc, Q, N)
+    cc = c_mat.reshape(B, nc, Q, N)
+    cum = jnp.cumsum(dtc * a_head, axis=2)              # (B, nc, Q, H)
+    dx = dtc[..., None] * xc                            # dt_s x_s
+    # inside a chunk: y_t = sum_{s<=t} exp(cum_t - cum_s) (C_t.B_s) dt_s x_s
+    cb = jnp.einsum("bcqn,bcsn->bcqs", cc, bc,
+                    preferred_element_type=jnp.float32)
+    diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,S,H)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+    w = cb[..., None] * jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    y = jnp.einsum("bcqsh,bcshp->bcqhp", w, dx,
+                   preferred_element_type=jnp.float32)
+    # what each chunk adds to the state at its own end, and its decay
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum)           # (B, nc, Q, H)
+    added = jnp.einsum("bcsh,bcshp,bcsn->bchpn", to_end, dx, bc,
+                       precision=lax.Precision.HIGHEST)
+    decay = jnp.exp(cum[:, :, -1, :])                   # (B, nc, H)
+
+    def carry(s, chunk_in):
+        add_c, dec_c = chunk_in
+        return s * dec_c[..., None, None] + add_c, s
+
+    s_end, s_in = lax.scan(carry, s0, (jnp.moveaxis(added, 1, 0),
+                                       jnp.moveaxis(decay, 1, 0)))
+    # the state a chunk starts from, decayed to each of its positions
+    y = y + jnp.einsum("bcqn,cbhpn,bcqh->bcqhp", cc, s_in, jnp.exp(cum),
+                       precision=lax.Precision.HIGHEST)
+    return y.reshape(B, nc * Q, H, P)[:, :T], s_end
+
+
+def _ssm_scan_op(table, x, dt, b_mat, c_mat, a_log, d_skip, dt_bias,
+                 step, length, layer=0, chunk=256):
+    """Mamba-2's selective state-space scan with carried state.
+    ``table``: (layers, B, H, P, N) float32, lane b's state of each
+    layer; ``x``: (B, T, H*P); ``dt``: (B, T, H) raw; ``b_mat``,
+    ``c_mat``: (B, T, N) (one group); ``a_log``, ``d_skip``,
+    ``dt_bias``: (H,).  Per head, with ``dt = softplus(dt + dt_bias)``
+    and ``A = -exp(a_log)``: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x)
+    B_t``, ``y_t = S_t C_t + d_skip x_t``.  Row b's positions from
+    ``length_b`` on are padding: their ``dt`` is 0, so they leave the
+    state as it is; a lane with ``step`` 0 and something valid starts
+    from zeros.  Returns
+    ``(y (B, T, H*P), table)`` with plane ``layer`` replaced whole.
+    T > 1 takes the chunked form (``_ssd_chunked``, scope
+    ``ssm/scan``); T = 1 is one read and one write of the plane (scope
+    ``ssm/state_update``), fenced by ``optimization_barrier`` so that
+    the compiler fuses nothing of its neighbours into it and a trace
+    can time it alone.  ``layer`` and ``chunk`` are static."""
+    B, T, H = dt.shape
+    P = x.shape[-1] // H
+    f32 = jnp.float32
+    if T == 1:
+        x, dt, b_mat, c_mat, a_log, d_skip, dt_bias, step, length = \
+            lax.optimization_barrier(
+                (x, dt, b_mat, c_mat, a_log, d_skip, dt_bias, step,
+                 length))
+    n = jnp.asarray(length).astype(jnp.int32)
+    scope = "ssm/state_update" if T == 1 else "ssm/scan"
+    with jax.named_scope(scope):
+        a_head = -jnp.exp(a_log.astype(f32))
+        valid = jnp.arange(T, dtype=jnp.int32)[None, :] < n[:, None]
+        dtp = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)) \
+            * valid[..., None].astype(f32)
+        xh = x.astype(f32).reshape(B, T, H, P)
+        s0 = _fresh(table[layer], step, n).astype(f32)
+        if T == 1:
+            d1, x1 = dtp[:, 0], xh[:, 0]
+            s_new = s0 * jnp.exp(d1 * a_head)[..., None, None] \
+                + (d1[..., None] * x1)[..., None] \
+                * b_mat.astype(f32)[:, 0, None, None, :]
+            y = jnp.sum(s_new * c_mat.astype(f32)[:, 0, None, None, :],
+                        axis=-1)[:, None]
+        else:
+            y, s_new = _ssd_chunked(xh, dtp, a_head, b_mat.astype(f32),
+                                    c_mat.astype(f32), s0, chunk)
+        y = y + d_skip.astype(f32)[None, None, :, None] * xh
+        y = y.reshape(B, T, H * P).astype(x.dtype)
+        table = table.at[layer].set(s_new.astype(table.dtype))
+    if T == 1:
+        y = lax.optimization_barrier(y)
+    return y, table
+
+
+register_op("ssm_scan", num_inputs=10, num_outputs=2,
+            differentiable=False,
+            params=[Param("layer", int, 0, lower=0),
+                    Param("chunk", int, 256, lower=1)],
+            doc=_ssm_scan_op.__doc__)(_ssm_scan_op)
 
 
 def _flash_attention_op(q, k, v, causal=False, sm_scale=-1.0):

@@ -56,7 +56,7 @@ from .faults import (CorruptEntry, CrashAt, Corrupt, Fault, FaultPlan,
                      SlowStart, SlowStartError, StaleKey,
                      TruncateEntry, WorkerCrashed)
 from .generate import (GenerateBatcher, GenerateRequest,
-                       GenerateRunner, sample_token)
+                       GenerateRunner, StateTable, sample_token)
 from .health import WorkerHealth, WorkerState
 from .router import (FleetGenerateRequest, FleetRequest, FleetRouter,
                      FleetWorker)
@@ -69,7 +69,7 @@ __all__ = ["ModelRunner", "InferenceServer", "DynamicBatcher",
            "RequestTimeout", "RetriableError", "WorkerLost",
            "batch_ladder",
            "GenerateRunner", "GenerateBatcher", "GenerateRequest",
-           "sample_token",
+           "StateTable", "sample_token",
            "FleetRouter", "FleetWorker", "FleetRequest",
            "FleetGenerateRequest",
            "WorkerHealth", "WorkerState",

@@ -9,7 +9,13 @@ Three pieces:
   (batch-rung x prompt-bucket) and ONE incremental *decode-step*
   executable over a preallocated bucket-paged KV cache.  The cache is
   a slot table: each in-flight request owns a cache *lane* (axis 2 of
-  the one ``(num_layers, 2, slots, heads, L, head_dim)`` array).  The
+  the one ``(num_layers, 2, slots, heads, L, head_dim)`` array).  A
+  model with more than one kind of per-lane state declares a *state
+  spec* instead (:class:`StateTable`: name, shape with its lane axis,
+  dtype — keys and values by position beside recurrent state of fixed
+  size); every table is allocated, donated, threaded and handed back
+  together, and such a graph also takes each row's number of valid new
+  tokens and returns one row of logits a lane.  The
   graph threads the whole table through its layers: layer i's
   ``kv_cache_write`` (a loop over the lanes, each turn one
   ``lax.dynamic_update_slice`` on the table) puts each lane's new
@@ -50,7 +56,8 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -64,7 +71,16 @@ from .batcher import (InferenceRequest, RequestTimeout, ServerBusy,
 from .runner import batch_ladder
 
 __all__ = ["GenerateRequest", "GenerateRunner", "GenerateBatcher",
-           "sample_token"]
+           "StateTable", "sample_token"]
+
+
+class StateTable(NamedTuple):
+    """One per-lane state table of an incremental graph: ``shape`` with
+    the lane count at ``lane_axis``, held on the device as ``dtype``."""
+    name: str
+    shape: Tuple[int, ...]
+    lane_axis: int
+    dtype: str
 
 
 def sample_token(logits, *, position: int, seed: int = 0,
@@ -77,10 +93,12 @@ def sample_token(logits, *, position: int, seed: int = 0,
     position), so a replayed generation that re-reaches the same
     position samples the SAME token regardless of which worker (or
     which run) computes it."""
+    if top_k is None or top_k <= 1:
+        # the first maximum of the row as it came: a float64 copy of a
+        # hundred thousand logits would find the same one
+        return int(np.argmax(np.asarray(logits).reshape(-1)))  # mxlint: sync-point — logits are already host rows here
     # mxlint: sync-point — logits are already host rows here
     row = np.asarray(logits, np.float64).reshape(-1)  # mxlint: disable=dtype-hygiene (f64 host sampling on purpose: platform-identical softmax/ties)
-    if top_k is None or top_k <= 1:
-        return int(np.argmax(row))
     k = min(int(top_k), row.shape[0])
     idx = np.argpartition(row, -k)[-k:]
     # stable descending order: ties break by token id, not partition
@@ -160,24 +178,42 @@ class GenerateRunner:
     Parameters
     ----------
     symbol : mxtpu.symbol.Symbol
-        A 3-input incremental export (``HybridBlock.export`` of a
-        model called in incremental mode): inputs ``(tokens, step,
-        cache)``, outputs ``(logits, new_cache)``.  The cache layout
-        contract is ``(num_layers, 2, B, heads, L, head_dim)`` —
-        exactly what ``TransformerModel.kv_cache_spec`` /
-        ``BERTModel.kv_cache_spec`` describe.
+        An incremental export (``HybridBlock.export`` of a model
+        called in incremental mode).  With one KV table: inputs
+        ``(tokens, step, cache)``, outputs ``(logits, new_cache)``,
+        the cache laid out ``(num_layers, 2, B, heads, L, head_dim)``
+        — exactly what ``TransformerModel.kv_cache_spec`` /
+        ``BERTModel.kv_cache_spec`` describe.  With a state spec:
+        inputs ``(tokens, step, length, *tables)``, outputs ``(logits,
+        *tables)``; ``length`` (B,) is each row's number of valid new
+        tokens, and ``logits`` is ``(B, 1, V)``, one row a lane at its
+        last valid position (``last_logits_only``).
     params : dict name -> numpy/NDArray
         Trained weights (uploaded once, shared by every executable).
     kv_spec : tuple
-        ``net.kv_cache_spec(max_lanes, max_len)`` — axis 2 is the lane
-        count, axis 4 the cache capacity L.  The runner allocates ONE
-        extra scratch slot internally (prefill batch padding scatters
-        there; its contents are garbage by construction and never
-        read), so the device cache has ``max_lanes + 1`` slots.
+        Either ``net.kv_cache_spec(max_lanes, max_len)`` — axis 2 is
+        the lane count, axis 4 the cache capacity L, held in float32
+        — or a state spec: a sequence of :class:`StateTable` ``(name,
+        shape, lane_axis, dtype)`` such as
+        ``HybridDecoderModel.state_spec`` declares, one of them a 6-D
+        table named ``"kv"`` whose axis 4 is the capacity.  The runner
+        allocates ONE extra scratch slot in every table (prefill batch
+        padding scatters there; its contents are garbage by
+        construction and never read), so the device tables have
+        ``max_lanes + 1`` slots.  ``new_cache()`` hands out one array
+        for the 6-tuple and a tuple of arrays, in the spec's order and
+        each in its own dtype, for a state spec; ``prefill``/``decode``
+        take and return whichever it is.
     prompt_buckets : ascending ints
         Prompt-length rungs; prefill compiles per (batch-rung x
         prompt-bucket).  Prompts (plus replay prefixes) longer than
         the largest bucket prefill in bucket-width chunks.
+    max_prefill_batch : int, optional
+        The widest prefill rung there is (default: ``max_lanes``).  A
+        prefill holds its rows' gathered lanes beside the tables, so
+        where lanes are large the upper rungs of the ladder need more
+        memory than the device has: the ladder then ends at this rung
+        and the batcher admits at most so many requests a step.
     quant_scales : dict, optional — calibrated activation thresholds
         (from a :class:`ModelRunner` ``calibrate()`` over the same
         architecture) arming the int8 trace path; required when
@@ -188,9 +224,9 @@ class GenerateRunner:
     def __init__(self, symbol, params: Dict[str, Any],
                  kv_spec: Sequence[int], *,
                  prompt_buckets: Sequence[int],
-                 input_names: Sequence[str] = ("data0", "data1",
-                                               "data2"),
+                 input_names: Optional[Sequence[str]] = None,
                  device=None, donate: Optional[bool] = None,
+                 max_prefill_batch: Optional[int] = None,
                  cache: Any = "auto", amp=None, quant=None,
                  quant_scales: Optional[Dict[str, float]] = None):
         import jax
@@ -201,27 +237,52 @@ class GenerateRunner:
         self._quant = _quant_mod.resolve(quant)
         self._quant_scales = dict(quant_scales) if quant_scales else None
         self._symbol = symbol
-        if len(input_names) != 3:
-            raise MXNetError(
-                "generate: input_names must be the (tokens, step, "
-                "cache) triple of the incremental export")
-        self._input_names = tuple(input_names)
-        kv_spec = tuple(int(d) for d in kv_spec)
-        self.kv_spec = kv_spec  # the declared cache geometry mxmem audits
-        if len(kv_spec) != 6 or kv_spec[1] != 2:
+        # one KV table as a 6-tuple of ints, or a declared state spec
+        self._one_table = not isinstance(kv_spec[0], (tuple, list))
+        if self._one_table:
+            self.state_spec = (StateTable(
+                "kv", tuple(int(d) for d in kv_spec), 2, "float32"),)
+        else:
+            self.state_spec = tuple(
+                StateTable(str(n), tuple(int(d) for d in shape),
+                           int(axis), jax.numpy.dtype(dt).name)
+                for n, shape, axis, dt in kv_spec)
+        kv = [t.shape for t in self.state_spec if t.name == "kv"]
+        # the declared cache geometry mxmem audits
+        self.kv_spec = kv[0] if kv else ()
+        if len(self.kv_spec) != 6 or self.kv_spec[1] != 2:
             raise MXNetError(
                 "generate: kv_spec must be (num_layers, 2, lanes, "
-                "heads, L, head_dim) — use net.kv_cache_spec()")
-        self.max_lanes = kv_spec[2]
+                "heads, L, head_dim) — use net.kv_cache_spec() — or a "
+                "state spec with such a table named 'kv'")
+        self.max_lanes = self.kv_spec[2]
         if self.max_lanes < 1:
             raise MXNetError("generate: kv_spec lane count must be >= 1")
+        if any(t.shape[t.lane_axis] != self.max_lanes
+               for t in self.state_spec):
+            raise MXNetError(
+                f"generate: every state table must hold "
+                f"{self.max_lanes} lanes at its lane axis")
+        n_in = 2 + len(self.state_spec) + (0 if self._one_table else 1)
+        if input_names is None:
+            input_names = tuple(f"data{i}" for i in range(n_in))
+        if len(input_names) != n_in:
+            raise MXNetError(
+                "generate: input_names must be the (tokens, step, "
+                "cache) triple of the incremental export, or (tokens, "
+                "step, length, *tables) for a state spec")
+        self._input_names = tuple(input_names)
         # one scratch slot past the lanes: prefill batch-padding rows
         # scatter there (duplicate scratch writes are garbage by
         # design — the scratch lane is never sampled from)
         self._slots = self.max_lanes + 1
         self.scratch_slot = self.max_lanes
-        self._kv_shape = kv_spec[:2] + (self._slots,) + kv_spec[3:]
-        self.max_len = kv_spec[4]
+        self._table_shapes = tuple(
+            t.shape[:t.lane_axis] + (self._slots,)
+            + t.shape[t.lane_axis + 1:] for t in self.state_spec)
+        self._kv_shape = self._table_shapes[
+            [t.name for t in self.state_spec].index("kv")]
+        self.max_len = self.kv_spec[4]
         self.prompt_buckets = tuple(sorted(int(s)
                                            for s in prompt_buckets))
         if not self.prompt_buckets:
@@ -232,7 +293,9 @@ class GenerateRunner:
                 f"generate: largest prompt bucket "
                 f"{self.prompt_buckets[-1]} exceeds KV capacity "
                 f"{self.max_len}")
-        self.batch_buckets = batch_ladder(self.max_lanes)
+        self.batch_buckets = batch_ladder(
+            self.max_lanes if max_prefill_batch is None
+            else max(1, min(int(max_prefill_batch), self.max_lanes)))
         self._device = device if device is not None else jax.devices()[0]
         if donate is None:
             donate = knobs.get("MXTPU_SERVING_DONATE")
@@ -318,6 +381,15 @@ class GenerateRunner:
             "beside its arguments and outputs (a rebuilt KV table "
             "shows here as a table's worth).",
             labels=("kind", "bucket"))
+        self._m_state_bytes = obs.gauge(
+            "mxtpu_gen_state_bytes",
+            "Bytes of each per-lane state table as new_cache() "
+            "allocated it (scratch slot included).",
+            labels=("table",))
+        self._m_resets = obs.counter(
+            "mxtpu_gen_state_reset_total",
+            "Prefill rows that started a lane from zero state "
+            "(step 0): admissions and replays, not later chunks.")
 
         from .. import cache as cache_mod
         self._cache = cache_mod.default_cache() if cache == "auto" \
@@ -325,6 +397,17 @@ class GenerateRunner:
         self._fingerprint = ""
         if self._cache is not None:
             self._fingerprint = self._model_fingerprint()
+
+    @property
+    def last_logits_only(self) -> bool:
+        """A graph with a state spec is told each row's valid length
+        and hands back one row of logits a lane, ``(b, 1, V)``."""
+        return not self._one_table
+
+    def _rows(self, tokens, step, length):
+        """The row inputs the graph takes."""
+        return (tokens, step) if self._one_table \
+            else (tokens, step, length)
 
     @staticmethod
     def _as_np(v):
@@ -358,9 +441,10 @@ class GenerateRunner:
         return self.prompt_buckets[-1]
 
     def batch_rung_for(self, n: int) -> int:
-        if n < 1 or n > self.max_lanes:
+        if n < 1 or n > self.batch_buckets[-1]:
             raise MXNetError(
-                f"generate: prefill batch {n} outside 1..{self.max_lanes}")
+                f"generate: prefill batch {n} outside "
+                f"1..{self.batch_buckets[-1]}")
         return next(r for r in self.batch_buckets if r >= n)
 
     def buckets(self) -> List[Tuple]:
@@ -394,6 +478,10 @@ class GenerateRunner:
                                        self._param_vals)],
             "donate": self._donate,
         }
+        if not self._one_table:
+            fp["state"] = [[t.name, list(shape), t.lane_axis, t.dtype]
+                           for t, shape in zip(self.state_spec,
+                                               self._table_shapes)]
         if self._amp:
             fp["amp"] = True
         if self._quant:
@@ -463,25 +551,26 @@ class GenerateRunner:
             scope.enter_context(_amp_mod.autocast())
         return scope
 
-    def _eval_incremental(self, tokens, step, kv_small, param_vals):
-        """Trace the incremental graph once: (tokens, step, small
-        cache) -> (logits, new small cache), inference mode."""
+    def _eval_incremental(self, rows, tables, param_vals):
+        """Trace the incremental graph once: (the row inputs, the
+        state tables) -> (logits, new tables), inference mode."""
         import jax.numpy as jnp
         from .. import autograd
         from ..ndarray.ndarray import NDArray
         from ..symbol import _eval_symbol
-        if self._amp:
+        if self._amp and self._one_table:
+            # a graph with a state spec takes its weights as they are
+            # staged: its ops bring what they need to float32
+            # themselves, and a float32 copy of a bfloat16 embedding
+            # would be made anew in every step
             param_vals = tuple(
                 v.astype(jnp.float32)
                 if (jnp.issubdtype(v.dtype, jnp.floating)
                     and v.dtype != jnp.float32)
                 else v for v in param_vals)
-        bindings = {self._input_names[0]: NDArray(tokens, None,
-                                                  _placed=True),
-                    self._input_names[1]: NDArray(step, None,
-                                                  _placed=True),
-                    self._input_names[2]: NDArray(kv_small, None,
-                                                  _placed=True)}
+        bindings = {n: NDArray(v, None, _placed=True)
+                    for n, v in zip(self._input_names,
+                                    tuple(rows) + tuple(tables))}
         for n, v in zip(self._param_names, param_vals):
             bindings[n] = NDArray(v, None, _placed=True)
         prev_rec = autograd.set_recording(False)
@@ -492,65 +581,97 @@ class GenerateRunner:
         finally:
             autograd.set_training(prev_train)
             autograd.set_recording(prev_rec)
-        if len(outs) != 2:
+        if len(outs) != 1 + len(tables):
             raise MXNetError(
                 f"generate: incremental graph must output (logits, "
-                f"cache), got {len(outs)} outputs")
-        return outs[0].data, outs[1].data
+                f"cache) — one output a state table — got "
+                f"{len(outs)} outputs")
+        return outs[0].data, tuple(o.data for o in outs[1:])
 
     def _prefill_pure(self):
-        """(tokens (b,s), step (b,), lane_idx (b,), kv_big, params) ->
-        (logits (b,s,V), kv_big').  Gather-extend-scatter: each row's
-        lane is pulled from the slot table, extended by its s tokens
-        at its own step offset, and written back — so chunked prefill
-        of a long prompt+prefix is just repeated calls at advancing
-        step offsets.  Padding rows target the scratch slot."""
+        """(tokens (b,s), step (b,), [length (b,),] lane_idx (b,),
+        state, params) -> (logits, state').  Gather-extend-write: each
+        row's lane is pulled from every slot table, extended by its
+        tokens at its own step offset, and written back — so chunked
+        prefill of a long prompt+prefix is just repeated calls at
+        advancing step offsets.  Padding rows target the scratch slot.
+        One KV table: logits are (b,s,V) and the lanes go back by one
+        indexed update.  A state spec: logits are (b,1,V) and each
+        table's rows go back one lane at a time, in place
+        (``write_whole_lanes``), so no program holds a second copy of
+        a table whose lanes are megabytes each."""
         import jax
         import jax.numpy as jnp
+        from ..ndarray.rnn_impl import write_whole_lanes
 
-        def fn(tokens, step, lane_idx, kv_big, param_vals):
+        def fn(*args):
+            *rows, lane_idx, state, param_vals = args
             with jax.named_scope("gen/prefill_program"):
                 idx = lane_idx.astype(jnp.int32)
-                kv_small = kv_big[:, :, idx]
-                logits, new_small = self._eval_incremental(
-                    tokens, step, kv_small, param_vals)
-                kv_big = kv_big.at[:, :, idx].set(
-                    new_small.astype(kv_big.dtype))
-            return logits, kv_big
+                if self._one_table:
+                    kv_small = state[:, :, idx]
+                    logits, (new_small,) = self._eval_incremental(
+                        rows, (kv_small,), param_vals)
+                    return logits, state.at[:, :, idx].set(
+                        new_small.astype(state.dtype))
+                axes = [t.lane_axis for t in self.state_spec]
+                logits, new = self._eval_incremental(
+                    rows, tuple(jnp.take(t, idx, axis=a)
+                                for t, a in zip(state, axes)),
+                    param_vals)
+                return logits, tuple(
+                    write_whole_lanes(t, n, idx, a)
+                    for t, n, a in zip(state, new, axes))
 
         return fn
 
     def _decode_pure(self):
-        """(tokens (slots,1), step (slots,), kv_big, params) ->
-        (logits (slots,1,V), kv_big') — THE decode step: every slot
-        advances one position; inactive slots compute ignored rows
-        (masked attention keeps them finite)."""
+        """(tokens (slots,1), step (slots,), [length (slots,),] state,
+        params) -> (logits (slots,1,V), state') — THE decode step:
+        every slot advances one position; inactive slots compute
+        ignored rows (masked attention keeps them finite; a row of
+        length 0 leaves its recurrent state as it was)."""
         import jax
 
-        def fn(tokens, step, kv_big, param_vals):
+        def fn(*args):
+            *rows, state, param_vals = args
             with jax.named_scope("gen/decode_program"):
-                return self._eval_incremental(tokens, step, kv_big,
-                                              param_vals)
+                if self._one_table:
+                    logits, (state,) = self._eval_incremental(
+                        rows, (state,), param_vals)
+                    return logits, state
+                return self._eval_incremental(rows, state, param_vals)
 
         return fn
 
     def _structs(self, bucket: Tuple):
         import jax
-        f32 = np.float32
         kind, shp = bucket
 
-        def sds(shape):
-            return jax.ShapeDtypeStruct(tuple(shape), f32,
+        def sds(shape, dtype=np.float32):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype,
                                         sharding=self._sharding)
 
-        kv = sds(self._kv_shape)
+        state = tuple(sds(shape, jax.numpy.dtype(t.dtype))
+                      for t, shape in zip(self.state_spec,
+                                          self._table_shapes))
+        if self._one_table:
+            state = state[0]
         if kind == "prefill":
             b, s = shp
-            return (sds((b, s)), sds((b,)), sds((b,)), kv)
-        if kind == "decode":
-            (slots,) = shp
-            return (sds((slots, 1)), sds((slots,)), kv)
-        raise MXNetError(f"generate: unknown executable kind {kind!r}")
+            n = b
+            rows = (sds((b, s)), sds((b,)))
+        elif kind == "decode":
+            (n,) = shp
+            rows = (sds((n, 1)), sds((n,)))
+        else:
+            raise MXNetError(
+                f"generate: unknown executable kind {kind!r}")
+        if self.last_logits_only:
+            rows += (sds((n,)),)                  # length
+        if kind == "prefill":
+            rows += (sds((n,)),)                  # lane_idx
+        return rows + (state,)
 
     def _entry(self, bucket: Tuple):
         """Load-or-compile one generation executable (exactly once,
@@ -638,30 +759,65 @@ class GenerateRunner:
 
     # -- execution --------------------------------------------------------
     def new_cache(self):
-        """Fresh zeroed KV slot table on this runner's device."""
-        import jax
-        return jax.device_put(
-            np.zeros(self._kv_shape, np.float32), self._device)
+        """Fresh zeroed slot tables on this runner's device, each in
+        the dtype its spec states: one array for a 6-tuple ``kv_spec``,
+        a tuple of arrays in the spec's order for a state spec."""
+        import jax.numpy as jnp
+        tables = tuple(jnp.zeros(shape, jnp.dtype(t.dtype),
+                                 device=self._device)
+                       for t, shape in zip(self.state_spec,
+                                           self._table_shapes))
+        if self._obs:
+            for name, nbytes in self.state_bytes().items():
+                self._m_state_bytes.labels(table=name).set(nbytes)
+        return tables[0] if self._one_table else tables
+
+    def state_bytes(self) -> Dict[str, int]:
+        """Bytes of each state table as ``new_cache()`` allocates it
+        (scratch slot included), by the table's name."""
+        import jax.numpy as jnp
+        return {t.name: int(np.prod(shape, dtype=np.int64))
+                * jnp.dtype(t.dtype).itemsize
+                for t, shape in zip(self.state_spec, self._table_shapes)}
 
     def prefill(self, tokens: np.ndarray, step: np.ndarray,
-                lane_idx: np.ndarray, kv) -> Tuple[np.ndarray, Any]:
+                lane_idx: np.ndarray, kv, length=None
+                ) -> Tuple[np.ndarray, Any]:
         """One prefill dispatch on already-bucketed host arrays:
         ``tokens (b, s)`` / ``step (b,)`` / ``lane_idx (b,)`` must
-        match a ladder rung exactly (the batcher pads).  Returns
-        (host logits (b, s, V), new device KV table) — the passed
-        table is consumed (donated on accelerator backends)."""
+        match a ladder rung exactly (the batcher pads); ``length
+        (b,)`` is each row's number of valid tokens (all ``s`` if not
+        given).  Returns (host logits — (b, s, V), or (b, 1, V) at each
+        row's last valid position where ``last_logits_only`` — and the
+        new device state); the passed state is consumed (donated on
+        accelerator backends)."""
         b, s = tokens.shape
+        if length is None:
+            length = np.full((b,), s, np.float32)
+        fresh = int(np.count_nonzero((length > 0) & (step == 0)))
+        if self._obs:
+            self._m_resets.inc(fresh)
         return self._call(obs.SPAN_PREFILL_CALL, ("prefill", (b, s)),
-                          (tokens, step, lane_idx), kv,
-                          {"rows": b, "bucket": s})
+                          self._rows(tokens, step, length) + (lane_idx,),
+                          kv,
+                          {"rows": b, "bucket": s,
+                           "tokens": int(np.sum(length)),
+                           "resets": fresh})
 
-    def decode(self, tokens: np.ndarray, step: np.ndarray, kv
-               ) -> Tuple[np.ndarray, Any]:
+    def decode(self, tokens: np.ndarray, step: np.ndarray, kv,
+               length=None) -> Tuple[np.ndarray, Any]:
         """THE decode step: ``tokens (slots, 1)`` / ``step (slots,)``
-        advance every slot one position.  Returns (host logits
-        (slots, 1, V), new device KV table)."""
+        advance every slot one position; ``length (slots,)`` is 1 for
+        a lane that decodes and 0 for an idle one (all 1 if not
+        given).  Returns (host logits (slots, 1, V), new device
+        state)."""
+        if length is None:
+            length = np.ones((self._slots,), np.float32)
+        on = length > 0
         return self._call(obs.SPAN_DECODE, ("decode", (self._slots,)),
-                          (tokens, step), kv, {"slots": self._slots})
+                          self._rows(tokens, step, length), kv,
+                          {"slots": self._slots, "active": int(on.sum()),
+                           "context_tokens": int(step[on].sum())})
 
     def _call(self, name: str, bucket: Tuple,
               host_rows: Sequence[np.ndarray], kv,
@@ -1067,8 +1223,8 @@ class GenerateBatcher:
         if not free or not self._queue:
             return []
         head = self._queue[0]
-        take = [r for r in self._queue
-                if r.group == head.group][:len(free)]
+        take = [r for r in self._queue if r.group == head.group][
+            :min(len(free), self.runner.batch_buckets[-1])]
         taken = set(map(id, take))
         self._queue = [r for r in self._queue if id(r) not in taken]
         pairs = []
@@ -1110,20 +1266,27 @@ class GenerateBatcher:
                 base = c * s
                 tokens = np.zeros((b, s), np.float32)
                 step = np.zeros((b,), np.float32)
+                length = np.zeros((b,), np.float32)
                 lidx = np.full((b,), runner.scratch_slot, np.float32)
                 for row, (lane, r) in enumerate(pairs):
                     if base >= need[row]:
                         continue  # this row finished in an earlier chunk
                     valid = min(s, need[row] - base)
                     tokens[row, :valid] = full[row][base:base + valid]
+                    # step 0 starts the lane's recurrent state from
+                    # zero; a later chunk carries it on; the padded
+                    # positions past ``valid`` leave it as it is
                     step[row] = base
+                    length[row] = valid
                     lidx[row] = lane
                 logits, self._kv = runner.prefill(tokens, step, lidx,
-                                                  self._kv)
+                                                  self._kv, length)
                 for row in range(len(pairs)):
                     last = need[row] - 1
                     if base <= last < base + s:
-                        first_logits[row] = logits[row, last - base]
+                        first_logits[row] = logits[
+                            row, 0 if runner.last_logits_only
+                            else last - base]
             with self._cond:
                 if self._closed:
                     # the batcher died between admit and commit: these
@@ -1179,10 +1342,13 @@ class GenerateBatcher:
         slots = runner.max_lanes + 1
         tokens = np.zeros((slots, 1), np.float32)
         steps = np.zeros((slots,), np.float32)
+        length = np.zeros((slots,), np.float32)
         for i, lane in active:
             tokens[i, 0] = lane.last_token
             steps[i] = lane.frontier
-        logits, self._kv = runner.decode(tokens, steps, self._kv)
+            length[i] = 1
+        logits, self._kv = runner.decode(tokens, steps, self._kv,
+                                         length)
         # the tokens exist only now, after the decode: gaps are read
         # off the batcher's clock here, not at the step's start
         t_emit = self._clock()

@@ -193,3 +193,45 @@ def test_kv_table_is_written_in_place_on_v5e(one_chip, monkeypatch,
     plane = slots * heads * cap * dim * 4
     assert mem["alias_size_in_bytes"] == layers * 2 * plane
     assert mem["temp_size_in_bytes"] < plane, mem
+
+
+def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,
+                                                    no_persistent_cache):
+    """The hybrid cell's recurrent state at its real widths (48 slots,
+    64 heads of 64 with state 128, 4352 convolution channels; two of
+    the 36 layers): one decode step through ``ssm_conv`` and
+    ``ssm_scan`` replaces each layer's plane of both tables.  The
+    chip's compiler must alias the donated tables to the results and
+    need less than one ``ssm`` plane (100 MB) of temporaries — a plane
+    rebuilt beside the table shows as a plane's worth, a table copied
+    as 75 MB a lane."""
+    from mxtpu.ndarray import rnn_impl
+    layers, slots, heads, p, n, k = 2, 48, 64, 64, 128, 4
+    chan = heads * p + 2 * n
+
+    def step(ssm, conv, xbc, dt, w, b, a_log, d_skip, dt_bias, at, length):
+        out = jnp.zeros((slots, 1, heads * p), jnp.float32)
+        for i in range(layers):
+            mixed, conv = rnn_impl._ssm_conv_op(
+                conv, xbc[i] + jnp.pad(out, ((0, 0), (0, 0), (0, 2 * n))),
+                w, b, at, length, layer=i)
+            out, ssm = rnn_impl._ssm_scan_op(
+                ssm, mixed[..., :heads * p], dt[i],
+                mixed[..., heads * p:heads * p + n],
+                mixed[..., heads * p + n:], a_log, d_skip, dt_bias, at,
+                length, layer=i)
+        return out, ssm, conv
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    from mxtpu import analysis
+    _, mem = analysis.compiled_artifact(
+        step, sds(layers, slots, heads, p, n), sds(layers, slots, k - 1, chan),
+        sds(layers, slots, 1, chan), sds(layers, slots, 1, heads),
+        sds(chan, k), sds(chan), sds(heads), sds(heads), sds(heads),
+        sds(slots), sds(slots), donate_argnums=(0, 1))
+    plane = slots * heads * p * n * 4
+    tables = layers * (plane + slots * (k - 1) * chan * 4)
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < plane, mem
