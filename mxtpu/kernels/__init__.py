@@ -17,6 +17,9 @@ __all__ = ["layer_norm", "flash_attention", "pallas_enabled",
            "precision_metadata", "layout_metadata"]
 
 
+_KERNELS = ("flash_attention", "layer_norm", "batch_norm", "kv_write")
+
+
 def layout_metadata():
     """``{kernel_name: LAYOUT}`` for every Pallas kernel — the
     declared operand-layout contract (which physical layouts each
@@ -29,7 +32,7 @@ def layout_metadata():
     return {
         name: dict(importlib.import_module(
             f"{__name__}.{name}").LAYOUT)
-        for name in ("flash_attention", "layer_norm", "batch_norm")
+        for name in _KERNELS
     }
 
 
@@ -45,7 +48,7 @@ def precision_metadata():
     return {
         name: dict(importlib.import_module(
             f"{__name__}.{name}").PRECISION)
-        for name in ("flash_attention", "layer_norm", "batch_norm")
+        for name in _KERNELS
     }
 
 

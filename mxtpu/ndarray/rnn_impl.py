@@ -230,33 +230,61 @@ def _kv_cache_write_op(table, new, step, layer=0, plane=0):
     (B, H, T, D) freshly projected keys or values; ``step``: (B,)
     per-lane write offsets (each lane advances independently under
     continuous batching).  Returns the SAME table with rows
-    ``[layer, plane, b, :, step_b : step_b + T, :]`` replaced: a loop
-    over the lanes, each turn one ``lax.dynamic_update_slice`` on the
-    6-D table, which XLA performs in the donated buffer — the table is
-    never taken apart and re-stacked.  The loop's carry is held to the
-    layout the table has on the device (see ``_resident_layout``).
-    What was measured against it on the chip (PERF.md, PR 26): the
-    same updates unrolled, 1,536 in the decode program, take a third
-    less time and add half a minute to every set-up; ``lax.scatter``
-    and a loop whose carry is left free bring two copies of the whole
-    table, ``.at[...].set`` with index arrays a transpose of it round
-    every write.
+    ``[layer, plane, b, :, step_b : step_b + T, :]`` replaced, in the
+    donated buffer — the table is never taken apart and re-stacked.
+    One token a lane (``T == 1``, the decode step) on a TPU that keeps
+    the table with ``L`` minor is a masked column store, one Pallas
+    kernel over the lanes (``mxtpu.kernels.kv_write``); every other
+    write is the lanes' loop of ``_write_lanes``.  Which of the two is
+    decided from what is observed here — ``T``, the backend, the
+    table's layout on the device — and both store the same bits.
     ``layer`` and ``plane`` are static attributes, so they ride the
     symbol's JSON.  Values are cast to the table's dtype on write, so
     a bf16 cache under mxtpu.amp stays bf16 regardless of compute
     dtype; a write that would run past ``L`` is clamped to end there,
     as ``dynamic_update_slice`` does."""
-    return _write_lanes(table, new.astype(table.dtype),
-                        jnp.asarray(step).astype(jnp.int32),
-                        jnp.int32(layer), jnp.int32(plane))
+    from ..kernels import kv_write
+    new = new.astype(table.dtype)
+    idx = jnp.asarray(step).astype(jnp.int32)
+    if new.shape[2] == 1 and _capacity_is_minor(table):
+        return kv_write.kv_write(
+            table, new, jnp.clip(idx, 0, table.shape[4] - 1),
+            jnp.int32(layer), jnp.int32(plane))
+    return _write_lanes(table, new, idx, jnp.int32(layer),
+                        jnp.int32(plane))
+
+
+def _capacity_is_minor(table):
+    """Whether the column store may take a one-token write: Pallas
+    kernels run here, and the device keeps the table with ``L`` minor
+    and ``head_dim`` next, so that the table with its last two axes
+    swapped is the same bytes and the kernel binds it where it lies."""
+    from .. import kernels
+    if not kernels.pallas_enabled():
+        return False
+    order = tuple(_resident_layout(table).major_to_minor)
+    return order == (0, 1, 2, 3, 5, 4)
 
 
 @jax.jit
 def _write_lanes(table, new, idx, layer, plane):
-    """The lanes' loop of ``kv_cache_write``.  ``layer`` and ``plane``
-    arrive as values so that one traced loop serves every plane: an
-    eager forward compiles it once, and inside a program it is one
-    callee whose arguments XLA folds to the constants they are."""
+    """The lanes' loop of ``kv_cache_write``: each turn one
+    ``lax.dynamic_update_slice`` on the 6-D table, which XLA performs
+    in the donated buffer, the loop's carry held to the layout the
+    table has on the device (see ``_resident_layout``).  Right for a
+    prefill's ``T`` contiguous positions, which are runs along the
+    minor axis; a single position is a column there, stored element by
+    element (4.2 us an update: PERF.md, PR 26 and PR 29), which is why
+    the decode step takes the kernel.  What was measured against the
+    loop on the chip (PERF.md, PR 26): the same updates unrolled take
+    a third less time and add half a minute to every set-up;
+    ``lax.scatter`` and a loop whose carry is left free bring two
+    copies of the whole table, ``.at[...].set`` with index arrays a
+    transpose of it round every write.
+    ``layer`` and ``plane`` arrive as values so that one traced loop
+    serves every plane: an eager forward compiles it once, and inside
+    a program it is one callee whose arguments XLA folds to the
+    constants they are."""
     from jax.experimental.layout import with_layout_constraint
     held = _resident_layout(table)
     zero = jnp.int32(0)

@@ -17,9 +17,11 @@ Three pieces:
   together, and such a graph also takes each row's number of valid new
   tokens and returns one row of logits a lane.  The
   graph threads the whole table through its layers: layer i's
-  ``kv_cache_write`` (a loop over the lanes, each turn one
-  ``lax.dynamic_update_slice`` on the table) puts each lane's new
-  rows at its OWN step index of planes
+  ``kv_cache_write`` (a decode step's one token a lane: one Pallas
+  kernel that stores the column, ``mxtpu.kernels.kv_write``, where the
+  device keeps the table with ``L`` minor; otherwise a loop over the
+  lanes, each turn one ``lax.dynamic_update_slice`` on the table) puts
+  each lane's new rows at its OWN step index of planes
   ``(i, 0)`` and ``(i, 1)``, ``kv_cache_read`` hands those planes to
   ``cached_attention``, which masks scores to each lane's valid
   prefix, so stale cache beyond a lane's frontier is unreachable and
@@ -692,6 +694,7 @@ class GenerateRunner:
             kv_argnum = len(in_structs) - 1
             t0 = time.perf_counter()
             from mxtpu import analysis
+            from ..kernels import kv_write
             compiled, source, ckey, cmeta = None, "cold", None, {}
             with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
                               kind=kind, bucket=str(bucket[1])) as rg:
@@ -711,23 +714,30 @@ class GenerateRunner:
                     jitted = jax.jit(
                         fn, donate_argnums=(kv_argnum,)
                         if apply_donate else ())
-                    compiled = jitted.lower(
-                        *in_structs, self._param_structs).compile()
+                    with kv_write.call_sites() as traced:
+                        compiled = jitted.lower(
+                            *in_structs, self._param_structs).compile()
+                    cmeta = dict(analysis.audit_stamp(),
+                                 kv_kernel_writes=traced[0])
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
                     if ckey is not None:
-                        self._cache.store(ckey, compiled,
-                                          meta=analysis.audit_stamp())
+                        self._cache.store(ckey, compiled, meta=cmeta)
                 elif analysis.needs_reaudit(cmeta):
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
-                rg.set(source=source)
+                # the one-token writes of the KV table that this program
+                # makes by the column-store kernel (0: the lanes' loop);
+                # a program loaded from disk says what its writer traced
+                kv_kernel_writes = int(cmeta.get("kv_kernel_writes", 0))
+                rg.set(source=source, kv_kernel_writes=kv_kernel_writes)
                 temp_bytes = (analysis.mem_stats(compiled) or {}).get(
                     "temp_size_in_bytes")
                 if temp_bytes is not None:
                     rg.set(temp_bytes=temp_bytes)
             self.compile_seconds[bucket] = time.perf_counter() - t0
-            entry = {"compiled": compiled, "in_structs": in_structs}
+            entry = {"compiled": compiled, "in_structs": in_structs,
+                     "kv_kernel_writes": kv_kernel_writes}
             self._entries[bucket] = entry
             self._compile_sources[bucket] = source
             if self._obs:
@@ -842,7 +852,8 @@ class GenerateRunner:
             with self._region(name + obs.SPAN_FETCH):
                 # mxlint: sync-point — deliberate D2H: the batcher samples on host
                 logits = np.asarray(logits)
-            rg.set(logits_bytes=logits.nbytes)
+            rg.set(logits_bytes=logits.nbytes,
+                   kv_kernel_writes=entry["kv_kernel_writes"])
         return logits, kv
 
     # -- introspection / contracts ----------------------------------------

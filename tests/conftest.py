@@ -62,6 +62,17 @@ def _seed_everything():
     yield
 
 
+@pytest.fixture
+def column_store(monkeypatch):
+    """The chip's answer, given here: Pallas kernels run (under the
+    interpreter) and the device keeps a KV table with ``L`` minor, so a
+    one-token ``kv_cache_write`` takes the column-store kernel
+    (``mxtpu.kernels.kv_write``)."""
+    from mxtpu.ndarray import rnn_impl
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    monkeypatch.setattr(rnn_impl, "_capacity_is_minor", lambda t: True)
+
+
 # thread pools park non-daemon workers for reuse; those are pool
 # lifecycle, not a test leaking its own worker
 _LEAK_ALLOW = ("ThreadPoolExecutor-", "asyncio_", "pydevd.")

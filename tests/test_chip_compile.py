@@ -60,6 +60,26 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
 
 
+@pytest.fixture
+def chip_layouts(topo, monkeypatch):
+    """``rnn_impl._resident_layout`` asks the default device, which is
+    the CPU here; the described chip answers in its place, as the chip
+    does for a table's shape and dtype (PERF.md, PR 26 and PR 29)."""
+    import numpy as np
+    from jax.experimental.layout import Layout
+    from mxtpu.ndarray import rnn_impl
+    chip = topo.devices[0]
+    monkeypatch.setattr(
+        rnn_impl, "_resident_layout",
+        lambda x: Layout.from_pjrt_layout(chip.client.get_default_layout(
+            np.dtype(x.dtype), tuple(x.shape), chip)))
+    # the lanes' loop keeps its traces: none made with the other
+    # answer may serve here, nor this one serve a later test
+    rnn_impl._write_lanes.clear_cache()
+    yield
+    rnn_impl._write_lanes.clear_cache()
+
+
 def _attention(q_shape, k_shape, causal, grad):
     from mxtpu.kernels import flash_attention
 
@@ -142,38 +162,42 @@ def test_kernel_compiles_for_v5e(case, one_chip, on_tpu,
         f"the lax reference was taken: {calls}"
 
 
-def test_kv_table_is_written_in_place_on_v5e(one_chip, monkeypatch,
-                                             no_persistent_cache,
-                                             request):
-    """The serve cell's slot table at its real widths (32 slots, 16
-    heads, 512 positions, head_dim 64, float32; two layers of the 24):
-    written by ``kv_cache_write``, read by ``cached_attention``, and
-    handed back.  The chip's compiler must alias the donated table to
-    the result and need less than one cache plane of temporaries — a
-    table copied, or carried through the lanes' loop in another
-    layout, shows as a table's worth."""
-    from jax.experimental.layout import Layout
+@pytest.mark.parametrize(
+    "dtype,slots,heads,query_heads,cap,tiles",
+    [(jnp.float32, 32, 16, 16, 512, ((8, 128),)),
+     (jnp.bfloat16, 49, 8, 32, 1280, ((8, 128), (2, 1)))],
+    ids=["bertgen-f32", "hybrid-bf16"])
+def test_kv_table_is_written_in_place_on_v5e(
+        dtype, slots, heads, query_heads, cap, tiles, one_chip, on_tpu,
+        chip_layouts, no_persistent_cache):
+    """A serve cell's slot table at its real widths — bertgen: 32
+    slots, 16 heads, 512 positions, float32; the hybrid: 49 slots, 8
+    key/value heads under 32 query heads, 1280 positions, bfloat16;
+    head_dim 64, two of the layers — written by ``kv_cache_write``
+    one token a lane, read by ``cached_attention``, and handed back.
+    The described chip says where it keeps such a table: ``L`` minor,
+    so the writes take the column-store kernel.  The chip's compiler
+    must alias the donated table to the result and need less than one
+    cache plane of temporaries — a table copied, a swap of the last
+    two axes that is not a bitcast, or a table carried through a loop
+    in another layout shows as a table's worth — and the program holds
+    one custom call a write and no loop."""
     from mxtpu.ndarray import rnn_impl
-    # the default device here is the CPU; this is the chip's answer
-    # for the table's shape (PERF.md, PR 26)
-    monkeypatch.setattr(
-        rnn_impl, "_resident_layout",
-        lambda x: Layout(major_to_minor=(0, 1, 2, 3, 5, 4),
-                         tiling=((8, 128),)))
-    # the lanes' loop keeps its traces: none made with the other
-    # answer may serve here, nor this one serve a later test
-    rnn_impl._write_lanes.clear_cache()
-    request.addfinalizer(rnn_impl._write_lanes.clear_cache)
-    layers, slots, heads, cap, dim = 2, 32, 16, 512, 64
+    layers, dim = 2, 64
+    shape = (layers, 2, slots, heads, cap, dim)
+    held = rnn_impl._resident_layout(jax.ShapeDtypeStruct(shape, dtype))
+    assert held.major_to_minor == (0, 1, 2, 3, 5, 4)
+    assert held.tiling == tiles
 
     def step(table, k, v, q, at):
         # as in the model, a layer's keys and values come from the
         # layer below: its reads of the table end before they are made
         out = jnp.zeros_like(q)
         for i in range(layers):
-            table = rnn_impl._kv_cache_write_op(table, k[i] + out, at,
+            below = out[:, ::query_heads // heads]
+            table = rnn_impl._kv_cache_write_op(table, k[i] + below, at,
                                                 layer=i, plane=0)
-            table = rnn_impl._kv_cache_write_op(table, v[i] + out, at,
+            table = rnn_impl._kv_cache_write_op(table, v[i] + below, at,
                                                 layer=i, plane=1)
             out = rnn_impl._cached_attention_op(
                 q + out,
@@ -181,18 +205,41 @@ def test_kv_table_is_written_in_place_on_v5e(one_chip, monkeypatch,
                 rnn_impl._kv_cache_read_op(table, layer=i, plane=1), at)
         return out, table
 
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    from mxtpu import analysis
+    text, mem = analysis.compiled_artifact(
+        step, sds(*shape, dtype=dtype),
+        sds(layers, slots, heads, 1, dim),
+        sds(layers, slots, heads, 1, dim), sds(slots, query_heads, 1, dim),
+        sds(slots), donate_argnums=0)
+    plane = slots * heads * cap * dim * jnp.dtype(dtype).itemsize
+    assert mem["alias_size_in_bytes"] == layers * 2 * plane
+    assert mem["temp_size_in_bytes"] < plane, mem
+    calls = analysis.summarize(text, mem)["custom_calls"]
+    assert calls["tpu_custom_call"]["count"] == layers * 2, calls
+    assert " while(" not in text
+
+
+def test_a_prefill_write_keeps_the_lanes_loop_on_v5e(
+        one_chip, on_tpu, chip_layouts, no_persistent_cache):
+    """Sixty-four positions a lane are runs along the minor axis, not
+    columns: on the same table the write stays the loop of
+    ``dynamic_update_slice``, in place."""
+    from mxtpu.ndarray import rnn_impl
+    shape = (2, 2, 8, 16, 512, 64)
+
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     from mxtpu import analysis
-    _, mem = analysis.compiled_artifact(
-        step, sds(layers, 2, slots, heads, cap, dim),
-        sds(layers, slots, heads, 1, dim),
-        sds(layers, slots, heads, 1, dim), sds(slots, heads, 1, dim),
-        sds(slots), donate_argnums=0)
-    plane = slots * heads * cap * dim * 4
-    assert mem["alias_size_in_bytes"] == layers * 2 * plane
-    assert mem["temp_size_in_bytes"] < plane, mem
+    text, mem = analysis.compiled_artifact(
+        lambda t, n, at: rnn_impl._kv_cache_write_op(t, n, at, 1, 1),
+        sds(*shape), sds(8, 16, 64, 64), sds(8), donate_argnums=0)
+    assert mem["alias_size_in_bytes"] == 2 * 2 * 8 * 16 * 512 * 64 * 4
+    assert mem["temp_size_in_bytes"] < 8 * 16 * 512 * 64 * 4, mem
+    assert " while(" in text and "tpu_custom_call" not in text
 
 
 def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,

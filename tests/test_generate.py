@@ -736,13 +736,25 @@ def _serve_by_hand(r):
     return out, np.asarray(kv)
 
 
+@pytest.mark.parametrize("write", ["loop", "kernel"])
 def test_in_place_table_equals_slice_and_stack(any_export, any_runner,
-                                               monkeypatch):
+                                               monkeypatch, request,
+                                               write):
     """Lanes at different frontiers, a chunked prefill and a reused
-    lane: the programs that write the table in place give the logits
-    and the table of programs traced from the same graph with the
-    slice/write/stack reference in the write's place."""
-    got, got_kv = _serve_by_hand(any_runner)
+    lane: the programs that write the table in place — every write by
+    the lanes' loop, or the decode step's by the column-store kernel —
+    give the logits and the table of programs traced from the same
+    graph with the slice/write/stack reference in the write's place."""
+    if write == "kernel":
+        request.getfixturevalue("column_store")
+        r = _any_runner(any_export)
+        r.warmup()
+        assert r._entries[("decode", (LANES + 1,))][
+            "kv_kernel_writes"] == 2 * NL
+        assert r._entries[("prefill", (2, 4))]["kv_kernel_writes"] == 0
+    else:
+        r = any_runner
+    got, got_kv = _serve_by_hand(r)
     ref = _any_runner(any_export)
     with monkeypatch.context() as m:
         m.setattr(get_op("kv_cache_write"), "fn",
@@ -760,16 +772,22 @@ def test_in_place_table_equals_slice_and_stack(any_export, any_runner,
     assert np.abs(got_kv[:, :, :LANES, :, :10]).sum() > 0
 
 
-def test_kv_cache_write_rows_frontiers_cast_and_clamp():
+@pytest.mark.parametrize("T,write", [(3, "loop"), (1, "loop"),
+                                     (1, "kernel"), (3, "kernel")])
+def test_kv_cache_write_rows_frontiers_cast_and_clamp(T, write, request):
     """The write alone against a loop over lanes: T rows at each
     lane's own frontier of the named plane and nowhere else, cast to
     the table's dtype (a bf16 table stays bf16 whatever the compute
-    dtype), a write past the end of ``L`` clamped to end there."""
+    dtype), a write past the end of ``L`` clamped to end there.  By
+    the lanes' loop, and where the column-store kernel is on offer: it
+    takes the one-token write and leaves the others to the loop."""
+    if write == "kernel":
+        request.getfixturevalue("column_store")
     rng = np.random.RandomState(0)
-    layers, B, H, cap, D, T = 3, 4, 2, 8, 4, 3
+    layers, B, H, cap, D = 3, 4, 2, 8, 4
     table = rng.randn(layers, 2, B, H, cap, D).astype(np.float32)
     new = rng.randn(B, H, T, D).astype(np.float32)
-    step = np.array([0, 2, 5, 7], np.float32)     # 7 + 3 > 8: clamps to 5
+    step = np.array([0, 2, 5, 9], np.float32)     # past the end: clamps
     for dtype in (jnp.float32, jnp.bfloat16):
         t0 = jnp.asarray(table).astype(dtype)
         out = mx.nd.kv_cache_write(
@@ -777,7 +795,7 @@ def test_kv_cache_write_rows_frontiers_cast_and_clamp():
             mx.nd.array(step), layer=1, plane=1)
         assert out.data.dtype == dtype
         want = np.array(t0.astype(jnp.float32))
-        for b, s in enumerate([0, 2, 5, 5]):
+        for b, s in enumerate([0, 2, 5, cap - T]):
             want[1, 1, b, :, s:s + T] = np.asarray(
                 jnp.asarray(new[b]).astype(dtype).astype(jnp.float32))
         np.testing.assert_array_equal(
@@ -785,6 +803,81 @@ def test_kv_cache_write_rows_frontiers_cast_and_clamp():
         plane = mx.nd.kv_cache_read(out, layer=1, plane=1)
         np.testing.assert_array_equal(
             np.asarray(plane.data.astype(jnp.float32)), want[1, 1])
+    from mxtpu import analysis
+    from mxtpu.ndarray import rnn_impl
+    text = analysis.lowered_text(
+        lambda t, n, s: rnn_impl._kv_cache_write_op(t, n, s, 1, 1),
+        table, new, step)
+    # the Pallas interpreter keeps the kernel's ``name=`` in op names
+    by_kernel = T == 1 and write == "kernel"
+    assert ("kv_cache_write" in text) == by_kernel
+    assert by_kernel or "dynamic-update-slice" in text
+
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(
+        x, {2: jnp.uint16, 4: jnp.uint32}[x.dtype.itemsize]))
+
+
+@pytest.mark.parametrize("dtype,new_dtype,heads,cap", [
+    (jnp.float32, jnp.float32, 16, 256),
+    (jnp.bfloat16, jnp.bfloat16, 8, 384),
+    (jnp.bfloat16, jnp.float32, 16, 256),      # the cast on the way in
+    (jnp.float32, jnp.float32, 4, 200),        # a last block cut short
+    (jnp.float32, jnp.float32, 32, 128),       # heads over two blocks
+], ids=["f32-h16", "bf16-h8", "f32-into-bf16", "cap200", "h32"])
+def test_column_store_stores_the_bits_of_the_lanes_loop(
+        dtype, new_dtype, heads, cap, column_store, monkeypatch):
+    """The kernel against ``_write_lanes`` bit for bit, through the
+    op: frontiers at 0, either side of a block's edge, the last
+    position and past it (clamped), two lanes at one frontier, the
+    scratch slot, a negative zero; every plane but the named one
+    untouched."""
+    from mxtpu.kernels import kv_write
+    from mxtpu.ndarray import rnn_impl
+    if heads == 32:
+        monkeypatch.setattr(kv_write, "_BLOCK_BYTES", 16 * 64 * 128 * 4)
+    rng = np.random.RandomState(1)
+    layers, B, D = 2, 7, 64
+    table = jnp.asarray(rng.randn(layers, 2, B, heads, cap, D), dtype)
+    new = jnp.asarray(rng.randn(B, heads, 1, D), new_dtype)
+    new = new.at[0, 0, 0, 0].set(-0.0)
+    step = jnp.asarray([0, 127, 128, cap - 1, cap + 5, 127, 3],
+                       jnp.float32)
+    for layer, plane in ((0, 1), (1, 0)):
+        want = rnn_impl._write_lanes(
+            table, new.astype(dtype), step.astype(jnp.int32),
+            jnp.int32(layer), jnp.int32(plane))
+        with kv_write.call_sites() as traced:
+            got = rnn_impl._kv_cache_write_op(table, new, step,
+                                              layer=layer, plane=plane)
+        assert traced == [1]
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        assert (_bits(got) != _bits(table)).any()
+
+
+def test_column_store_is_taken_by_what_is_observed(monkeypatch):
+    """One token a lane, Pallas kernels on, and a device that keeps
+    the table with ``L`` minor and ``head_dim`` next: the three
+    together, and nothing a caller sets."""
+    from jax.experimental.layout import Layout
+    from mxtpu import kernels
+    from mxtpu.ndarray import rnn_impl
+    table = jnp.zeros((1, 2, 2, 2, 8, 4))
+    assert not rnn_impl._capacity_is_minor(table)   # the CPU, no Pallas
+    monkeypatch.setattr(kernels, "pallas_enabled", lambda: True)
+    assert not rnn_impl._capacity_is_minor(table)   # row-major here
+    for order, minor in (((0, 1, 2, 3, 5, 4), True),
+                         ((0, 1, 2, 3, 4, 5), False),
+                         ((0, 1, 2, 5, 3, 4), False)):
+        monkeypatch.setattr(
+            rnn_impl, "_resident_layout",
+            lambda x, order=order: Layout(major_to_minor=order,
+                                          tiling=((8, 128),)))
+        assert rnn_impl._capacity_is_minor(table) is minor
+    monkeypatch.setattr(kernels, "pallas_enabled", lambda: False)
+    assert not rnn_impl._capacity_is_minor(table)
 
 
 def test_write_casts_to_a_bf16_table_under_amp(net):

@@ -148,7 +148,8 @@ GEN_COUNTS = {
     "gen/prefill": {"rows", "rung", "bucket", "chunks"},
     "gen/prefill/call": {"rows", "bucket"},
     "gen/prefill/call/done": {"logits_bytes"},
-    "gen/decode": {"slots"}, "gen/decode/done": {"logits_bytes"},
+    "gen/decode": {"slots"},
+    "gen/decode/done": {"logits_bytes", "kv_kernel_writes"},
     "gen/sample": {"lanes"}, "gen/fire": {"tokens"},
 }
 
@@ -320,8 +321,15 @@ def test_ttft_and_token_gaps_count_the_step_that_made_the_token(runner):
     assert list(stats._tok_us) == [0.25e6, 0.25e6]
 
 
-def test_compile_regions_count_new_buckets_only(export, tmp_path):
-    """One ``compile`` region per entry built, none on a second call."""
+@pytest.mark.parametrize("write", ["loop", "kernel"])
+def test_compile_regions_count_new_buckets_only(export, tmp_path,
+                                                request, write):
+    """One ``compile`` region per entry built, none on a second call;
+    each says how many of its program's one-token writes of the KV
+    table the column-store kernel makes, and ``gen/decode`` says it of
+    the program it ran."""
+    if write == "kernel":
+        request.getfixturevalue("column_store")
     r = _runner(export, prompt_buckets=(4,))
     with _Session(tmp_path / "first") as s:
         _serve(r, prompts=((1, 2, 3),), max_tokens=2)
@@ -331,6 +339,11 @@ def test_compile_regions_count_new_buckets_only(export, tmp_path):
     assert all(e[3]["entry"].startswith("GenerateRunner") for e in built)
     done = s.named("compile/done")
     assert [e[3]["source"] for e in done] == ["cold", "cold"]
+    # prefill is built first; its writes are four positions a lane
+    sites = 2 * NL if write == "kernel" else 0
+    assert [int(e[3]["kv_kernel_writes"]) for e in done] == [0, sites]
+    assert {int(e[3]["kv_kernel_writes"])
+            for e in s.named("gen/decode/done")} == {sites}
     # each program's temporary bytes: the count a rebuilt KV table
     # shows in, and the operator's gauge of the same number
     temps = sorted(int(e[3]["temp_bytes"]) for e in done)
