@@ -15,8 +15,8 @@ schedules them over ICI (SURVEY.md §2.4, §5.8).
 ``KVStore`` (``mxtpu.kvstore``) remains as the API-parity facade; this
 module is the mechanism.
 
-ZeRO-1 (default on single-process ``dp`` meshes, kill switch
-``MXTPU_ZERO=0``): instead of all-reducing full gradients and keeping
+ZeRO-1 (default on single-process ``dp`` meshes, ``zero=0`` turns it
+off): instead of all-reducing full gradients and keeping
 a replicated optimizer-state copy per device, the step reduce-scatters
 each (shape, dtype) bucket's gradients, updates the 1/dp state shard
 the device owns, and all-gathers the fresh params — the in-graph form
@@ -244,6 +244,12 @@ def plan_zero_buckets(sigs, dp: int, stack_axis_only: bool = False):
     return buckets
 
 
+def _batch_spec(ndim: int, axis: int, name: str) -> P:
+    """Spec of a rank-``ndim`` batch array sharded over mesh axis
+    ``name`` on its ``axis`` (replicated where it has no such axis)."""
+    return P(*[name if d == axis else None for d in range(ndim)])
+
+
 # the two phases of every step body, by the names their operations
 # carry in the program's op_name metadata (bucket packing and unpacking
 # belong to the optimizer's)
@@ -260,6 +266,103 @@ def _mem_stats(compiled):
     return memflow.mem_stats(compiled)
 
 
+class _Gspmd:
+    """The exchange of an unsharded step: every array is global, so
+    each piece is the identity and the gradient all-reduce stays
+    implicit, GSPMD's to insert."""
+
+    def wrap(self, step, x_raw, y_raw, n_extra):
+        return step
+
+    def shard_key(self, key_data):
+        return key_data
+
+    def reduce(self, loss, raw_aux):
+        return loss, raw_aux
+
+    def scatter(self, g, b):
+        return g
+
+    def mean(self, g):
+        return g
+
+    def own(self, v, b, axis):
+        return v
+
+    def agree(self, finite):
+        return finite
+
+    def gather(self, w2, b):
+        return w2
+
+
+class _Zero1(_Gspmd):
+    """The ZeRO-1 exchange: the step is an explicit ``shard_map`` over
+    ``dp_axis``, each bucket's gradients are reduce-scattered in and
+    its updated weights all-gathered out.  GSPMD's
+    ReduceScatterCreator pass is GPU/TPU only, so sharding constraints
+    alone cannot guarantee the reduce-scatter on every backend — the
+    explicit collectives make the comm layout part of the program,
+    testable from the HLO on the CPU virtual mesh."""
+
+    def __init__(self, mesh, dp_axis, batch_axis, state_specs):
+        self.mesh, self.dp_axis = mesh, dp_axis
+        self.dp = mesh.shape[dp_axis]
+        self.batch_axis, self.state_specs = batch_axis, state_specs
+
+    def wrap(self, step, x_raw, y_raw, n_extra):
+        rep = (P(),) * n_extra
+        in_specs = (P(), P(), self.state_specs, P(), P(), P()) + tuple(
+            _batch_spec(v.ndim, self.batch_axis, self.dp_axis)
+            for v in (x_raw, y_raw)) + rep
+        out_specs = (P(), P(), self.state_specs, P()) + rep
+        # check_vma=False: the checker can't infer that the tiled
+        # all_gather output is replicated
+        return jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
+
+    def shard_key(self, key_data):
+        # decorrelate dropout across shards (the GSPMD path gets this
+        # for free from its globally-sharded RNG)
+        return jax.random.key_data(jax.random.fold_in(
+            jax.random.wrap_key_data(key_data),
+            lax.axis_index(self.dp_axis)))
+
+    def reduce(self, loss, raw_aux):
+        # loss_flat reduces over the LOCAL shard; equal shard sizes
+        # make the mean of shard means the global mean
+        return lax.psum(loss, self.dp_axis) / self.dp, tuple(
+            lax.pmean(a, self.dp_axis)
+            if jnp.issubdtype(a.dtype, jnp.inexact) else a
+            for a in raw_aux)
+
+    def scatter(self, g, b):
+        # THE ZeRO exchange: reduce-scatter replaces the gradient
+        # all-reduce; this device gets the summed rows it owns
+        return lax.psum_scatter(g, self.dp_axis,
+                                scatter_dimension=b["axis"], tiled=True)
+
+    def mean(self, g):
+        # the sum over shards becomes the mean that matches the
+        # mean-of-shard-means loss
+        return g / self.dp
+
+    def own(self, v, b, axis):
+        # rows [me*rows, (me+1)*rows) of the padded bucket
+        return lax.dynamic_slice_in_dim(
+            v, lax.axis_index(self.dp_axis) * b["rows"], b["rows"], axis)
+
+    def agree(self, finite):
+        return lax.psum((~finite).astype(jnp.int32), self.dp_axis) == 0
+
+    def gather(self, w2, b):
+        ax = b["axis"]
+        w2 = lax.all_gather(w2, self.dp_axis, axis=ax, tiled=True)
+        if b["pad"]:
+            w2 = lax.slice_in_dim(w2, 0, b["stacked_shape"][ax], axis=ax)
+        return w2
+
+
 class TrainStep:
     """One fused XLA executable per (shape signature): fwd + bwd +
     collectives + optimizer + aux writeback.  Call with (x, y) batches;
@@ -272,9 +375,8 @@ class TrainStep:
     device updates only the 1/dp state shard it owns, and the fresh
     params are all-gathered back to replicated — optimizer HBM drops
     ~dp× at the same total comm bytes as the all-reduce it replaces.
-    ``zero=0`` (or ``MXTPU_ZERO=0`` in the environment) restores the
-    replicated GSPMD path; ``zero=1`` insists and raises where ZeRO
-    can't apply.  The ZeRO step is an explicit ``shard_map`` over
+    ``zero=0`` restores the replicated GSPMD path; ``zero=1`` insists
+    and raises where ZeRO can't apply.  The ZeRO step is an explicit ``shard_map`` over
     ``dp_axis``, with three contract changes vs the GSPMD path:
 
     * the batch dim must divide the dp size (error otherwise);
@@ -400,37 +502,27 @@ class TrainStep:
                 labels=("entry",)).labels(entry=_entry)
 
     def _decide_zero(self, zero) -> bool:
-        """Resolve the ZeRO-1 mode: ``MXTPU_ZERO=0`` is the global
-        kill switch, ``zero=0/1`` the per-step override, and the auto
-        default is ON exactly when the mechanism applies — a
-        single-process mesh with a >1-sized ``dp_axis`` and no
-        tensor-parallel ``param_spec_fn``."""
-        env = knobs.get("MXTPU_ZERO").strip().lower()
-        if env in ("0", "off", "false"):
-            return False
+        """Resolve the ZeRO-1 mode: ``zero=0/1`` decides where it is
+        given, and the auto default is ON exactly when the mechanism
+        applies — a single-process mesh with a >1-sized ``dp_axis``
+        and no tensor-parallel ``param_spec_fn``."""
         if zero is not None and not zero:
             return False
-        forced = bool(zero)  # mxlint: disable=host-sync — Python arg
         if self.mesh is None or self.dp_axis not in self.mesh.shape \
                 or self.mesh.shape[self.dp_axis] <= 1:
-            if forced:
-                raise MXNetError(
-                    "zero=1 needs a mesh whose dp axis "
-                    f"({self.dp_axis!r}) has size > 1")
-            return False
-        if self.param_spec_fn is not None:
-            if forced:
-                raise MXNetError(
-                    "zero=1 does not compose with param_spec_fn "
-                    "(tensor parallelism) yet — drop one of the two")
-            return False
-        if _mesh_is_multiprocess(self.mesh):
-            if forced:
-                raise MXNetError(
-                    "zero=1 needs a single-process mesh (multi-host "
-                    "ZeRO is pending transport validation)")
-            return False
-        return True
+            why = ("zero=1 needs a mesh whose dp axis "
+                   f"({self.dp_axis!r}) has size > 1")
+        elif self.param_spec_fn is not None:
+            why = ("zero=1 does not compose with param_spec_fn "
+                   "(tensor parallelism) yet — drop one of the two")
+        elif _mesh_is_multiprocess(self.mesh):
+            why = ("zero=1 needs a single-process mesh (multi-host "
+                   "ZeRO is pending transport validation)")
+        else:
+            return True
+        if zero:
+            raise MXNetError(why)
+        return False
 
     def _amp_extra(self) -> tuple:
         """Trailing loss-scaler argument for the step callables —
@@ -560,6 +652,70 @@ class TrainStep:
             init_all,
             out_shardings=self._zero_state_shardings)(train_vals)
 
+    def _partition(self):
+        """The partition decision: the step's buckets — each with
+        ``jidx`` (positions in the trainable tuple), its shard
+        ``axis``, ``pad`` and whether it updates ``stacked`` — and
+        ``take``/``put``, which fetch a bucket's weights, gradients and
+        optimizer state and put the updated ones back.  These two are
+        the ONE place where the two state layouts meet: per-parameter
+        tuples, stacked and cut apart every step (unsharded; a group of
+        one stays unstacked), or the bucket's resident dp-sharded stack
+        (ZeRO-1, ``_init_zero_state``)."""
+        zero = self.zero
+        if zero:
+            buckets = [dict(b, stacked=True) for b in self._zero_buckets]
+        else:
+            # (shape, dtype) groups, each updated as ONE stacked op
+            # instead of one HLO chain per parameter: a BERT-Large step
+            # has ~25 bucket updates for ~400 parameters.  All rules
+            # are elementwise in (w, g, state) with lr/wd entering as
+            # broadcast (n,1,..,1) scalars (LAMB reduces its
+            # trust-ratio norms per slice), so a stack updates as its
+            # rows would alone.  MXTPU_BATCHED_OPT=0 makes every group
+            # one parameter: the per-parameter loop.
+            batched = knobs.get("MXTPU_BATCHED_OPT")
+            by_sig: Dict[Any, List[int]] = {}
+            for j, i in enumerate(self._train_idx):
+                v = self._params[i]._data._data
+                by_sig.setdefault((v.shape, str(v.dtype)) if batched
+                                  else j, []).append(j)
+            buckets = [{"jidx": js, "axis": None, "pad": 0,
+                        "stacked": len(js) > 1}
+                       for js in by_sig.values()]
+
+        def take(k, b, train_vals, grads, opt_state):
+            js = b["jidx"]
+            if not b["stacked"]:
+                return train_vals[js[0]], grads[js[0]], opt_state[js[0]]
+            w = jnp.stack([train_vals[j] for j in js])
+            g = jnp.stack([grads[j] for j in js])
+            if zero:
+                return w, g, opt_state[k]
+            return w, g, tuple(
+                jnp.stack([opt_state[j][n] for j in js])
+                for n in range(len(opt_state[js[0]])))
+
+        def put(k, b, w2, st2, new_vals, new_state):
+            js = b["jidx"]
+            if not b["stacked"]:
+                new_vals[js[0]], new_state[js[0]] = w2, st2
+                return
+            for a, j in enumerate(js):
+                new_vals[j] = w2[a]
+                if not zero:
+                    new_state[j] = tuple(leaf[a] for leaf in st2)
+            if zero:
+                new_state[k] = st2
+        return buckets, take, put
+
+    def _exchange(self):
+        """The exchange decision: what crosses replicas in a step."""
+        if not self.zero:
+            return _Gspmd()
+        return _Zero1(self.mesh, self.dp_axis, self.batch_axis,
+                      self._zero_state_specs)
+
     def _build(self, key, x_raw, y_raw):
         params = self._params
         train_idx = self._train_idx
@@ -629,145 +785,124 @@ class TrainStep:
                            else a for a in raw_aux]
             return jnp.mean(raw_l.astype(jnp.float32)), tuple(raw_aux)
 
-        # Batched optimizer apply: bucket trainable params by
-        # (shape, dtype) and update each bucket as ONE stacked op
-        # instead of one HLO chain per parameter — a BERT-Large step
-        # drops from ~400 per-param update chains to ~25 bucket
-        # updates.  All rules are elementwise in (w, g, state) with
-        # lr/wd entering as broadcast (n,1,..,1) scalars, so the
-        # stacked apply is numerically identical to the per-param loop
-        # (LAMB reduces its trust-ratio norms per slice).
-        # MXTPU_BATCHED_OPT=0 restores the per-param loop.
-        batched = knobs.get("MXTPU_BATCHED_OPT")
-        groups: List[List[int]] = []
-        if batched:
-            by_sig: Dict[Tuple, List[int]] = {}
-            for j, i in enumerate(train_idx):
-                v = params[i]._data._data
-                by_sig.setdefault((v.shape, str(v.dtype)), []).append(j)
-            groups = list(by_sig.values())
+        # The step is one sequence whatever its options; three pieces,
+        # each chosen here once from what the object already knows,
+        # say what the variants differ in: the partition (buckets and
+        # the state's layout), the exchange (what crosses replicas) and
+        # the precision (what happens to a gradient before its update).
+        buckets, take, put = self._partition()
+        ex = self._exchange()
+        opt_update = self._opt_update
+        window = self._amp_window if self._amp_scaler else None
 
-        def apply_updates(train_vals, grads, opt_state, lrs, wds):
-            n = len(train_vals)
-            new_vals: List[Any] = [None] * n
-            new_state: List[Any] = [None] * n
-            if not batched:
-                for j, (w, g, st) in enumerate(zip(train_vals, grads,
-                                                   opt_state)):
-                    new_vals[j], new_state[j] = self._opt_update(
-                        w, g, st, lrs[j], wds[j])
-                return tuple(new_vals), tuple(new_state)
-            for group in groups:
-                if len(group) == 1:
-                    j = group[0]
-                    new_vals[j], new_state[j] = self._opt_update(
-                        train_vals[j], grads[j], opt_state[j],
-                        lrs[j], wds[j])
-                    continue
-                w_s = jnp.stack([train_vals[j] for j in group])
-                g_s = jnp.stack([grads[j] for j in group])
-                n_leaves = len(opt_state[group[0]])
-                st_s = tuple(
-                    jnp.stack([opt_state[j][k] for j in group])
-                    for k in range(n_leaves))
-                # mxlint: disable=host-sync — Python index lists
-                idx = jnp.asarray(np.asarray(group, np.int32))
-                bshape = (len(group),) + (1,) * (w_s.ndim - 1)
-                lr_s = jnp.take(lrs, idx).reshape(bshape)
-                wd_s = jnp.take(wds, idx).reshape(bshape)
-                w2_s, st2_s = self._opt_update(w_s, g_s, st_s, lr_s,
-                                               wd_s, stacked=True)
-                for a, j in enumerate(group):
-                    new_vals[j] = w2_s[a]
-                    new_state[j] = tuple(leaf[a] for leaf in st2_s)
-            return tuple(new_vals), tuple(new_state)
+        def rates(b, ndim, *per_param):
+            """lr and wd of a bucket's rows, broadcast over its rank
+            ``ndim`` — the one place that does it."""
+            if not b["stacked"]:
+                return [v[b["jidx"][0]] for v in per_param]
+            # mxlint: disable=host-sync — Python index lists
+            idx = jnp.asarray(np.asarray(b["jidx"], np.int32))
+            out = []
+            for v in per_param:
+                r = jnp.take(v, idx)
+                if b["axis"] == 0:
+                    # per-row rates follow the rows this device owns;
+                    # an inner-axis shard sees every row
+                    if b["pad"]:
+                        r = jnp.pad(r, (0, b["pad"]))
+                    r = ex.own(r, b, 0)
+                out.append(r.reshape(r.shape + (1,) * (ndim - 1)))
+            return out
+
+        def apply_updates(train_vals, grads, opt_state, lrs, wds, scale):
+            """Every bucket: exchange in, update, exchange out.
+            Returns ``(new_vals, new_state, finite)``; ``finite`` is
+            None where no loss scale asks for the test."""
+            new_vals: List[Any] = [None] * len(train_vals)
+            new_state: List[Any] = [None] * len(opt_state)
+
+            def exchanged(k, b):
+                w, g, st = take(k, b, train_vals, grads, opt_state)
+                if b["pad"]:
+                    widths = [(0, 0)] * w.ndim
+                    widths[b["axis"]] = (0, b["pad"])
+                    w, g = jnp.pad(w, widths), jnp.pad(g, widths)
+                g = ex.scatter(g, b)
+                if amp_on:
+                    # grads reach the param edge in bf16 (AD transpose
+                    # of the entry upcast; half the reduce-scatter
+                    # bytes under ZeRO-1): unscale in f32, so the
+                    # finite test and the optimizer see full range
+                    g = g.astype(jnp.float32)
+                g = ex.mean(g)
+                if scale is not None:
+                    g = g / scale
+                return k, b, w, g, st
+
+            todo = (exchanged(k, b) for k, b in enumerate(buckets))
+            finite = None
+            if scale is not None:
+                # ONE finite consensus gates every update — all shards
+                # must agree to skip, or padded-row mismatches would
+                # desynchronize the replicated params — so every bucket
+                # is exchanged before the first update; without a
+                # scale the generator interleaves the two per bucket
+                todo = list(todo)
+                finite = ex.agree(_amp_mod.all_finite(
+                    [g for _, _, _, g, _ in todo]))
+            for k, b, w, g, st in todo:
+                w_loc = ex.own(w, b, b["axis"])
+                w2, st2 = opt_update(w_loc, g, st,
+                                     *rates(b, w.ndim, lrs, wds),
+                                     stacked=b["stacked"])
+                if finite is not None:
+                    # skipped step: keep params AND state, back off
+                    w2, st2 = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o),
+                        (w2, st2), (w_loc, st))
+                put(k, b, ex.gather(w2, b), st2, new_vals, new_state)
+            return tuple(new_vals), tuple(new_state), finite
 
         def step(train_vals, frozen_vals, opt_state, key_data, lrs, wds,
-                 x, y):
+                 x, y, *extra):
+            # ``extra`` is the loss scaler's state, or nothing
+            # (_amp_extra): the off path keeps the pre-AMP signature
+            scale = extra[0][0] if extra else None
+
+            def objective(tv, fv, kd, xx, yy):
+                l, aux = loss_flat(tv, fv, kd, xx, yy)
+                return (l if scale is None
+                        else l * scale.astype(l.dtype)), (l, aux)
+
             with jax.named_scope(_SCOPE_FWD_BWD):
-                (loss, raw_aux), grads = jax.value_and_grad(
-                    loss_flat, has_aux=True)(train_vals, frozen_vals,
-                                             key_data, x, y)
+                (_, (loss, raw_aux)), grads = jax.value_and_grad(
+                    objective, has_aux=True)(
+                        train_vals, frozen_vals, ex.shard_key(key_data),
+                        x, y)
+                loss, raw_aux = ex.reduce(loss, raw_aux)
             with jax.named_scope(_SCOPE_OPTIMIZER):
-                new_vals, new_state = apply_updates(
-                    train_vals, grads, opt_state, lrs, wds)
-            return loss, new_vals, new_state, raw_aux
+                new_vals, new_state, finite = apply_updates(
+                    train_vals, grads, opt_state, lrs, wds, scale)
+                if scale is not None:
+                    extra = (_amp_mod.scaler_update(extra[0], finite,
+                                                    window),)
+            return (loss, new_vals, new_state, raw_aux) + extra
 
-        if amp_on and not self.zero:
-            window = self._amp_window if self._amp_scaler else None
-
-            if self._amp_scaler:
-                def step(train_vals, frozen_vals, opt_state, key_data,  # noqa: F811
-                         lrs, wds, x, y, scaler):
-                    scale = scaler[0]
-
-                    def scaled(tv, fv, kd, xx, yy):
-                        l, aux = loss_flat(tv, fv, kd, xx, yy)
-                        return l * scale.astype(l.dtype), (l, aux)
-
-                    with jax.named_scope(_SCOPE_FWD_BWD):
-                        (_, (loss, raw_aux)), grads = \
-                            jax.value_and_grad(scaled, has_aux=True)(
-                                train_vals, frozen_vals, key_data, x, y)
-                    with jax.named_scope(_SCOPE_OPTIMIZER):
-                        # grads reach the param edge in bf16 (AD
-                        # transpose of the entry upcast); unscale in
-                        # f32 so the finite test and the optimizer see
-                        # full range
-                        grads = tuple(g.astype(jnp.float32) / scale
-                                      for g in grads)
-                        finite = _amp_mod.all_finite(grads)
-                        new_vals, new_state = apply_updates(
-                            train_vals, grads, opt_state, lrs, wds)
-                        # skipped step: keep params AND state, back off
-                        keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
-                        new_vals = tuple(map(keep, new_vals,
-                                             train_vals))
-                        new_state = jax.tree_util.tree_map(
-                            keep, new_state, opt_state)
-                        scaler2 = _amp_mod.scaler_update(
-                            scaler, finite, window)
-                    return loss, new_vals, new_state, raw_aux, scaler2
-            else:
-                def step(train_vals, frozen_vals, opt_state, key_data,  # noqa: F811
-                         lrs, wds, x, y):
-                    with jax.named_scope(_SCOPE_FWD_BWD):
-                        (loss, raw_aux), grads = jax.value_and_grad(
-                            loss_flat, has_aux=True)(
-                                train_vals, frozen_vals, key_data, x, y)
-                    with jax.named_scope(_SCOPE_OPTIMIZER):
-                        grads = tuple(g.astype(jnp.float32)
-                                      for g in grads)
-                        new_vals, new_state = apply_updates(
-                            train_vals, grads, opt_state, lrs, wds)
-                    return loss, new_vals, new_state, raw_aux
-
-        if self.zero:
-            # ZeRO-1 replaces the whole sync+update path: an explicit
-            # shard_map whose bucket exchange is reduce-scatter →
-            # shard-local update → all-gather
-            step = self._build_zero_step(loss_flat, x_raw, y_raw)
-
-        train_vals = tuple(params[i]._data._data for i in train_idx)
-        frozen_vals = tuple(params[i]._data._data for i in frozen_idx)
+        step = ex.wrap(step, x_raw, y_raw, len(self._amp_extra()))
+        train_vals, frozen_vals = self._vals(frozen_idx)
         zeros = jnp.zeros(len(train_idx), jnp.float32)
+        step_args = (train_vals, frozen_vals, self._opt_state,
+                     jax.random.key_data(key), zeros, zeros,
+                     x_raw, y_raw) + self._amp_extra()
         donate = (0, 2) if self.donate else ()
         fitted = jax.jit(step, donate_argnums=donate)
         fn = fitted
         mem = None
-        if (self.param_spec_fn is None and
-                (self.mesh is None
-                 or not _mesh_is_multiprocess(self.mesh))):
+        if self._aot:
             # AOT-compile now: the lowering trace doubles as the aux
             # discovery pass (no separate eval_shape), the first step
             # pays no tracing, and memory_analysis / cost_analysis /
-            # hlo_text come for free afterwards.  Multi-process meshes
-            # keep the jit wrapper — its dispatch handles cross-host
-            # arrays.  So does tensor-parallel (param_spec_fn): GSPMD
-            # may return updated params with a compiler-chosen
-            # sharding that differs from the placement the program was
-            # lowered with, and AOT executables reject input shardings
-            # that drift between steps.
+            # hlo_text come for free afterwards.
             # ISSUE 13: load-or-compile through the persistent cache.
             # The lowering trace does double duty: it is the aux
             # discovery pass AND the cache fingerprint — the lowered
@@ -776,14 +911,11 @@ class TrainStep:
             # different computations (relu vs tanh, a loss built with
             # different flags, distinct lambdas) can never share a
             # key.  A verified disk hit skips only the XLA compile.
-            lower_args = (train_vals, frozen_vals, self._opt_state,
-                          jax.random.key_data(key), zeros, zeros,
-                          x_raw, y_raw) + self._amp_extra()
             t0 = _prof._now_us()
             with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
                               kind="train",
                               bucket=str(x_raw.shape)) as rg:
-                lowered = fitted.lower(*lower_args)
+                lowered = fitted.lower(*step_args)
                 source, ckey, loaded, cmeta = "cold", None, None, {}
                 if self._cache is not None:
                     ckey = self._train_cache_key(lowered, x_raw, y_raw)
@@ -818,10 +950,7 @@ class TrainStep:
                     (_prof._now_us() - t0) / 1e6)
         else:
             # learn the aux structure without device work
-            jax.eval_shape(step, train_vals, frozen_vals,
-                           self._opt_state, jax.random.key_data(key),
-                           zeros, zeros, x_raw, y_raw,
-                           *self._amp_extra())
+            jax.eval_shape(step, *step_args)
         # aux (BN running stats) positions inside the frozen tuple, in
         # aux_params order, for the scanned multi-step path to thread
         # them through the carry (None if an aux is somehow trainable)
@@ -835,218 +964,6 @@ class TrainStep:
                 "leaves": 5 + len(jax.tree_util.tree_leaves(
                     (train_vals, frozen_vals, self._opt_state,
                      self._amp_extra())))}
-
-    def _build_zero_step(self, loss_flat, x_raw, y_raw):
-        """The ZeRO-1 step body: an explicit ``shard_map`` over
-        ``dp_axis``.  GSPMD's ReduceScatterCreator pass is GPU/TPU
-        only, so sharding constraints alone cannot guarantee the
-        reduce-scatter on every backend — the explicit collectives
-        make the comm layout part of the program, testable from the
-        HLO on the CPU virtual mesh."""
-        mesh, dp_axis = self.mesh, self.dp_axis
-        dp = self._zero_dp
-        buckets = self._zero_buckets
-        opt_update = self._opt_update
-        batch_axis = self.batch_axis
-        amp_on = self.amp
-        use_scaler = amp_on and self._amp_scaler
-        window = self._amp_window if use_scaler else None
-
-        def apply_zero(train_vals, grads, opt_state, lrs, wds):
-            new_vals: List[Any] = [None] * len(train_vals)
-            new_state = []
-            me = lax.axis_index(dp_axis)
-            for b, st in zip(buckets, opt_state):
-                js, ax, pad, rows = (b["jidx"], b["axis"], b["pad"],
-                                     b["rows"])
-                w_s = jnp.stack([train_vals[j] for j in js])
-                g_s = jnp.stack([grads[j] for j in js])
-                orig = w_s.shape[ax]
-                if pad:
-                    widths = [(0, 0)] * w_s.ndim
-                    widths[ax] = (0, pad)
-                    w_s = jnp.pad(w_s, widths)
-                    g_s = jnp.pad(g_s, widths)
-                # THE ZeRO exchange: reduce-scatter replaces the
-                # gradient all-reduce; this device owns rows
-                # [me*rows, (me+1)*rows) of the padded bucket.
-                # psum_scatter sums partial grads; /dp makes the mean
-                # matching the mean-of-shard-means loss
-                g_loc = lax.psum_scatter(g_s, dp_axis,
-                                         scatter_dimension=ax,
-                                         tiled=True)
-                if amp_on:
-                    # THE AMP comm payoff: grads arrive bf16 (half the
-                    # per-step reduce-scatter bytes); accumulate the
-                    # unscale/update math in f32 from here on
-                    g_loc = g_loc.astype(jnp.float32)
-                g_loc = g_loc / dp
-                start = me * rows
-                w_loc = lax.dynamic_slice_in_dim(w_s, start, rows, ax)
-                # mxlint: disable=host-sync — Python index lists
-                idxa = jnp.asarray(np.asarray(js, np.int32))
-                if ax == 0:
-                    # per-row lr/wd follow the rows this device owns
-                    lr_v = jnp.take(lrs, idxa)
-                    wd_v = jnp.take(wds, idxa)
-                    if pad:
-                        lr_v = jnp.pad(lr_v, (0, pad))
-                        wd_v = jnp.pad(wd_v, (0, pad))
-                    bshape = (rows,) + (1,) * (w_s.ndim - 1)
-                    lr_b = lax.dynamic_slice_in_dim(
-                        lr_v, start, rows, 0).reshape(bshape)
-                    wd_b = lax.dynamic_slice_in_dim(
-                        wd_v, start, rows, 0).reshape(bshape)
-                else:
-                    # inner-axis shard: every device sees every row
-                    bshape = (len(js),) + (1,) * (w_s.ndim - 1)
-                    lr_b = jnp.take(lrs, idxa).reshape(bshape)
-                    wd_b = jnp.take(wds, idxa).reshape(bshape)
-                w2_loc, st2 = opt_update(w_loc, g_loc, st, lr_b, wd_b,
-                                         stacked=True)
-                w2 = lax.all_gather(w2_loc, dp_axis, axis=ax,
-                                    tiled=True)
-                if pad:
-                    w2 = lax.slice_in_dim(w2, 0, orig, axis=ax)
-                for a, j in enumerate(js):
-                    new_vals[j] = w2[a]
-                new_state.append(st2)
-            return tuple(new_vals), tuple(new_state)
-
-        def apply_zero_amp(train_vals, grads, opt_state, lrs, wds,
-                           scale):
-            """Loss-scaled variant: phase 1 exchanges every bucket
-            (bf16 reduce-scatter) and unscales in f32, then ONE global
-            finite consensus gates phase 2's updates — every shard
-            must agree to skip, or padded-row mismatches would
-            desynchronize the replicated params."""
-            new_vals: List[Any] = [None] * len(train_vals)
-            new_state = []
-            me = lax.axis_index(dp_axis)
-            prep = []
-            bad = jnp.zeros((), jnp.int32)
-            for b, st in zip(buckets, opt_state):
-                js, ax, pad = b["jidx"], b["axis"], b["pad"]
-                w_s = jnp.stack([train_vals[j] for j in js])
-                g_s = jnp.stack([grads[j] for j in js])
-                orig = w_s.shape[ax]
-                if pad:
-                    widths = [(0, 0)] * w_s.ndim
-                    widths[ax] = (0, pad)
-                    w_s = jnp.pad(w_s, widths)
-                    g_s = jnp.pad(g_s, widths)
-                g_loc = lax.psum_scatter(g_s, dp_axis,
-                                         scatter_dimension=ax,
-                                         tiled=True)
-                g_loc = g_loc.astype(jnp.float32) / dp / scale
-                bad = bad + jnp.sum(
-                    ~jnp.isfinite(g_loc)).astype(jnp.int32)
-                prep.append((b, st, w_s, g_loc, orig))
-            finite = lax.psum(bad, dp_axis) == 0
-            for b, st, w_s, g_loc, orig in prep:
-                js, ax, pad, rows = (b["jidx"], b["axis"], b["pad"],
-                                     b["rows"])
-                start = me * rows
-                w_loc = lax.dynamic_slice_in_dim(w_s, start, rows, ax)
-                # mxlint: disable=host-sync — Python index lists
-                idxa = jnp.asarray(np.asarray(js, np.int32))
-                if ax == 0:
-                    lr_v = jnp.take(lrs, idxa)
-                    wd_v = jnp.take(wds, idxa)
-                    if pad:
-                        lr_v = jnp.pad(lr_v, (0, pad))
-                        wd_v = jnp.pad(wd_v, (0, pad))
-                    bshape = (rows,) + (1,) * (w_s.ndim - 1)
-                    lr_b = lax.dynamic_slice_in_dim(
-                        lr_v, start, rows, 0).reshape(bshape)
-                    wd_b = lax.dynamic_slice_in_dim(
-                        wd_v, start, rows, 0).reshape(bshape)
-                else:
-                    bshape = (len(js),) + (1,) * (w_s.ndim - 1)
-                    lr_b = jnp.take(lrs, idxa).reshape(bshape)
-                    wd_b = jnp.take(wds, idxa).reshape(bshape)
-                w2_loc, st2 = opt_update(w_loc, g_loc, st, lr_b, wd_b,
-                                         stacked=True)
-                # non-finite anywhere: keep shard params AND state
-                keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
-                w2_loc = keep(w2_loc, w_loc)
-                st2 = jax.tree_util.tree_map(keep, st2, st)
-                w2 = lax.all_gather(w2_loc, dp_axis, axis=ax,
-                                    tiled=True)
-                if pad:
-                    w2 = lax.slice_in_dim(w2, 0, orig, axis=ax)
-                for a, j in enumerate(js):
-                    new_vals[j] = w2[a]
-                new_state.append(st2)
-            return tuple(new_vals), tuple(new_state), finite
-
-        def body(train_vals, frozen_vals, opt_state, key_data, lrs,
-                 wds, x, y):
-            me = lax.axis_index(dp_axis)
-            # decorrelate dropout across shards (the GSPMD path gets
-            # this for free from its globally-sharded RNG)
-            with jax.named_scope(_SCOPE_FWD_BWD):
-                kd = jax.random.key_data(jax.random.fold_in(
-                    jax.random.wrap_key_data(key_data), me))
-                (loss, raw_aux), grads = jax.value_and_grad(
-                    loss_flat, has_aux=True)(train_vals, frozen_vals,
-                                             kd, x, y)
-                # loss_flat reduces over the LOCAL shard; equal shard
-                # sizes make the mean of shard means the global mean
-                loss = lax.psum(loss, dp_axis) / dp
-                raw_aux = tuple(
-                    lax.pmean(a, dp_axis)
-                    if jnp.issubdtype(a.dtype, jnp.inexact) else a
-                    for a in raw_aux)
-            with jax.named_scope(_SCOPE_OPTIMIZER):
-                new_vals, new_state = apply_zero(
-                    train_vals, grads, opt_state, lrs, wds)
-            return loss, new_vals, new_state, raw_aux
-
-        def body_amp(train_vals, frozen_vals, opt_state, key_data,
-                     lrs, wds, x, y, scaler):
-            me = lax.axis_index(dp_axis)
-            kd = jax.random.key_data(jax.random.fold_in(
-                jax.random.wrap_key_data(key_data), me))
-            scale = scaler[0]
-
-            def scaled(tv, fv, k2, xx, yy):
-                l, aux = loss_flat(tv, fv, k2, xx, yy)
-                return l * scale.astype(l.dtype), (l, aux)
-
-            with jax.named_scope(_SCOPE_FWD_BWD):
-                (_, (loss, raw_aux)), grads = jax.value_and_grad(
-                    scaled, has_aux=True)(train_vals, frozen_vals, kd,
-                                          x, y)
-                loss = lax.psum(loss, dp_axis) / dp
-                raw_aux = tuple(
-                    lax.pmean(a, dp_axis)
-                    if jnp.issubdtype(a.dtype, jnp.inexact) else a
-                    for a in raw_aux)
-            with jax.named_scope(_SCOPE_OPTIMIZER):
-                new_vals, new_state, finite = apply_zero_amp(
-                    train_vals, grads, opt_state, lrs, wds, scale)
-                scaler2 = _amp_mod.scaler_update(scaler, finite,
-                                                 window)
-            return loss, new_vals, new_state, raw_aux, scaler2
-
-        xspec = [None] * x_raw.ndim
-        xspec[batch_axis] = dp_axis
-        yspec = [None] * max(y_raw.ndim, 1)
-        if y_raw.ndim > batch_axis:
-            yspec[batch_axis] = dp_axis
-        in_specs = (P(), P(), self._zero_state_specs, P(), P(), P(),
-                    P(*xspec), P(*yspec[:y_raw.ndim]))
-        out_specs = (P(), P(), self._zero_state_specs, P())
-        fn = body
-        if use_scaler:
-            fn = body_amp
-            in_specs = in_specs + (P(),)
-            out_specs = out_specs + (P(),)
-        # check_vma=False: the checker can't infer that the tiled
-        # all_gather output is replicated
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
 
     # -- the hot call ----------------------------------------------------
     def _prep(self, x, y):
@@ -1062,22 +979,44 @@ class TrainStep:
         y_raw = y.data if isinstance(y, NDArray) else wrap(y)
         self._collect(x if isinstance(x, NDArray)
                       else NDArray(x_raw, None, _placed=True))
-        if self.zero and x_raw.shape[self.batch_axis] % self._zero_dp:
-            raise MXNetError(
-                f"ZeRO-1 shards the batch over dp={self._zero_dp}; "
-                f"batch dim {x_raw.shape[self.batch_axis]} is not "
-                f"divisible (pad the batch, or pass zero=0)")
-        if self.mesh is not None:
-            spec = [None] * x_raw.ndim
-            spec[self.batch_axis] = self.dp_axis
-            x_raw = _device_put_global(x_raw, self.mesh, P(*spec))
-            yspec = [None] * max(y_raw.ndim, 1)
-            yspec[self.batch_axis] = self.dp_axis
-            y_raw = _device_put_global(y_raw, self.mesh,
-                                       P(*yspec[:y_raw.ndim]))
+        x_raw, y_raw = self._place_batch(x_raw, y_raw, self.batch_axis,
+                                         "batch")
         sig = (x_raw.shape, str(x_raw.dtype), y_raw.shape,
                str(y_raw.dtype))
         return x_raw, y_raw, sig
+
+    def _place_batch(self, x_raw, y_raw, axis, what):
+        """One call's batch on the mesh, sharded over ``dp_axis`` on
+        ``axis`` — after the check ZeRO-1's ``shard_map`` needs."""
+        if self.zero and x_raw.shape[axis] % self._zero_dp:
+            raise MXNetError(
+                f"ZeRO-1 shards the batch over dp={self._zero_dp}; "
+                f"{what} dim {x_raw.shape[axis]} is not divisible "
+                f"(pad the batch, or pass zero=0)")
+        if self.mesh is None:
+            return x_raw, y_raw
+        return tuple(_device_put_global(
+            v, self.mesh, _batch_spec(v.ndim, axis, self.dp_axis))
+            for v in (x_raw, y_raw))
+
+    def _vals(self, frozen_idx):
+        """The parameters' current buffers as the step takes them:
+        ``(train_vals, frozen_vals)``."""
+        params = self._params
+        return (tuple(params[i]._data._data for i in self._train_idx),
+                tuple(params[i]._data._data for i in frozen_idx))
+
+    @property
+    def _aot(self) -> bool:
+        """Whether programs are compiled ahead of their first call.
+        Multi-process meshes keep the jit wrapper — its dispatch
+        handles cross-host arrays.  So does tensor-parallel
+        (param_spec_fn): GSPMD may return updated params with a
+        compiler-chosen sharding that differs from the placement the
+        program was lowered with, and AOT executables reject input
+        shardings that drift between steps."""
+        return self.param_spec_fn is None and (
+            self.mesh is None or not _mesh_is_multiprocess(self.mesh))
 
     def _train_cache_key(self, lowered, x_raw, y_raw):
         """Persistent-cache key of the AOT one-step program (ISSUE
@@ -1142,10 +1081,8 @@ class TrainStep:
                 lrs, wds, kd = self._commit_small(
                     lrs, wds, jax.random.key_data(key))
                 params = self._params
-                train_vals = tuple(params[i]._data._data
-                                   for i in self._train_idx)
-                frozen_vals = tuple(params[i]._data._data
-                                    for i in entry["frozen_idx"])
+                train_vals, frozen_vals = self._vals(
+                    entry["frozen_idx"])
             if self._guards:
                 self._churn.note_call()
             t0 = _prof._now_us() if self._obs else 0.0
@@ -1239,42 +1176,21 @@ class TrainStep:
             ys = y_raw.reshape((steps, B) + y_raw.shape[1:]) \
                 if y_raw.ndim else y_raw
         self._collect(NDArray(x_raw[:B], None, _placed=True))
-        if self.zero and B % self._zero_dp:
-            raise MXNetError(
-                f"ZeRO-1 shards the batch over dp={self._zero_dp}; "
-                f"microbatch dim {B} is not divisible (pad the batch, "
-                f"or pass zero=0)")
         batch_dim = 0 if reuse_batch else 1
-        if self.mesh is not None:
-            spec = [None] * xs.ndim
-            spec[batch_dim] = self.dp_axis
-            xs = _device_put_global(xs, self.mesh, P(*spec))
-            yspec = [None] * max(ys.ndim, 1)
-            if ys.ndim > batch_dim:
-                yspec[batch_dim] = self.dp_axis
-            ys = _device_put_global(ys, self.mesh, P(*yspec[:ys.ndim]))
+        xs, ys = self._place_batch(xs, ys, batch_dim, "microbatch")
         key = _rnd._next_key(None)
         one_shape = xs.shape[batch_dim:] if not reuse_batch else xs.shape
         y_one = ys.shape[batch_dim:] if not reuse_batch else ys.shape
         sig = (one_shape, str(xs.dtype), y_one, str(ys.dtype))
         entry = self._compiled.get(sig)
         if entry is None:
-            if self._guards:
-                self._churn.note_compile(sig)
-            if self._obs:
-                self._m_compile.inc()
-            xb0 = xs if reuse_batch else xs[0]
-            yb0 = ys if reuse_batch else (ys[0] if ys.ndim else ys)
-            entry = self._build(key, xb0, yb0)
-            self._compiled[sig] = entry
+            entry = self._entry_for(
+                xs if reuse_batch else xs[0],
+                ys if reuse_batch or not ys.ndim else ys[0], sig, key)
         msig = ("multi", steps, reuse_batch) + sig
         self._t += steps
         lrs, wds = self._lrs_wds()
-        params = self._params
-        train_vals = tuple(params[i]._data._data
-                           for i in self._train_idx)
-        frozen_vals = tuple(params[i]._data._data
-                            for i in entry["frozen_idx"])
+        train_vals, frozen_vals = self._vals(entry["frozen_idx"])
         keys = jax.vmap(jax.random.key_data)(
             jax.random.split(key, steps))
         lrs, wds, keys = self._commit_small(lrs, wds, keys)
@@ -1286,47 +1202,33 @@ class TrainStep:
                 self._m_compile.inc()
             raw_step = entry["raw_step"]
             aux_pos = entry["aux_pos"]
-            amp_scaler = self._amp_scaler
 
             def multi_fn(train_vals, frozen_vals, opt_state, key_data,
-                         lrs, wds, xs, ys, *amp_s):
+                         lrs, wds, xs, ys, *extra):
                 def body(carry, inp):
-                    if amp_scaler:
-                        tv, frozen, st, sc = carry
-                    else:
-                        tv, frozen, st = carry
+                    tv, frozen, st, extra = carry
                     if reuse_batch:
                         (kd,) = inp
                         xb, yb = xs, ys
                     else:
                         xb, yb, kd = inp
-                    if amp_scaler:
-                        loss, tv2, st2, raw_aux, sc2 = raw_step(
-                            tv, frozen, st, kd, lrs, wds, xb, yb, sc)
-                    else:
-                        loss, tv2, st2, raw_aux = raw_step(
-                            tv, frozen, st, kd, lrs, wds, xb, yb)
+                    loss, tv2, st2, raw_aux, *extra2 = raw_step(
+                        tv, frozen, st, kd, lrs, wds, xb, yb, *extra)
                     frozen2 = list(frozen)
                     for pos, v in zip(aux_pos, raw_aux):
                         if pos is not None:
                             frozen2[pos] = v
-                    carry2 = (tv2, tuple(frozen2), st2)
-                    if amp_scaler:
-                        carry2 = carry2 + (sc2,)
-                    return carry2, loss
+                    return (tv2, tuple(frozen2), st2, tuple(extra2)), loss
                 scanned = (key_data,) if reuse_batch else \
                     (xs, ys, key_data)
-                carry0 = (train_vals, frozen_vals, opt_state)
-                if amp_scaler:
-                    carry0 = carry0 + (amp_s[0],)
-                carry, losses = lax.scan(body, carry0, scanned)
-                return (losses,) + carry
+                (tv, frozen, st, extra), losses = lax.scan(
+                    body, (train_vals, frozen_vals, opt_state, extra),
+                    scanned)
+                return (losses, tv, frozen, st) + extra
 
             donate = (0, 1, 2) if self.donate else ()
             multi = jax.jit(multi_fn, donate_argnums=donate)
-            if (self.param_spec_fn is None and
-                    (self.mesh is None
-                     or not _mesh_is_multiprocess(self.mesh))):
+            if self._aot:
                 # AOT (as in _build): the scanned program's memory
                 # stats are what bench.py's hbm_peak reports
                 with self._region(obs.SPAN_COMPILE,
@@ -1370,22 +1272,22 @@ class TrainStep:
         (building it if needed).  On the AOT path this is the very
         executable the step runs; the multi-process jit path lowers a
         twin for inspection."""
-        x_raw, y_raw, sig = self._prep(x, y)
-        key = _rnd._next_key(None)
-        entry = self._entry_for(x_raw, y_raw, sig, key)
+        entry, args = self._step_args(x, y)
         fn = entry["fn"]
         if not hasattr(fn, "lower"):  # AOT: already a Compiled
             return fn
+        return fn.lower(*args).compile()
+
+    def _step_args(self, x, y):
+        """The one-step entry for this (x, y) signature (built if
+        needed) and the arguments a call would hand its program."""
+        x_raw, y_raw, sig = self._prep(x, y)
+        key = _rnd._next_key(None)
+        entry = self._entry_for(x_raw, y_raw, sig, key)
         lrs, wds = self._lrs_wds()
-        params = self._params
-        train_vals = tuple(params[i]._data._data
-                           for i in self._train_idx)
-        frozen_vals = tuple(params[i]._data._data
-                            for i in entry["frozen_idx"])
-        return fn.lower(
-            train_vals, frozen_vals, self._opt_state,
-            jax.random.key_data(key), lrs, wds, x_raw,
-            y_raw, *self._amp_extra()).compile()
+        return entry, self._vals(entry["frozen_idx"]) + (
+            self._opt_state, jax.random.key_data(key), lrs, wds,
+            x_raw, y_raw) + self._amp_extra()
 
     def memory_analysis(self, x, y):
         """Per-device memory footprint of the one-step compiled
@@ -1428,19 +1330,8 @@ class TrainStep:
         code put it, before backend float normalization rewrites
         sub-f32 math."""
         from mxtpu import analysis
-        x_raw, y_raw, sig = self._prep(x, y)
-        key = _rnd._next_key(None)
-        entry = self._entry_for(x_raw, y_raw, sig, key)
-        lrs, wds = self._lrs_wds()
-        params = self._params
-        train_vals = tuple(params[i]._data._data
-                           for i in self._train_idx)
-        frozen_vals = tuple(params[i]._data._data
-                            for i in entry["frozen_idx"])
-        return analysis.lowered_text(
-            entry["raw_step"], train_vals, frozen_vals,
-            self._opt_state, jax.random.key_data(key), lrs, wds,
-            x_raw, y_raw, *self._amp_extra())
+        entry, args = self._step_args(x, y)
+        return analysis.lowered_text(entry["raw_step"], *args)
 
     def param_sigs(self, x=None, y=None):
         """``(name, shape, dtype)`` per trainable parameter, in step
@@ -1673,8 +1564,7 @@ def build_train_step(net, loss_fn, optimizer="sgd", optimizer_params=None,
     tensor-parallel sharding.  On single-process dp meshes the step
     defaults to ZeRO-1 sharded optimizer states (reduce-scatter +
     all-gather instead of all-reduce; see :class:`TrainStep`) —
-    ``zero=0`` or ``MXTPU_ZERO=0`` restores the replicated path,
-    ``zero=1`` insists.
+    ``zero=0`` restores the replicated path, ``zero=1`` insists.
 
     ``amp=1`` turns on policy-driven mixed precision (``mxtpu.amp``):
     bf16 parameter storage over f32 master weights, bf16 casts on the

@@ -112,24 +112,50 @@ def test_masters_f32_params_bf16():
     assert stats["skipped_steps"] == 0 and stats["loss_scale"] >= 1.0
 
 
-def test_nonfinite_batch_skips_update(monkeypatch):
+@pytest.mark.parametrize("entry", ["call", "run_steps"])
+@pytest.mark.parametrize("zero", [False, True],
+                         ids=["unsharded", "zero"])
+def test_nonfinite_batch_skips_update(zero, entry, monkeypatch):
+    """A non-finite batch costs the step, never the weights: params
+    and state are kept and the scale backs off — on every shard under
+    ZeRO-1, where ONE shard's row is bad and all must agree to skip."""
     monkeypatch.setenv("MXTPU_AMP_LOSS_SCALE", "1024")
     rng = np.random.RandomState(1)
-    x = nd.array(rng.randn(4, 8).astype(np.float32))
-    y = nd.array(rng.randn(4, 4).astype(np.float32))
+    x = nd.array(rng.randn(8, 8).astype(np.float32))
+    y = nd.array(rng.randn(8, 4).astype(np.float32))
     net = _dense_net(x)
+    where = {"mesh": _mesh(), "zero": 1} if zero else {}
     step = parallel.build_train_step(net, _mse, "sgd",
-                                     {"learning_rate": 0.1}, amp=True)
-    step(x, y)
+                                     {"learning_rate": 0.1}, amp=True,
+                                     **where)
+    assert step.zero is zero
+
+    def go(xb, yb, k):
+        if entry == "call":
+            for _ in range(k):
+                step(xb, yb)
+        else:
+            step.run_steps(xb, yb, steps=k, reuse_batch=True)
+
+    go(x, y, 1)
     before = snapshot_params(net)
-    bad_y = nd.array(np.full((4, 4), np.inf, np.float32))
-    step(x, bad_y)
-    after = snapshot_params(net)
-    for b, a in zip(before, after):
+    state = jax.tree_util.tree_map(np.asarray, step._opt_state)
+    bad = y.asnumpy().copy()
+    bad[3] = np.inf          # one row: one shard's under dp8
+    go(x, nd.array(bad), 2)
+    for b, a in zip(before, snapshot_params(net)):
         np.testing.assert_array_equal(b, a)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, state,
+                           jax.tree_util.tree_map(np.asarray,
+                                                  step._opt_state))
     stats = step.amp_stats()
-    assert stats["skipped_steps"] == 1
-    assert stats["loss_scale"] == 512.0  # halved on the bad step
+    assert stats["skipped_steps"] == 2
+    assert stats["loss_scale"] == 256.0  # halved on each bad step
+    # and the step still trains afterwards
+    go(x, y, 1)
+    assert step.amp_stats()["skipped_steps"] == 2
+    assert any((b != a).any()
+               for b, a in zip(before, snapshot_params(net)))
 
 
 # ----------------------------------------------------------------------
@@ -277,7 +303,8 @@ def test_zero_reduce_scatter_rides_bf16():
     assert all("bf16[" not in ln for ln in f32_rs)
 
 
-def test_zero_amp_parity():
+@pytest.mark.parametrize("entry", ["call", "run_steps"])
+def test_zero_amp_parity(entry):
     rng = np.random.RandomState(0)
     x = nd.array(rng.randn(8, 8).astype(np.float32))
     y = nd.array(rng.randn(8, 4).astype(np.float32))
@@ -288,6 +315,9 @@ def test_zero_amp_parity():
         step = parallel.build_train_step(
             net, _mse, "adam", {"learning_rate": 1e-3},
             mesh=_mesh(), zero=1, amp=amp_flag)
+        if entry == "run_steps":
+            return step.run_steps(x, y, steps=3,
+                                  reuse_batch=True).asnumpy()
         return [float(step(x, y).asscalar()) for _ in range(3)]
 
     snap = snapshot_params(_dense_net(x))

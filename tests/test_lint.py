@@ -256,7 +256,7 @@ def test_knob_raw_env_exempts_knobs_py():
 def test_knob_unregistered():
     ctx = _ctx("""
         from mxtpu import knobs
-        a = knobs.get("MXTPU_ZERO")            # registered
+        a = knobs.get("MXTPU_GUARDS")          # registered
         b = knobs.get("MXTPU_NOT_A_KNOB")      # not
     """)
     found = R.KnobUnregistered().check(ctx)
@@ -270,7 +270,7 @@ def test_knobs_module_standalone_load_and_types():
     assert "MXTPU_GUARDS" in reg and "MXTPU_BENCH_MODEL" in reg
     # typed defaults straight from the registry
     assert mod.get("MXTPU_SERVING_MAX_BATCH") == 32
-    assert mod.get("MXTPU_BATCHED_OPT") is True
+    assert mod.get("MXTPU_FUSED_LN_EPILOGUE") is True
     with pytest.raises(Exception, match="unregistered"):
         mod.get("MXTPU_NOT_A_KNOB")
 

@@ -197,11 +197,10 @@ def _mesh(n=8):
     return jax.sharding.Mesh(np.array(devs[:n]), ("dp",))
 
 
-def test_seeded_zero_replication(monkeypatch):
+def test_seeded_zero_replication():
     """zero=0 forced under a record declared to shard: measured
     opt-state bytes exceed the plan_zero_buckets geometry and
     EXACTLY zero-replication fires, naming the opt-state buffer."""
-    monkeypatch.setenv("MXTPU_ZERO", "0")
     rng = np.random.RandomState(0)
     x = nd.array(rng.randn(8, 16).astype(np.float32))
     y = nd.array(rng.randn(8, 4).astype(np.float32))
@@ -211,7 +210,7 @@ def test_seeded_zero_replication(monkeypatch):
     net(x)
     step = parallel.build_train_step(
         net, lambda p, t: ((p - t) ** 2).mean(), "adam",
-        {"learning_rate": 1e-3}, mesh=_mesh())
+        {"learning_rate": 1e-3}, mesh=_mesh(), zero=0)
     assert not step.zero
     step(x, y)
     record = memflow.train_step_record(step, x, y, "zero_pert",
