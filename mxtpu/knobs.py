@@ -109,14 +109,6 @@ def registered() -> Dict[str, Knob]:
 
 # -- performance kill switches (each =0 restores the pre-optimization
 #    behaviour exactly; README "Performance kill switches & knobs") ----
-register("MXTPU_BATCHED_OPT", True, "bool",
-         "(shape, dtype)-bucketed stacked optimizer updates; `0` "
-         "makes every bucket one parameter: one update chain per "
-         "parameter (ignored under ZeRO-1, whose exchange is inherently "
-         "bucketed). The train step's partition reads it, nothing else; "
-         "on the chip `0` trains BERT-Large 31 % faster (PERF.md §6, "
-         "PR 30) and ROADMAP S2 flips the default and deletes the knob.",
-         "kill-switch")
 register("MXTPU_FUSED_LN_EPILOGUE", True, "bool",
          "Fused bias+dropout+add+LayerNorm Pallas epilogue; `0` "
          "reverts to the unfused lax composite.", "kill-switch")

@@ -41,7 +41,6 @@ from .. import amp as _amp_mod
 from ..base import MXNetError
 from .. import cache as cache_mod
 from .. import guards
-from .. import knobs
 from .. import obs
 from .. import profiler as _prof
 from .. import optimizer as opt_mod
@@ -188,11 +187,11 @@ def plan_zero_buckets(sigs, dp: int, stack_axis_only: bool = False):
     optimizer-memory table and the bench accounting).
 
     ``sigs`` is a list of ``(shape, dtype_str)`` per trainable
-    parameter, in step order.  Parameters bucket by (shape, dtype) —
-    the same buckets MXTPU_BATCHED_OPT stacks — and each bucket picks
-    ONE axis of its stacked ``(n,) + shape`` array to shard over
-    ``dp``: the axis minimizing relative zero-padding (ties prefer the
-    stack axis, whose lr/wd bookkeeping is simplest).  Singleton
+    parameter, in step order.  Parameters bucket by (shape, dtype)
+    and each bucket picks ONE axis of its stacked ``(n,) + shape``
+    array to shard over ``dp``: the axis minimizing relative
+    zero-padding (ties prefer the stack axis, whose lr/wd bookkeeping
+    is simplest).  Singleton
     buckets (n=1, e.g. an embedding table) would waste (dp-1)/dp of a
     full row if only the stack axis were allowed — axis choice is what
     makes the ≤ replicated/dp × 1.15 footprint hold.  LAMB buckets are
@@ -385,8 +384,9 @@ class TrainStep:
       accumulates per-shard batch statistics (averaged into the
       running stats — the reference's non-sync DDP behaviour) and
       dropout draws an independent stream per shard;
-    * optimizer updates always run bucket-stacked (the ZeRO exchange
-      is per bucket), regardless of ``MXTPU_BATCHED_OPT``.
+    * optimizer updates run bucket-stacked (the ZeRO exchange is per
+      bucket and the state lives stacked); an unsharded step updates
+      one parameter at a time and stacks nothing.
 
     ``save_states`` always writes the canonical per-parameter layout
     (gather-on-save), so checkpoints are interchangeable between ZeRO
@@ -658,43 +658,31 @@ class TrainStep:
         ``axis``, ``pad`` and whether it updates ``stacked`` — and
         ``take``/``put``, which fetch a bucket's weights, gradients and
         optimizer state and put the updated ones back.  These two are
-        the ONE place where the two state layouts meet: per-parameter
-        tuples, stacked and cut apart every step (unsharded; a group of
-        one stays unstacked), or the bucket's resident dp-sharded stack
-        (ZeRO-1, ``_init_zero_state``)."""
-        zero = self.zero
-        if zero:
+        the ONE place where the two state layouts meet, one case each:
+        per-parameter tuples, handed through untouched (unsharded:
+        every parameter is its own bucket, always), or the bucket's
+        resident dp-sharded stack (ZeRO-1, ``_init_zero_state``).
+        ``self.zero`` decides, nothing else."""
+        if self.zero:
             buckets = [dict(b, stacked=True) for b in self._zero_buckets]
         else:
-            # (shape, dtype) groups, each updated as ONE stacked op
-            # instead of one HLO chain per parameter: a BERT-Large step
-            # has ~25 bucket updates for ~400 parameters.  All rules
-            # are elementwise in (w, g, state) with lr/wd entering as
-            # broadcast (n,1,..,1) scalars (LAMB reduces its
-            # trust-ratio norms per slice), so a stack updates as its
-            # rows would alone.  MXTPU_BATCHED_OPT=0 makes every group
-            # one parameter: the per-parameter loop.
-            batched = knobs.get("MXTPU_BATCHED_OPT")
-            by_sig: Dict[Any, List[int]] = {}
-            for j, i in enumerate(self._train_idx):
-                v = self._params[i]._data._data
-                by_sig.setdefault((v.shape, str(v.dtype)) if batched
-                                  else j, []).append(j)
-            buckets = [{"jidx": js, "axis": None, "pad": 0,
-                        "stacked": len(js) > 1}
-                       for js in by_sig.values()]
+            # One parameter a bucket: parameters and state live
+            # unstacked, so a (shape, dtype) stack would be built from
+            # them and cut apart again every step — two more passes over
+            # every weight, gradient and moment, a third of the
+            # BERT-Large step on the chip (PERF.md §6, PR 31).  XLA
+            # fuses each parameter's update chain by itself; only
+            # ZeRO-1, whose state is resident stacked, stacks.
+            buckets = [{"jidx": [j], "axis": None, "pad": 0,
+                        "stacked": False}
+                       for j in range(len(self._train_idx))]
 
         def take(k, b, train_vals, grads, opt_state):
             js = b["jidx"]
             if not b["stacked"]:
                 return train_vals[js[0]], grads[js[0]], opt_state[js[0]]
-            w = jnp.stack([train_vals[j] for j in js])
-            g = jnp.stack([grads[j] for j in js])
-            if zero:
-                return w, g, opt_state[k]
-            return w, g, tuple(
-                jnp.stack([opt_state[j][n] for j in js])
-                for n in range(len(opt_state[js[0]])))
+            return (jnp.stack([train_vals[j] for j in js]),
+                    jnp.stack([grads[j] for j in js]), opt_state[k])
 
         def put(k, b, w2, st2, new_vals, new_state):
             js = b["jidx"]
@@ -703,10 +691,7 @@ class TrainStep:
                 return
             for a, j in enumerate(js):
                 new_vals[j] = w2[a]
-                if not zero:
-                    new_state[j] = tuple(leaf[a] for leaf in st2)
-            if zero:
-                new_state[k] = st2
+            new_state[k] = st2
         return buckets, take, put
 
     def _exchange(self):
@@ -912,9 +897,12 @@ class TrainStep:
             # different flags, distinct lambdas) can never share a
             # key.  A verified disk hit skips only the XLA compile.
             t0 = _prof._now_us()
+            # groups / stacked_groups: what the partition did
             with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
-                              kind="train",
-                              bucket=str(x_raw.shape)) as rg:
+                              kind="train", bucket=str(x_raw.shape),
+                              groups=len(buckets),
+                              stacked_groups=sum(
+                                  b["stacked"] for b in buckets)) as rg:
                 lowered = fitted.lower(*step_args)
                 source, ckey, loaded, cmeta = "cold", None, None, {}
                 if self._cache is not None:

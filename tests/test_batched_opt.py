@@ -1,17 +1,17 @@
-"""Batched (shape/dtype-bucketed, stacked) optimizer update in the
-compiled train step (ISSUE 2 tentpole 2) against a plain reference:
-the same loss differentiated by ``jax.grad`` and the optimizer's rule
-applied leaf by leaf, in a loop written here.  Every rule is
-elementwise in (w, g, state), so a stack updates as its rows would
-alone, up to the compiler's choice of fusion (an ULP); LAMB's per-slice
-trust-ratio norms may differ by reduction order as well.
-Also covers the new LAMB optimizer end to end."""
+"""The compiled train step's optimizer update against a plain
+reference: the same loss differentiated by ``jax.grad`` and the
+optimizer's rule applied leaf by leaf, in a loop written here.  An
+unsharded step updates one parameter a bucket and stacks nothing (the
+(shape, dtype)-stacked "batched" update of ISSUE 2 went with its knob
+in PR 31; the file keeps its name); only ZeRO-1, whose state lives
+stacked, still buckets — the partition tests at the end hold both to
+that.  Also covers the LAMB optimizer end to end."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mxtpu import autograd, gluon, nd, optimizer, parallel
+from mxtpu import autograd, gluon, nd, obs, optimizer, parallel, profiler
 from mxtpu.gluon import nn
 from mxtpu.optimizer.functional import adam_bias_correction, opt_rule
 from mxtpu.parallel import snapshot_params, restore_params
@@ -19,8 +19,9 @@ from mxtpu.parallel import snapshot_params, restore_params
 
 def _make_net(x):
     net = nn.HybridSequential()
-    # three Dense(16) → a 3-param bucket for weights and one for
-    # biases, plus singleton buckets from the in/out layers
+    # three Dense(16): repeated (shape, dtype) signatures — what a
+    # stacking partition would group (ZeRO-1: a 3-row bucket of
+    # weights, one of biases, singletons from the out layer)
     net.add(nn.Dense(16, flatten=False), nn.Dense(16, flatten=False),
             nn.Dense(16, flatten=False), nn.Dense(4, flatten=False))
     net.initialize(init="xavier")
@@ -29,7 +30,7 @@ def _make_net(x):
 
 
 def _run(optname, oparams, x, y, snap, steps=5, compute_dtype=None):
-    """The compiled step: its (shape, dtype) groups updated stacked."""
+    """The compiled step, unsharded: one parameter an update."""
     net = _make_net(x)
     restore_params(net, snap)
     step = parallel.build_train_step(
@@ -89,18 +90,11 @@ def _data():
     ("sgd", {"learning_rate": 0.05}),
     ("adam", {"learning_rate": 1e-3, "wd": 1e-4}),
 ])
-@pytest.mark.parametrize("grouped", ["1", "0"],
-                         ids=["grouped", "per-param"])
-def test_batched_bit_identical_elementwise_rules(optname, oparams,
-                                                 grouped, _data,
-                                                 monkeypatch):
-    """Elementwise rules: a stacked bucket is its rows' updates, bit
-    for bit in the arithmetic — what is left between two compiled
-    programs is the compiler's fusion order, an ULP.  Both settings of
-    the partition's one switch (MXTPU_BATCHED_OPT: (shape, dtype)
-    groups, or every group one parameter) are held to the reference,
-    not to each other."""
-    monkeypatch.setenv("MXTPU_BATCHED_OPT", grouped)
+def test_step_matches_per_leaf_elementwise_rules(optname, oparams,
+                                                 _data):
+    """Elementwise rules: the compiled step is the per-leaf loop's
+    arithmetic — what is left between two compiled programs is the
+    compiler's fusion order, an ULP."""
     x, y, snap = _data
     la, pa = _run(optname, oparams, x, y, snap)
     lb, pb = _reference(optname, oparams, x, y, snap)
@@ -109,13 +103,13 @@ def test_batched_bit_identical_elementwise_rules(optname, oparams,
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def test_batched_lamb_matches_per_param(_data):
+def test_lamb_step_matches_per_leaf(_data):
     x, y, snap = _data
     oparams = {"learning_rate": 1e-2, "wd": 1e-2}
     la, pa = _run("lamb", oparams, x, y, snap)
     lb, pb = _reference("lamb", oparams, x, y, snap)
-    # trust-ratio norms reduce in a different order when stacked:
-    # per-dtype tolerance, not bitwise
+    # trust-ratio norms reduce in the compiler's order: per-dtype
+    # tolerance, not bitwise
     np.testing.assert_allclose(la, lb, rtol=1e-5, atol=1e-7)
     for a, b in zip(pa, pb):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
@@ -125,10 +119,10 @@ def test_batched_lamb_matches_per_param(_data):
     ("adam", {"learning_rate": 1e-3, "wd": 1e-4}),
     ("lamb", {"learning_rate": 1e-2, "wd": 1e-2}),
 ])
-def test_batched_multi_precision_bf16(optname, oparams, _data):
+def test_multi_precision_bf16_matches_per_leaf(optname, oparams, _data):
     """compute_dtype='bfloat16' (the multi_precision recipe: bf16
-    fwd/bwd, f32 master weights + optimizer state) batched vs
-    per-param."""
+    fwd/bwd, f32 master weights + optimizer state) against the
+    per-leaf loop."""
     x, y, snap = _data
     la, pa = _run(optname, oparams, x, y, snap,
                   compute_dtype="bfloat16")
@@ -140,8 +134,8 @@ def test_batched_multi_precision_bf16(optname, oparams, _data):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-def test_batched_run_steps_scan_path(_data):
-    """The scanned multi-step path threads the bucketed update through
+def test_run_steps_scan_path(_data):
+    """The scanned multi-step path threads the update through
     lax.scan and still converges."""
     x, y, snap = _data
     net = _make_net(x)
@@ -155,7 +149,7 @@ def test_batched_run_steps_scan_path(_data):
     assert ls[-1] < ls[0], ls
 
 
-def test_batched_save_load_states_roundtrip(tmp_path, _data):
+def test_save_load_states_roundtrip(tmp_path, _data):
     x, y, snap = _data
     net = _make_net(x)
     restore_params(net, snap)
@@ -205,3 +199,95 @@ def test_lamb_trust_ratio_scale_invariance():
     np.testing.assert_allclose(outs[0], outs[1], rtol=5e-3, atol=1e-3)
     # and the update actually moved the weights
     assert np.abs(outs[0] - w).max() > 1e-3
+
+
+# ------------------------------------------------------- the partition
+def _mse(p, t):
+    return ((p - t) ** 2).mean()
+
+
+def _step_of(x, y, snap, **kw):
+    net = _make_net(x)
+    restore_params(net, snap)
+    return parallel.build_train_step(net, _mse, "adam",
+                                     {"learning_rate": 1e-3}, **kw)
+
+
+def _built(x, y, snap, **kw):
+    """A step over ``_make_net`` built (by its first call) with the
+    chrome-trace profiler on: ``(step, args of its compile region)``."""
+    step = _step_of(x, y, snap, **kw)
+    profiler.set_state("run")
+    try:
+        step(x, y)
+        (built,) = [e for e in profiler.events()
+                    if e["name"] == obs.SPAN_COMPILE]
+    finally:
+        profiler.set_state("stop")
+        profiler.dumps(reset=True)
+    return step, built["args"]
+
+
+def _dp8():
+    return jax.sharding.Mesh(np.array(jax.devices()[:8]), ("dp",))
+
+
+@pytest.mark.parametrize("mesh", [None, "dp8"],
+                         ids=["one-device", "replicated-dp8"])
+def test_unsharded_partition_is_one_parameter_a_bucket(mesh, _data):
+    """Without ZeRO — one device, or a replicated dp mesh — every
+    trainable parameter is its own unstacked bucket although the net
+    repeats shapes, and the ``compile`` region says so."""
+    x, y, snap = _data
+    step, args = _built(x, y, snap,
+                        **({"mesh": _dp8(), "zero": 0} if mesh else {}))
+    buckets, _, _ = step._partition()
+    n = len(step._train_idx)
+    assert n == 8 and len({s for _, s, _ in step.param_sigs()}) < n
+    assert [b["jidx"] for b in buckets] == [[j] for j in range(n)]
+    assert not any(b["stacked"] for b in buckets)
+    assert (args["groups"], args["stacked_groups"]) == (n, 0)
+
+
+def test_zero1_partition_keeps_its_stacked_buckets(_data):
+    x, y, snap = _data
+    step, args = _built(x, y, snap, mesh=_dp8(), zero=1)
+    buckets, _, _ = step._partition()
+    assert all(b["stacked"] for b in buckets)
+    assert max(len(b["jidx"]) for b in buckets) == 3
+    assert args["groups"] == args["stacked_groups"] \
+        == len(step._zero_buckets) < len(step._train_idx)
+
+
+def _packing_ops(text):
+    """``concatenate`` instructions under the optimizer's scope: what
+    ``jnp.stack`` of a bucket's rows lowers to."""
+    return [ln for ln in text.splitlines()
+            if " concatenate(" in ln and "train/optimizer" in ln]
+
+
+def test_only_the_zero_step_packs_in_its_program(_data):
+    """The program-level witness: an unsharded step's lowered text
+    stacks nothing under ``train/optimizer``; ZeRO-1's still stacks
+    the weights and the gradients of each bucket of several rows (a
+    stack of one is a reshape)."""
+    x, y, snap = _data
+    plain = _step_of(x, y, snap)
+    assert _packing_ops(plain.lowered_hlo_text(x, y)) == []
+    zero = _step_of(x, y, snap, mesh=_dp8(), zero=1)
+    assert len(_packing_ops(zero.lowered_hlo_text(x, y))) == 2 * sum(
+        len(b["jidx"]) > 1 for b in zero._zero_buckets) == 4
+
+
+def test_deleted_knob_changes_no_byte_of_the_program(_data, monkeypatch):
+    """MXTPU_BATCHED_OPT is gone, not hidden: set either way in the
+    environment, the step lowers to the same text."""
+    x, y, snap = _data
+    texts = []
+    for value in (None, "0", "1"):
+        if value is None:
+            monkeypatch.delenv("MXTPU_BATCHED_OPT", raising=False)
+        else:
+            monkeypatch.setenv("MXTPU_BATCHED_OPT", value)
+        texts.append(_step_of(x, y, snap).lowered_hlo_text(x, y))
+    assert texts[0] == texts[1] == texts[2]

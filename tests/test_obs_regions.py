@@ -406,6 +406,9 @@ def test_train_regions_land_in_the_xplane(tmp_path):
     assert [(e[3]["kind"], s.parent_of(e)) for e in built] == \
         [("train", "train/prep"), ("train_scan", "train/prep")]
     assert s.named("compile/done")[0][3]["source"] == "cold"
+    # what the partition did: one unstacked bucket a parameter
+    assert int(built[0][3]["groups"]) == len(step._train_idx)
+    assert int(built[0][3]["stacked_groups"]) == 0
     for a, b, c in zip(s.named("train/prep"), s.named("train/dispatch"),
                        s.named("train/writeback")):
         assert a[2] <= b[1] and b[2] <= c[1]

@@ -15,22 +15,22 @@ r6 additions, covering the hot-path work this profile motivated:
 - ``loop_floor``       — the chained loop on an identity-cost body:
   dispatch + loop overhead that no model change can remove; subtract
   from every other row before computing component shares.
-- ``step_batched`` /
-  ``step_perparam``    — the FULL TrainStep (fwd+bwd+optimizer) via
-  build_train_step with MXTPU_BATCHED_OPT=1/0; their difference is
-  the shape/dtype-bucketed optimizer saving, and step_batched minus
-  ``full`` is the whole optimizer+writeback share.
+- ``step``             — the FULL TrainStep (fwd+bwd+optimizer) via
+  build_train_step, as the benchmark's train cell runs it (one
+  parameter an update, nothing stacked).  ``step`` minus ``full`` is
+  the whole optimizer+writeback share.
 - ``step_zero``        — the FULL TrainStep on a dp mesh over every
   local device (dp = min(8, devices)) with ZeRO-1 sharded optimizer
-  states; vs step_batched this prices the reduce-scatter/all-gather
-  exchange against the dp× opt-state HBM saving.  Skipped on a
-  single-device host.
+  states; vs ``step`` this prices the reduce-scatter/all-gather
+  exchange (and ZeRO's stacked buckets) against the dp× opt-state HBM
+  saving.  Skipped on a single-device host.
 - ``--cost``           — also print TrainStep.cost_analysis() FLOPs /
   bytes for the step program (on TPU the Pallas custom calls hide
   their FLOPs; the CPU lowering counts everything — see
   bench.py _TRAIN_FLOPS provenance notes).
 
 Usage: python tools/profile_bert.py [batch] [seqlen] [only,csv] [--cost]
+(``--help`` prints this text and measures nothing.)
 (MXTPU_PROFILE_BERT_MODEL=tiny|base|large swaps the model so the
 harness itself can be smoke-tested on a CPU box.)
 """
@@ -198,7 +198,7 @@ class _env:
                 os.environ[k] = v
 
 
-def measure_train_step(batch, seqlen, batched, zero=None):
+def measure_train_step(batch, seqlen, zero=None):
     """Full compiled TrainStep (fwd+bwd+optimizer+writeback) per-step
     ms — the number bench.py's BERT row is made of.  ``zero=1`` runs
     it on a dp mesh over every local device with ZeRO-1 sharded
@@ -210,30 +210,29 @@ def measure_train_step(batch, seqlen, batched, zero=None):
     if zero:
         dp = min(8, jax.device_count())
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:dp]), ("dp",))
-    with _env(MXTPU_BATCHED_OPT="1" if batched else "0"):
-        net = _build_bert(seqlen)
-        net.initialize(init="xavier")
+    net = _build_bert(seqlen)
+    net.initialize(init="xavier")
 
-        def mlm_loss(pred, y):
-            return gloss.SoftmaxCrossEntropyLoss()(
-                pred.reshape((-1, pred.shape[-1])), y.reshape((-1,)))
+    def mlm_loss(pred, y):
+        return gloss.SoftmaxCrossEntropyLoss()(
+            pred.reshape((-1, pred.shape[-1])), y.reshape((-1,)))
 
-        step = parallel.build_train_step(
-            net, mlm_loss, "adam", {"learning_rate": 1e-4},
-            compute_dtype="bfloat16", cast_batch=False,
-            mesh=mesh, zero=zero)
-        rng = np.random.RandomState(0)
-        toks = nd.array(rng.randint(0, 30522, (batch, seqlen))
-                        .astype(np.float32))
-        last = step.run_steps(toks, toks, 2, reuse_batch=True)
-        float(last.asnumpy()[-1])  # compile + drain
-        n, best = 8, float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            last = step.run_steps(toks, toks, n, reuse_batch=True)
-            float(last.asnumpy()[-1])
-            best = min(best, (time.perf_counter() - t0) / n)
-        return best * 1e3, step, toks
+    step = parallel.build_train_step(
+        net, mlm_loss, "adam", {"learning_rate": 1e-4},
+        compute_dtype="bfloat16", cast_batch=False,
+        mesh=mesh, zero=zero)
+    rng = np.random.RandomState(0)
+    toks = nd.array(rng.randint(0, 30522, (batch, seqlen))
+                    .astype(np.float32))
+    last = step.run_steps(toks, toks, 2, reuse_batch=True)
+    float(last.asnumpy()[-1])  # compile + drain
+    n, best = 8, float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        last = step.run_steps(toks, toks, n, reuse_batch=True)
+        float(last.asnumpy()[-1])
+        best = min(best, (time.perf_counter() - t0) / n)
+    return best * 1e3, step, toks
 
 
 def measure_variant(batch, seqlen, variant):
@@ -241,11 +240,10 @@ def measure_variant(batch, seqlen, variant):
         dp = min(8, jax.device_count())
         if dp <= 1 or batch % dp:
             return None  # needs a >1 dp mesh that divides the batch
-        t, _, _ = measure_train_step(batch, seqlen, True, zero=1)
+        t, _, _ = measure_train_step(batch, seqlen, zero=1)
         return t
-    if variant in ("step_batched", "step_perparam"):
-        t, _, _ = measure_train_step(batch, seqlen,
-                                     variant == "step_batched")
+    if variant == "step":
+        t, _, _ = measure_train_step(batch, seqlen)
         return t
     if variant == "loop_floor":
         rng = np.random.RandomState(0)
@@ -279,17 +277,20 @@ def measure_variant(batch, seqlen, variant):
 
 VARIANTS = ["full", "attn_core_ablated", "attn_ablated", "ffn_ablated",
             "mlm_ablated", "ln_ablated", "no_dropout", "epilogue_lax",
-            "loop_floor", "step_batched", "step_perparam", "step_zero"]
+            "loop_floor", "step", "step_zero"]
 
 
 def main():
+    if "--help" in sys.argv[1:] or "-h" in sys.argv[1:]:
+        print(__doc__)
+        return
     argv = [a for a in sys.argv[1:] if not a.startswith("--")]
     want_cost = "--cost" in sys.argv[1:]
     batch = int(argv[0]) if len(argv) > 0 else 32
     seqlen = int(argv[1]) if len(argv) > 1 else 128
     only = argv[2].split(",") if len(argv) > 2 else None
     print(f"device={jax.devices()[0]} b{batch} s{seqlen} bf16 "
-          f"(fwd+bwd, chained; step_* rows add the optimizer)")
+          f"(fwd+bwd, chained; step rows add the optimizer)")
     base = None
     for v in VARIANTS:
         if only and v not in only:
@@ -301,7 +302,7 @@ def main():
             continue
         tok_s = batch * seqlen / t * 1e3
         delta = f"  (component ~{base - t:6.1f} ms)" \
-            if base is not None and not v.startswith("step_") \
+            if base is not None and not v.startswith("step") \
             and v != "loop_floor" else ""
         if v == "full":
             base = t
