@@ -263,7 +263,7 @@ def _kv_path_logits(runner, prompt, stream):
         tokens[0, 0] = last
         steps[0] = len(prompt) + t
         logits, kv = runner.decode(tokens, steps, kv)
-        out.append(logits[0, 0])
+        out.append(np.asarray(logits)[0, 0])
     return np.stack(out)
 
 

@@ -323,6 +323,22 @@ def write_whole_lanes(table, rows, lanes, axis):
     return lax.fori_loop(0, rows.shape[axis], one_row, table)
 
 
+def read_whole_lanes(table, lanes, axis):
+    """Lanes ``lanes[r]`` of ``table`` (along ``axis``) side by side,
+    one ``lax.dynamic_slice`` a row: what a prefill takes out of a slot
+    table before ``write_whole_lanes`` puts it back.  Not ``jnp.take``:
+    the chip's compiler turns a gather of lanes that are megabytes each
+    into slices of the WHOLE table laid beside it (5.0 GB of temporaries
+    for a 4.5 GB table of keys and values), where a slice at a computed
+    offset reads the table where it lies; and not slices stored one by
+    one into a zeroed result, which the compiler does not do in place
+    either (0.3 GB more than this at four lanes of 142 MB: PERF.md,
+    PR 32)."""
+    return jnp.concatenate(
+        [lax.dynamic_slice_in_dim(table, lanes[r], 1, axis=axis)
+         for r in range(lanes.shape[0])], axis=axis)
+
+
 register_op("kv_cache_write", num_inputs=3, differentiable=False,
             params=[Param("layer", int, 0, lower=0),
                     Param("plane", int, 0, enum=(0, 1))],
@@ -407,28 +423,49 @@ def _rms(x32, weight, eps):
     return x32 * lax.rsqrt(ms + eps) * weight.astype(jnp.float32)
 
 
-def _rms_norm_op(x, weight, eps=1e-5):
+def _rms_norm_op(x, weight, eps=1e-5, scope="rms_norm"):
     """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
-    statistics in float32 whatever ``x`` is."""
-    with jax.named_scope("rms_norm"):
+    statistics in float32 whatever ``x`` is.  ``scope`` names the
+    ``jax.named_scope`` the work is found under in a trace (the
+    attention layers' norm of queries and keys asks for ``qk_norm``)."""
+    with jax.named_scope(scope):
         return _rms(x.astype(jnp.float32), weight, eps).astype(x.dtype)
 
 
 register_op("rms_norm", num_inputs=2,
-            params=[Param("eps", float, 1e-5)],
+            params=[Param("eps", float, 1e-5),
+                    Param("scope", str, "rms_norm")],
             doc=_rms_norm_op.__doc__)(_rms_norm_op)
 
 
-def _gated_rms_norm_op(y, z, weight, eps=1e-5):
-    """``rms_norm(y * silu(z), weight)``: the Mamba-2 mixer's output
-    gate and norm, over the whole last axis (one group)."""
+def _gated_rms_norm_op(y, z, weight, eps=1e-5, group=0,
+                       norm_before_gate=False):
+    """A mixer's output gate and norm.  As Mamba-2 has them:
+    ``rms_norm(y * silu(z), weight)`` over the whole last axis (one
+    group).  ``group`` > 0 is the per-head form: the last axis is cut
+    into runs of ``group`` values, each normalised alone with the one
+    ``weight`` of ``group`` values they share; ``norm_before_gate``
+    gates the normalised value, ``rms_norm(y, weight) * silu(z)``, as
+    the Gated DeltaNet layer does."""
     with jax.named_scope("rms_norm"):
-        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        return _rms(g, weight, eps).astype(y.dtype)
+        y32 = y.astype(jnp.float32)
+        gate = jax.nn.silu(z.astype(jnp.float32))
+        if not norm_before_gate:
+            y32 = y32 * gate
+        if group:
+            heads = y32.reshape(y32.shape[:-1] + (-1, group))
+            y32 = _rms(heads, weight, eps).reshape(y32.shape)
+        else:
+            y32 = _rms(y32, weight, eps)
+        if norm_before_gate:
+            y32 = y32 * gate
+        return y32.astype(y.dtype)
 
 
 register_op("gated_rms_norm", num_inputs=3,
-            params=[Param("eps", float, 1e-5)],
+            params=[Param("eps", float, 1e-5),
+                    Param("group", int, 0, lower=0),
+                    Param("norm_before_gate", bool, False)],
             doc=_gated_rms_norm_op.__doc__)(_gated_rms_norm_op)
 
 
@@ -443,8 +480,10 @@ def _fresh(state, step, length):
                      state, jnp.zeros((), state.dtype))
 
 
-def _ssm_conv_op(table, x, weight, bias, step, length, layer=0):
+def _ssm_conv_op(table, x, weight, *rest, layer=0, no_bias=False):
     """Causal depthwise convolution with carried state, then silu.
+    Inputs ``(table, x, weight, bias, step, length)``, or without
+    ``bias`` where ``no_bias`` is set.
     ``table``: (layers, B, K-1, C), lane b's last K-1 inputs of each
     layer; ``x``: (B, T, C) new inputs, of which row b's first
     ``length_b`` are valid; ``weight``: (C, K); ``bias``: (C,).
@@ -454,14 +493,16 @@ def _ssm_conv_op(table, x, weight, bias, step, length, layer=0):
     inputs (a lane with ``length`` 0 keeps what it had), so padded
     positions never enter the state.  A lane with ``step`` 0 and
     something valid starts from zeros.  ``layer`` is a static attribute."""
+    bias, step, length = ((None,) if no_bias else ()) + rest
     with jax.named_scope("ssm/conv"):
         T, K = x.shape[1], weight.shape[1]
         state = _fresh(table[layer], step, length).astype(jnp.float32)
         full = jnp.concatenate([state, x.astype(jnp.float32)], axis=1)
         w = weight.astype(jnp.float32)
-        y = bias.astype(jnp.float32)
+        y = None if no_bias else bias.astype(jnp.float32)
         for j in range(K):
-            y = y + full[:, j:j + T] * w[:, j]
+            tap = full[:, j:j + T] * w[:, j]
+            y = tap if y is None else y + tap
         y = jax.nn.silu(y)
         n = jnp.asarray(length).astype(jnp.int32)
         last = jax.vmap(lambda f, at: lax.dynamic_slice_in_dim(
@@ -471,7 +512,8 @@ def _ssm_conv_op(table, x, weight, bias, step, length, layer=0):
 
 
 register_op("ssm_conv", num_inputs=6, num_outputs=2, differentiable=False,
-            params=[Param("layer", int, 0, lower=0)],
+            params=[Param("layer", int, 0, lower=0),
+                    Param("no_bias", bool, False)],
             doc=_ssm_conv_op.__doc__)(_ssm_conv_op)
 
 
@@ -584,6 +626,187 @@ register_op("ssm_scan", num_inputs=10, num_outputs=2,
             params=[Param("layer", int, 0, lower=0),
                     Param("chunk", int, 256, lower=1)],
             doc=_ssm_scan_op.__doc__)(_ssm_scan_op)
+
+
+# ----------------------------------------------------------------------
+# gated delta-rule (Gated DeltaNet) layers: the state is a matrix a
+# head, corrected at every token by a rank-one update of itself
+# ----------------------------------------------------------------------
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _l2_normalised(x32):
+    return x32 * lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+
+
+def _delta_step(s0, q, k, v, g, beta):
+    """One position of the gated delta rule.  ``s0`` (B, H, dk, dv);
+    ``q``, ``k`` (B, H, dk); ``v`` (B, H, dv); ``g``, ``beta`` (B, H).
+    ``S' = exp(g) S``; ``r = v - S'^T k``; ``S = S' + beta k r^T``;
+    ``o = S^T q``: element-wise passes over the state and sums down its
+    ``dk`` axis, nothing reshapes it.  Returns ``(o (B, H, dv), S)``."""
+    s = s0 * jnp.exp(g)[..., None, None]
+    r = v - jnp.sum(s * k[..., None], axis=2)
+    s = s + k[..., None] * (beta[..., None] * r)[..., None, :]
+    return jnp.sum(s * q[..., None], axis=2), s
+
+
+def _unit_lower_inverse(A, mm):
+    """``(I + A)^-1`` of strictly lower triangular ``A`` (..., rows,
+    rows) by products alone; ``A`` is grown with zeros to a power of
+    two.  The diagonal blocks of 4 rows are nilpotent, so their Neumann
+    series ends: ``(I - D)(I + D^2)``.  Then pairs of blocks are joined until one is left: the inverse of
+    ``[[X, 0], [Y, Z]]`` is ``[[X', 0], [-Z' Y X', Z']]``, and every
+    block on the way is a block of the answer, so nothing grows past it
+    (the whole series over 64 rows overflows where the keys of a chunk
+    are alike).  On the keys a model makes it agrees with forward
+    substitution to the last digits; on a chunk of keys within a
+    hundredth of each other with ``beta`` 1.9 it is 3e-5 off where
+    forward substitution is 3e-6 (8-row blocks: 2e-4), far under the
+    bfloat16 products it feeds.  The chip runs it in six tenths of the
+    time of its ``triangular_solve`` call (PERF.md sec. 6, PR 32)."""
+    rows, lead = A.shape[-1], A.shape[:-2]
+    C = 1 << (rows - 1).bit_length()
+    A = jnp.pad(A, [(0, 0)] * len(lead) + [(0, C - rows)] * 2)
+    h = min(4, C)
+
+    def blocks(below):
+        """Down the diagonal, the h x h blocks on it, or (``below``)
+        the one under the first of each pair: plain slices, side by
+        side (an indexed gather here crashed the chip's compiler)."""
+        step = 2 * h if below else h
+        return jnp.stack([A[..., i + below * h:i + below * h + h, i:i + h]
+                          for i in range(0, C, step)], axis=-3)
+
+    eye = jnp.eye(h, dtype=A.dtype)
+    D = blocks(0)
+    T = mm("...ij,...jk->...ik", eye - D,
+           eye + mm("...ij,...jk->...ik", D, D))
+    while h < C:
+        pairs = T.reshape(lead + (C // (2 * h), 2, h, h))
+        X, Z = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        low = -mm("...ij,...jk->...ik", Z,
+                  mm("...ij,...jk->...ik", blocks(1), X))
+        T = jnp.concatenate(
+            [jnp.concatenate([X, jnp.zeros_like(X)], axis=-1),
+             jnp.concatenate([low, Z], axis=-1)], axis=-2)
+        h *= 2
+    return T[..., 0, :rows, :rows]
+
+
+def _delta_chunked(q, k, v, g, beta, s0, chunk):
+    """The gated delta rule over many positions in its chunked (WY)
+    form (Yang, Kautz & Hatamizadeh 2024, arXiv:2412.06464, sec. 3).
+    ``q``, ``k`` (B, T, H, dk), ``v`` (B, T, H, dv), ``g`` <= 0 and
+    ``beta`` (B, T, H) (both 0 at a padded position: no decay, no
+    correction), ``s0`` (B, H, dk, dv).  With ``G`` the running sum of
+    ``g`` inside a chunk and ``d_t = beta_t r_t`` the correction each
+    position writes, the recurrence unrolls to ``S_t = e^{G_t} S_0 +
+    sum_{i<=t} e^{G_t-G_i} k_i d_i^T``, and the ``d`` of a chunk solve
+    the unit-lower-triangular system ``(I + A) D = beta V - (beta
+    e^{G} K) S_0`` with ``A_ti = beta_t e^{G_t-G_i} (k_t.k_i)``, i < t.
+    So per chunk and head: ``U = (I+A)^-1 beta V`` and ``W = (I+A)^-1
+    beta e^G K`` for all chunks at once (the inverse by products:
+    ``_unit_lower_inverse``), then from chunk to chunk only
+    ``D = U - W S``, ``O = e^G (Q S) + (M * Q K^T) D`` and ``S' =
+    e^{G_C} S + (e^{G_C-G} K)^T D`` are carried.  Every ratio of decays
+    is ``exp`` of a difference of ``G``, never a quotient of products
+    (which underflows inside a chunk).  All contractions at HIGHEST:
+    they feed the float32 state.  Returns ``(o (B, T, H, dv), S_T)``."""
+    B, T, H, dk = k.shape
+    dv = v.shape[-1]
+    C = min(int(chunk), T)
+    pad = (-T) % C
+    if pad:
+        grow = lambda z: jnp.pad(z, [(0, 0), (0, pad)] + [(0, 0)] * (z.ndim - 2))
+        q, k, v, g, beta = grow(q), grow(k), grow(v), grow(g), grow(beta)
+    nc = (T + pad) // C
+    # (B, nc*C, H, ...) -> (nc, B, H, C, ...)
+    cut = lambda z: jnp.moveaxis(
+        z.reshape((B, nc, C) + z.shape[2:]), (1, 3), (0, 2))
+    q, k, v, g, beta = cut(q), cut(k), cut(v), cut(g), cut(beta)
+    G = jnp.cumsum(g, axis=-1)                          # (nc, B, H, C)
+    diff = G[..., :, None] - G[..., None, :]            # G_t - G_i
+    lower = jnp.tril(jnp.ones((C, C), bool), -1)
+    upto = jnp.tril(jnp.ones((C, C), bool))
+    mm = lambda spec, a, b: jnp.einsum(spec, a, b, precision=_HIGHEST)
+    A = beta[..., :, None] * jnp.exp(jnp.where(lower, diff, -jnp.inf)) \
+        * mm("...td,...id->...ti", k, k)
+    rhs = jnp.concatenate([beta[..., None] * v,
+                           (beta * jnp.exp(G))[..., None] * k], axis=-1)
+    uw = mm("...ti,...iv->...tv", _unit_lower_inverse(A, mm), rhs)
+    qk = mm("...td,...id->...ti", q, k) \
+        * jnp.exp(jnp.where(upto, diff, -jnp.inf))
+    k_end = k * jnp.exp(G[..., -1:] - G)[..., None]
+    into, at_end = jnp.exp(G), jnp.exp(G[..., -1])
+
+    def carry(s, c):
+        uw_c, q_c, qk_c, k_end_c, into_c, at_end_c = c
+        d = uw_c[..., :dv] - mm("bhck,bhkv->bhcv", uw_c[..., dv:], s)
+        o = into_c[..., None] * mm("bhck,bhkv->bhcv", q_c, s) \
+            + mm("bhti,bhiv->bhtv", qk_c, d)
+        s = at_end_c[..., None, None] * s + mm("bhck,bhcv->bhkv", k_end_c, d)
+        return s, o
+
+    s_end, o = lax.scan(carry, s0, (uw, q, qk, k_end, into, at_end))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, nc * C, H, dv)
+    return o[:, :T], s_end
+
+
+def _delta_rule_op(table, q, k, v, g, beta, step, length, layer=0,
+                   chunk=64):
+    """The gated delta rule with carried state (Gated DeltaNet).
+    ``table``: (layers, B, H, dk, dv) float32, lane b's state of each
+    layer, a (dk, dv) matrix a head; ``q``, ``k``: (B, T, H*dk) and
+    ``v``: (B, T, H*dv), after their convolution; ``g``: (B, T, H) the
+    log of the decay, <= 0;
+    ``beta``: (B, T, H) the correction's strength.  Per head ``q`` and
+    ``k`` are brought to unit length (1e-6 under the root) and ``q``
+    scaled by ``dk^-1/2``; then ``S' = exp(g_t) S_{t-1}``, ``r_t = v_t
+    - S'^T k_t``, ``S_t = S' + beta_t k_t r_t^T``, ``o_t = S_t^T q_t``.
+    Row b's positions from ``length_b`` on are padding: their ``g`` and
+    ``beta`` are 0, so they leave the state as it is; a lane with
+    ``step`` 0 and something valid starts from zeros.  Returns ``(o (B,
+    T, H*dv), table)`` with plane ``layer`` replaced whole.  T > 1
+    takes the chunked form (``_delta_chunked``, scope ``delta/chunk``);
+    T = 1 is one read and one write of the plane (scope
+    ``delta/state_update``), fenced as ``ssm_scan``'s is so that a
+    trace can time it alone.  ``layer`` and ``chunk`` are static."""
+    B, T, H = g.shape
+    dk, dv = k.shape[-1] // H, v.shape[-1] // H
+    f32 = jnp.float32
+    if T == 1:
+        q, k, v, g, beta, step, length = lax.optimization_barrier(
+            (q, k, v, g, beta, step, length))
+    n = jnp.asarray(length).astype(jnp.int32)
+    with jax.named_scope("delta/state_update" if T == 1
+                         else "delta/chunk"):
+        valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
+                 < n[:, None])[..., None].astype(f32)
+        g32, b32 = g.astype(f32) * valid, beta.astype(f32) * valid
+        qh = _l2_normalised(q.astype(f32).reshape(B, T, H, dk)) \
+            * (1.0 / float(np.sqrt(dk)))
+        kh = _l2_normalised(k.astype(f32).reshape(B, T, H, dk))
+        s0 = _fresh(table[layer], step, n).astype(f32)
+        vh = v.astype(f32).reshape(B, T, H, dv)
+        if T == 1:
+            o, s_new = _delta_step(s0, qh[:, 0], kh[:, 0], vh[:, 0],
+                                   g32[:, 0], b32[:, 0])
+        else:
+            o, s_new = _delta_chunked(qh, kh, vh, g32, b32, s0, chunk)
+        o = o.reshape(B, T, H * dv)
+        o = o.astype(v.dtype)
+        table = table.at[layer].set(s_new.astype(table.dtype))
+    if T == 1:
+        o = lax.optimization_barrier(o)
+    return o, table
+
+
+register_op("delta_rule", num_inputs=8, num_outputs=2,
+            differentiable=False,
+            params=[Param("layer", int, 0, lower=0),
+                    Param("chunk", int, 64, lower=1)],
+            doc=_delta_rule_op.__doc__)(_delta_rule_op)
 
 
 def _flash_attention_op(q, k, v, causal=False, sm_scale=-1.0):

@@ -73,7 +73,7 @@ from .batcher import (InferenceRequest, RequestTimeout, ServerBusy,
 from .runner import batch_ladder
 
 __all__ = ["GenerateRequest", "GenerateRunner", "GenerateBatcher",
-           "StateTable", "sample_token"]
+           "StateTable", "DeviceLogits", "sample_token"]
 
 
 class StateTable(NamedTuple):
@@ -97,7 +97,11 @@ def sample_token(logits, *, position: int, seed: int = 0,
     which run) computes it."""
     if top_k is None or top_k <= 1:
         # the first maximum of the row as it came: a float64 copy of a
-        # hundred thousand logits would find the same one
+        # hundred thousand logits would find the same one — and so does
+        # the device, for a row it kept (``DeviceLogits``)
+        first = getattr(logits, "first_maximum", None)
+        if first is not None:
+            return int(first)
         return int(np.argmax(np.asarray(logits).reshape(-1)))  # mxlint: sync-point — logits are already host rows here
     # mxlint: sync-point — logits are already host rows here
     row = np.asarray(logits, np.float64).reshape(-1)  # mxlint: disable=dtype-hygiene (f64 host sampling on purpose: platform-identical softmax/ties)
@@ -112,6 +116,47 @@ def sample_token(logits, *, position: int, seed: int = 0,
     rng = np.random.default_rng([int(seed) & 0x7FFFFFFF,
                                  int(position) & 0x7FFFFFFF])
     return int(idx[rng.choice(k, p=p)])
+
+
+class DeviceLogits:
+    """A decode step's logits ``(slots, 1, V)`` as ``decode`` hands them
+    back: left on the device, beside each slot's first maximum, found
+    there and brought over — 4 bytes a slot where a row is 400 KB at a
+    vocabulary of 100,352.  Indexed as the host array would be,
+    ``logits[slot, 0]`` is a row that ``sample_token`` draws greedily
+    from without touching its numbers; whoever wants the numbers
+    (``np.asarray`` of the whole or of a row: a top-k draw, a test)
+    brings all of them over, once."""
+
+    class Row:
+        __slots__ = ("first_maximum", "_of", "_slot")
+
+        def __init__(self, of, slot):
+            self._of, self._slot = of, slot
+            self.first_maximum = int(of.first_maximum[slot])
+
+        def __array__(self, dtype=None, copy=None):
+            row = self._of.host()[self._slot, 0]
+            return row if dtype is None else row.astype(dtype)
+
+    def __init__(self, rows, first_maximum: np.ndarray):
+        self.rows, self.first_maximum = rows, first_maximum
+        self._host = None
+
+    def host(self) -> np.ndarray:
+        """The numbers, brought over at the first call and kept."""
+        if self._host is None:
+            # mxlint: sync-point — deliberate D2H, on demand: someone samples from the numbers
+            self._host = np.asarray(self.rows)
+        return self._host
+
+    def __array__(self, dtype=None, copy=None):
+        rows = self.host()
+        return rows if dtype is None else rows.astype(dtype)
+
+    def __getitem__(self, at):
+        slot, _ = at
+        return self.Row(self, slot)
 
 
 class GenerateRequest(InferenceRequest):
@@ -285,6 +330,8 @@ class GenerateRunner:
         self._kv_shape = self._table_shapes[
             [t.name for t in self.state_spec].index("kv")]
         self.max_len = self.kv_spec[4]
+        # what a prefill row gathers: one lane of every table
+        self._lane_bytes = sum(self.state_bytes().values()) // self._slots
         self.prompt_buckets = tuple(sorted(int(s)
                                            for s in prompt_buckets))
         if not self.prompt_buckets:
@@ -385,8 +432,9 @@ class GenerateRunner:
             labels=("kind", "bucket"))
         self._m_state_bytes = obs.gauge(
             "mxtpu_gen_state_bytes",
-            "Bytes of each per-lane state table as new_cache() "
-            "allocated it (scratch slot included).",
+            "Bytes the device holds for each per-lane state table as "
+            "new_cache() allocated it (scratch slot and the device's "
+            "tile padding included).",
             labels=("table",))
         self._m_resets = obs.counter(
             "mxtpu_gen_state_reset_total",
@@ -599,12 +647,13 @@ class GenerateRunner:
         advancing step offsets.  Padding rows target the scratch slot.
         One KV table: logits are (b,s,V) and the lanes go back by one
         indexed update.  A state spec: logits are (b,1,V) and each
-        table's rows go back one lane at a time, in place
+        table's rows come out one lane at a time
+        (``read_whole_lanes``) and go back one lane at a time, in place
         (``write_whole_lanes``), so no program holds a second copy of
         a table whose lanes are megabytes each."""
         import jax
         import jax.numpy as jnp
-        from ..ndarray.rnn_impl import write_whole_lanes
+        from ..ndarray.rnn_impl import read_whole_lanes, write_whole_lanes
 
         def fn(*args):
             *rows, lane_idx, state, param_vals = args
@@ -618,7 +667,7 @@ class GenerateRunner:
                         new_small.astype(state.dtype))
                 axes = [t.lane_axis for t in self.state_spec]
                 logits, new = self._eval_incremental(
-                    rows, tuple(jnp.take(t, idx, axis=a)
+                    rows, tuple(read_whole_lanes(t, idx, a)
                                 for t, a in zip(state, axes)),
                     param_vals)
                 return logits, tuple(
@@ -778,13 +827,21 @@ class GenerateRunner:
                        for t, shape in zip(self.state_spec,
                                            self._table_shapes))
         if self._obs:
-            for name, nbytes in self.state_bytes().items():
+            for name, nbytes in self.held_bytes(tables).items():
                 self._m_state_bytes.labels(table=name).set(nbytes)
         return tables[0] if self._one_table else tables
 
+    def held_bytes(self, tables) -> Dict[str, int]:
+        """Bytes the device holds for each of ``tables`` (as
+        ``new_cache()`` made them), by the table's name: the device
+        tiles an array's last two axes, so a table whose last axis is
+        no whole number of tiles takes more than its elements do."""
+        return {t.name: int(a.on_device_size_in_bytes())
+                for t, a in zip(self.state_spec, tables)}
+
     def state_bytes(self) -> Dict[str, int]:
-        """Bytes of each state table as ``new_cache()`` allocates it
-        (scratch slot included), by the table's name."""
+        """Bytes of each state table's elements as ``new_cache()``
+        allocates it (scratch slot included), by the table's name."""
         import jax.numpy as jnp
         return {t.name: int(np.prod(shape, dtype=np.int64))
                 * jnp.dtype(t.dtype).itemsize
@@ -812,15 +869,19 @@ class GenerateRunner:
                           kv,
                           {"rows": b, "bucket": s,
                            "tokens": int(np.sum(length)),
-                           "resets": fresh})
+                           "resets": fresh,
+                           "lane_bytes": b * self._lane_bytes})
 
     def decode(self, tokens: np.ndarray, step: np.ndarray, kv,
-               length=None) -> Tuple[np.ndarray, Any]:
+               length=None) -> Tuple["DeviceLogits", Any]:
         """THE decode step: ``tokens (slots, 1)`` / ``step (slots,)``
         advance every slot one position; ``length (slots,)`` is 1 for
         a lane that decodes and 0 for an idle one (all 1 if not
-        given).  Returns (host logits (slots, 1, V), new device
-        state)."""
+        given).  Returns (the logits (slots, 1, V) as a
+        :class:`DeviceLogits`, new device state): the logits stay where
+        they are, and each slot's first maximum, found by a second
+        small executable, is all that crosses to the host — what a
+        greedy lane needs; ``np.asarray`` of them brings the numbers."""
         if length is None:
             length = np.ones((self._slots,), np.float32)
         on = length > 0
@@ -829,12 +890,29 @@ class GenerateRunner:
                           {"slots": self._slots, "active": int(on.sum()),
                            "context_tokens": int(step[on].sum())})
 
+    @staticmethod
+    def _first_maximum_of(entry, logits):
+        """The executable that finds each slot's first maximum of
+        ``logits (slots, 1, V)`` on the device, ``(slots,)`` int32;
+        built at the entry's first run (a set-up's first run of the
+        decode program, never a token's)."""
+        import jax
+        import jax.numpy as jnp
+        fn = entry.get("first_maximum")
+        if fn is None:
+            fn = entry["first_maximum"] = jax.jit(
+                lambda rows: jnp.argmax(rows[:, 0, :], axis=-1).astype(
+                    jnp.int32)).lower(logits).compile()
+        return fn
+
     def _call(self, name: str, bucket: Tuple,
               host_rows: Sequence[np.ndarray], kv,
-              counts: Dict[str, int]) -> Tuple[np.ndarray, Any]:
+              counts: Dict[str, int]) -> Tuple[Any, Any]:
         """One executable call, in the region ``name`` with its three
         children: the host rows staged on the device, the call itself,
-        the logits brought back."""
+        and what comes back — a prefill's logits, a decode step's first
+        maxima (``logits_bytes`` counts the logits the call made,
+        ``fetched_bytes`` what the fetch brought over)."""
         import jax
         with self._region(name, **counts) as rg:
             entry = self._entry(bucket)
@@ -849,10 +927,18 @@ class GenerateRunner:
                     guards.no_implicit_transfers(self._guards):
                 logits, kv = entry["compiled"](*staged, kv,
                                                self._param_vals)
+                first = self._first_maximum_of(entry, logits)(logits) \
+                    if bucket[0] == "decode" else None
+            made = logits.nbytes
             with self._region(name + obs.SPAN_FETCH):
-                # mxlint: sync-point — deliberate D2H: the batcher samples on host
-                logits = np.asarray(logits)
-            rg.set(logits_bytes=logits.nbytes,
+                if first is None:
+                    # mxlint: sync-point — deliberate D2H: the batcher samples a prefill's first tokens on host
+                    logits = fetched = np.asarray(logits)
+                else:
+                    # mxlint: sync-point — deliberate D2H: token ids only
+                    fetched = np.asarray(first)
+                    logits = DeviceLogits(logits, fetched)
+            rg.set(logits_bytes=made, fetched_bytes=fetched.nbytes,
                    kv_kernel_writes=entry["kv_kernel_writes"])
         return logits, kv
 
@@ -1358,6 +1444,10 @@ class GenerateBatcher:
             tokens[i, 0] = lane.last_token
             steps[i] = lane.frontier
             length[i] = 1
+        # a greedy lane needs its slot's first maximum and nothing else
+        # of 400 KB of logits: the runner finds it on the device, and a
+        # host whose argmax runs at half speed in one process of two
+        # (PERF.md, PR 32) no longer sets the step's length
         logits, self._kv = runner.decode(tokens, steps, self._kv,
                                          length)
         # the tokens exist only now, after the decode: gaps are read

@@ -282,3 +282,93 @@ def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,
     tables = layers * (plane + slots * (k - 1) * chan * 4)
     assert mem["alias_size_in_bytes"] == tables
     assert mem["temp_size_in_bytes"] < plane, mem
+
+
+def test_delta_state_is_updated_in_place_on_v5e(one_chip,
+                                                no_persistent_cache):
+    """The Gated DeltaNet cell's state at its real widths (32 slots, 30
+    heads, keys of 96, values of 192, 11520 convolution channels; two of
+    the 12 linear layers of the stage): one decode step through
+    ``ssm_conv`` (no bias) and ``delta_rule`` replaces each layer's
+    plane of both tables.  The chip holds a last axis of 192 as 256 (a
+    plane of 70.8 MB takes 94.4), aliases the donated tables to the
+    results and needs less than one plane of temporaries: laid out with
+    heads x 192 minor the same update wrote each head's keys out over a
+    whole plane and needed 2.4 planes' worth (PERF.md, PR 32)."""
+    from mxtpu.ndarray import rnn_impl
+    layers, slots, heads, dk, dv, k = 2, 32, 30, 96, 192, 4
+    chan = heads * (2 * dk + dv)
+
+    def step(delta, conv, qkv, g, beta, w, at, length):
+        out = jnp.zeros((slots, 1, heads * dv), jnp.float32)
+        for i in range(layers):
+            mixed, conv = rnn_impl._ssm_conv_op(
+                conv, qkv[i] + jnp.pad(out, ((0, 0), (0, 0),
+                                             (2 * heads * dk, 0))),
+                w, at, length, layer=i, no_bias=True)
+            out, delta = rnn_impl._delta_rule_op(
+                delta, mixed[..., :heads * dk],
+                mixed[..., heads * dk:2 * heads * dk],
+                mixed[..., 2 * heads * dk:], g[i], beta[i], at, length,
+                layer=i)
+        return out, delta, conv
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    from mxtpu import analysis
+    _, mem = analysis.compiled_artifact(
+        step, sds(layers, slots, heads, dk, dv),
+        sds(layers, slots, k - 1, chan), sds(layers, slots, 1, chan),
+        sds(layers, slots, 1, heads), sds(layers, slots, 1, heads),
+        sds(chan, k), sds(slots), sds(slots), donate_argnums=(0, 1))
+    held = slots * heads * dk * 256 * 4          # 192 in tiles of 128
+    tables = layers * (held + slots * (k - 1) * chan * 4)
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < held, mem
+
+
+def test_a_prefill_reads_its_lanes_where_they_lie_on_v5e(
+        one_chip, on_tpu, chip_layouts, no_persistent_cache):
+    """The Gated DeltaNet cell's table of keys and values (32 slots, 30
+    heads of 128, 2304 positions, bfloat16: 1.13 GB a layer; one layer
+    here): the chip keeps it with ``head_dim`` minor, so a one-token
+    write is a run, not a column, and stays the lanes' loop; and four
+    lanes taken out by ``read_whole_lanes`` and put back by
+    ``write_whole_lanes`` need the four lanes twice over, not the
+    slices of the whole table that ``jnp.take`` brings (5.0 GB at the
+    cell's four layers: PERF.md, PR 32)."""
+    from mxtpu.kernels import kv_write
+    from mxtpu.ndarray import rnn_impl
+    shape = (1, 2, 32, 30, 2304, 128)
+    held = rnn_impl._resident_layout(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    assert held.major_to_minor == (0, 1, 2, 3, 4, 5)
+
+    def prefill(idx, table):
+        lanes = idx.astype(jnp.int32)
+        small = rnn_impl.read_whole_lanes(table, lanes, 2)
+        return rnn_impl.write_whole_lanes(table, small + jnp.bfloat16(1),
+                                          lanes, 2)
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    from mxtpu import analysis
+    text, mem = analysis.compiled_artifact(
+        prefill, sds(4), sds(*shape, dtype=jnp.bfloat16), donate_argnums=1)
+    lane = 2 * 30 * 2304 * 128 * 2
+    assert mem["alias_size_in_bytes"] == 32 * lane
+    assert mem["temp_size_in_bytes"] <= 2.2 * 4 * lane, mem
+    assert "mini-gather" not in text
+
+    def decode(table, new, at):
+        return rnn_impl._kv_cache_write_op(table, new, at, layer=0, plane=1)
+
+    with kv_write.call_sites() as traced:
+        text, mem = analysis.compiled_artifact(
+            decode, sds(*shape, dtype=jnp.bfloat16),
+            sds(32, 30, 1, 128), sds(32), donate_argnums=0)
+    assert traced[0] == 0 and "tpu_custom_call" not in text
+    assert mem["alias_size_in_bytes"] == 32 * lane
+    assert mem["temp_size_in_bytes"] < lane, mem

@@ -724,7 +724,7 @@ def _serve_by_hand(r):
         nonlocal kv
         logits, kv = r.decode(np.array(tokens, f32)[:, None],
                               np.array(step, f32), kv)
-        out.append(logits)
+        out.append(np.asarray(logits))
 
     prefill([[3, 7, 1, 4], [5, 2, 6, 0]], [0, 0], [0, 1])
     prefill([[9, 8, 2, 2]], [4], [0])

@@ -147,9 +147,10 @@ GEN_COUNTS = {
     "gen/admit/done": {"admitted", "evicted", "wait_us_sum"},
     "gen/prefill": {"rows", "rung", "bucket", "chunks"},
     "gen/prefill/call": {"rows", "bucket"},
-    "gen/prefill/call/done": {"logits_bytes"},
+    "gen/prefill/call/done": {"logits_bytes", "fetched_bytes"},
     "gen/decode": {"slots"},
-    "gen/decode/done": {"logits_bytes", "kv_kernel_writes"},
+    "gen/decode/done": {"logits_bytes", "fetched_bytes",
+                        "kv_kernel_writes"},
     "gen/sample": {"lanes"}, "gen/fire": {"tokens"},
 }
 
@@ -188,6 +189,12 @@ def test_generate_regions_land_in_the_xplane(runner, tmp_path):
         2 * 4 * vocab_bytes
     assert s.named("gen/decode/done")[0][3]["logits_bytes"] == \
         (LANES + 1) * vocab_bytes
+    # a prefill's logits come over whole; a decode step's stay on the
+    # device and a token id a slot crosses
+    assert s.named("gen/prefill/call/done")[0][3]["fetched_bytes"] == \
+        2 * 4 * vocab_bytes
+    assert s.named("gen/decode/done")[0][3]["fetched_bytes"] == \
+        (LANES + 1) * 4
     assert s.named("gen/decode")[0][3]["slots"] == LANES + 1
     steps = [e[3]["step"] for e in s.named("gen/step")]
     assert steps == list(range(1, len(steps) + 1))
