@@ -1490,10 +1490,14 @@ def bench_serving_generate(n_req=8, max_tokens=24, repeats=3):
         s = runner.prompt_bucket_for(len(seq))
         tok = np.zeros((b, s), np.float32)
         tok[0, :len(seq)] = seq
+        length = np.zeros(b, np.float32)
+        length[0] = len(seq)
         logits, kv = runner.prefill(
             tok, np.zeros(b, np.float32),
-            np.full(b, LANES, np.float32), kv)  # scratch slot
-        seq.append(int(np.argmax(logits[0, len(seq) - 1])))
+            np.full(b, LANES, np.float32), kv, length)  # scratch slot
+        # the row of the sequence's last position, its maximum found
+        # on the device
+        seq.append(int(logits.first_maximum[0]))
     naive_rate = max_tokens / (time.perf_counter() - t0)
 
     stats = {

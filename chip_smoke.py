@@ -255,8 +255,11 @@ def _kv_path_logits(runner, prompt, stream):
     tok[0, :len(prompt)] = prompt
     lanes = np.full(b, runner.scratch_slot, np.float32)
     lanes[0] = 0
-    logits, kv = runner.prefill(tok, np.zeros(b, np.float32), lanes, kv)
-    out = [logits[0, len(prompt) - 1]]
+    length = np.zeros(b, np.float32)
+    length[0] = len(prompt)
+    logits, kv = runner.prefill(tok, np.zeros(b, np.float32), lanes, kv,
+                                length)
+    out = [np.asarray(logits[0, 0])]
     for t, last in enumerate(stream[:-1]):
         tokens = np.zeros((slots, 1), np.float32)
         steps = np.zeros(slots, np.float32)
@@ -279,10 +282,12 @@ def _reprefill_logits(runner, prompt, stream):
         tok = np.zeros((b, runner.prompt_bucket_for(len(seq))),
                        np.float32)
         tok[0, :len(seq)] = seq
+        length = np.zeros(b, np.float32)
+        length[0] = len(seq)
         logits, kv = runner.prefill(
             tok, np.zeros(b, np.float32),
-            np.full(b, runner.scratch_slot, np.float32), kv)
-        out.append(logits[0, len(seq) - 1])
+            np.full(b, runner.scratch_slot, np.float32), kv, length)
+        out.append(np.asarray(logits[0, 0]))
     return np.stack(out)
 
 

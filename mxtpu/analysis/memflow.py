@@ -632,9 +632,10 @@ def generate_record(runner, target: str = "generate",
             else f"prefill_b{shp[0]}_s{shp[1]}"
         text, mem = runner.program_artifact(bucket)
         mem = mem or {}
-        # the state is the LAST data operand of every entry
-        kv_argnum = (2 if kind == "decode" else 3) \
-            + (1 if runner.last_logits_only else 0)
+        # the state is the LAST data operand of every entry, as the
+        # runner donates it: behind the rows, which hold a ``length``
+        # where the program takes one
+        kv_argnum = len(runner._structs(bucket)) - 1
         programs[name] = {
             "mem": mem,
             "collective_scratch": collective_scratch_bytes(text),

@@ -55,7 +55,7 @@ from .faults import (CorruptEntry, CrashAt, Corrupt, Fault, FaultPlan,
                      Hang, QueueWedge, ReadOnlyDir, SlowExec,
                      SlowStart, SlowStartError, StaleKey,
                      TruncateEntry, WorkerCrashed)
-from .generate import (GenerateBatcher, GenerateRequest,
+from .generate import (DeviceLogits, GenerateBatcher, GenerateRequest,
                        GenerateRunner, StateTable, sample_token)
 from .health import WorkerHealth, WorkerState
 from .router import (FleetGenerateRequest, FleetRequest, FleetRouter,
@@ -69,7 +69,7 @@ __all__ = ["ModelRunner", "InferenceServer", "DynamicBatcher",
            "RequestTimeout", "RetriableError", "WorkerLost",
            "batch_ladder",
            "GenerateRunner", "GenerateBatcher", "GenerateRequest",
-           "StateTable", "sample_token",
+           "StateTable", "DeviceLogits", "sample_token",
            "FleetRouter", "FleetWorker", "FleetRequest",
            "FleetGenerateRequest",
            "WorkerHealth", "WorkerState",

@@ -15,7 +15,9 @@ Three pieces:
   dtype — keys and values by position beside recurrent state of fixed
   size); every table is allocated, donated, threaded and handed back
   together, and such a graph also takes each row's number of valid new
-  tokens and returns one row of logits a lane.  The
+  tokens.  Either way a prefill call hands back what a decode call
+  does: one row of logits a lane, left on the device
+  (:class:`DeviceLogits`).  The
   graph threads the whole table through its layers: layer i's
   ``kv_cache_write`` (a decode step's one token a lane: one Pallas
   kernel that stores the column, ``mxtpu.kernels.kv_write``, where the
@@ -48,10 +50,12 @@ Three pieces:
   capacity) and deadline-expired requests.  Deterministic in sync
   mode — fake-clock tests drive it step by step.
 
-Sampling is host-side and replay-deterministic: greedy argmax, or
-top-k seeded by ``(seed, absolute_position)`` — the same token ids
-come out across runs AND across a mid-stream worker steal, because a
-replayed request resumes at the same absolute positions.
+Sampling is replay-deterministic: a greedy lane takes its row's first
+maximum, found on the device after a prefill as after a decode step;
+a top-k draw is made on the host, seeded by ``(seed,
+absolute_position)`` — the same token ids come out across runs AND
+across a mid-stream worker steal, because a replayed request resumes
+at the same absolute positions.
 """
 from __future__ import annotations
 
@@ -119,14 +123,15 @@ def sample_token(logits, *, position: int, seed: int = 0,
 
 
 class DeviceLogits:
-    """A decode step's logits ``(slots, 1, V)`` as ``decode`` hands them
-    back: left on the device, beside each slot's first maximum, found
-    there and brought over — 4 bytes a slot where a row is 400 KB at a
-    vocabulary of 100,352.  Indexed as the host array would be,
-    ``logits[slot, 0]`` is a row that ``sample_token`` draws greedily
-    from without touching its numbers; whoever wants the numbers
-    (``np.asarray`` of the whole or of a row: a top-k draw, a test)
-    brings all of them over, once."""
+    """A call's logits ``(rows, 1, V)`` as ``prefill`` and ``decode``
+    hand them back — a prefill's row is its prompt's last valid
+    position, a decode step's its slot's one new position: left on the
+    device, beside each row's first maximum, found there and brought
+    over — 4 bytes a row where a row is 400 KB at a vocabulary of
+    100,352.  Indexed as the host array would be, ``logits[row, 0]`` is
+    a row that ``sample_token`` draws greedily from without touching
+    its numbers; whoever wants the numbers (``np.asarray`` of the whole
+    or of a row: a top-k draw, a test) brings all of them over, once."""
 
     class Row:
         __slots__ = ("first_maximum", "_of", "_slot")
@@ -142,6 +147,10 @@ class DeviceLogits:
     def __init__(self, rows, first_maximum: np.ndarray):
         self.rows, self.first_maximum = rows, first_maximum
         self._host = None
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return tuple(self.rows.shape)
 
     def host(self) -> np.ndarray:
         """The numbers, brought over at the first call and kept."""
@@ -228,13 +237,15 @@ class GenerateRunner:
         An incremental export (``HybridBlock.export`` of a model
         called in incremental mode).  With one KV table: inputs
         ``(tokens, step, cache)``, outputs ``(logits, new_cache)``,
-        the cache laid out ``(num_layers, 2, B, heads, L, head_dim)``
-        — exactly what ``TransformerModel.kv_cache_spec`` /
+        ``logits`` over every position ``(B, S, V)`` (the prefill
+        program keeps each row's last valid one), the cache laid out
+        ``(num_layers, 2, B, heads, L, head_dim)`` — exactly what
+        ``TransformerModel.kv_cache_spec`` /
         ``BERTModel.kv_cache_spec`` describe.  With a state spec:
         inputs ``(tokens, step, length, *tables)``, outputs ``(logits,
         *tables)``; ``length`` (B,) is each row's number of valid new
         tokens, and ``logits`` is ``(B, 1, V)``, one row a lane at its
-        last valid position (``last_logits_only``).
+        last valid position.
     params : dict name -> numpy/NDArray
         Trained weights (uploaded once, shared by every executable).
     kv_spec : tuple
@@ -448,16 +459,17 @@ class GenerateRunner:
         if self._cache is not None:
             self._fingerprint = self._model_fingerprint()
 
-    @property
-    def last_logits_only(self) -> bool:
-        """A graph with a state spec is told each row's valid length
-        and hands back one row of logits a lane, ``(b, 1, V)``."""
-        return not self._one_table
+    def _takes_length(self, kind: str) -> bool:
+        """Whether the program of ``kind`` takes each row's number of
+        valid tokens: a graph with a state spec is told it in both
+        kinds; a one-table graph never is, and its prefill program
+        alone takes it, to keep each row's last valid position."""
+        return kind == "prefill" or not self._one_table
 
-    def _rows(self, tokens, step, length):
-        """The row inputs the graph takes."""
-        return (tokens, step) if self._one_table \
-            else (tokens, step, length)
+    def _rows(self, kind: str, tokens, step, length):
+        """The row inputs the program of ``kind`` takes."""
+        return (tokens, step, length) if self._takes_length(kind) \
+            else (tokens, step)
 
     @staticmethod
     def _as_np(v):
@@ -639,15 +651,18 @@ class GenerateRunner:
         return outs[0].data, tuple(o.data for o in outs[1:])
 
     def _prefill_pure(self):
-        """(tokens (b,s), step (b,), [length (b,),] lane_idx (b,),
-        state, params) -> (logits, state').  Gather-extend-write: each
-        row's lane is pulled from every slot table, extended by its
-        tokens at its own step offset, and written back — so chunked
-        prefill of a long prompt+prefix is just repeated calls at
-        advancing step offsets.  Padding rows target the scratch slot.
-        One KV table: logits are (b,s,V) and the lanes go back by one
-        indexed update.  A state spec: logits are (b,1,V) and each
-        table's rows come out one lane at a time
+        """(tokens (b,s), step (b,), length (b,), lane_idx (b,),
+        state, params) -> (logits (b,1,V), state').  Gather-extend-
+        write: each row's lane is pulled from every slot table,
+        extended by its tokens at its own step offset, and written
+        back — so chunked prefill of a long prompt+prefix is just
+        repeated calls at advancing step offsets.  Padding rows target
+        the scratch slot.  The logits are each row's last valid
+        position (a row of length 0 gives a row nobody reads).  One KV
+        table: the graph makes (b,s,V), of which the program keeps
+        that row, and the lanes go back by one indexed update.  A
+        state spec: the graph is told ``length`` and makes (b,1,V)
+        itself, and each table's rows come out one lane at a time
         (``read_whole_lanes``) and go back one lane at a time, in place
         (``write_whole_lanes``), so no program holds a second copy of
         a table whose lanes are megabytes each."""
@@ -660,11 +675,15 @@ class GenerateRunner:
             with jax.named_scope("gen/prefill_program"):
                 idx = lane_idx.astype(jnp.int32)
                 if self._one_table:
+                    tokens, step, length = rows
                     kv_small = state[:, :, idx]
                     logits, (new_small,) = self._eval_incremental(
-                        rows, (kv_small,), param_vals)
-                    return logits, state.at[:, :, idx].set(
-                        new_small.astype(state.dtype))
+                        (tokens, step), (kv_small,), param_vals)
+                    b, s = tokens.shape
+                    last = jnp.clip(length.astype(jnp.int32) - 1, 0, s - 1)
+                    return logits[jnp.arange(b), last][:, None, :], \
+                        state.at[:, :, idx].set(
+                            new_small.astype(state.dtype))
                 axes = [t.lane_axis for t in self.state_spec]
                 logits, new = self._eval_incremental(
                     rows, tuple(read_whole_lanes(t, idx, a)
@@ -718,7 +737,7 @@ class GenerateRunner:
         else:
             raise MXNetError(
                 f"generate: unknown executable kind {kind!r}")
-        if self.last_logits_only:
+        if self._takes_length(kind):
             rows += (sds((n,)),)                  # length
         if kind == "prefill":
             rows += (sds((n,)),)                  # lane_idx
@@ -849,28 +868,29 @@ class GenerateRunner:
 
     def prefill(self, tokens: np.ndarray, step: np.ndarray,
                 lane_idx: np.ndarray, kv, length=None
-                ) -> Tuple[np.ndarray, Any]:
+                ) -> Tuple["DeviceLogits", Any]:
         """One prefill dispatch on already-bucketed host arrays:
         ``tokens (b, s)`` / ``step (b,)`` / ``lane_idx (b,)`` must
         match a ladder rung exactly (the batcher pads); ``length
         (b,)`` is each row's number of valid tokens (all ``s`` if not
-        given).  Returns (host logits — (b, s, V), or (b, 1, V) at each
-        row's last valid position where ``last_logits_only`` — and the
-        new device state); the passed state is consumed (donated on
-        accelerator backends)."""
+        given).  Returns what ``decode`` returns: (the logits (b, 1, V)
+        of each row's last valid position as a :class:`DeviceLogits`,
+        left on the device with each row's first maximum brought over,
+        and the new device state); the passed state is consumed
+        (donated on accelerator backends).  Whoever wants every
+        position's logits has the graph itself."""
         b, s = tokens.shape
         if length is None:
             length = np.full((b,), s, np.float32)
         fresh = int(np.count_nonzero((length > 0) & (step == 0)))
         if self._obs:
             self._m_resets.inc(fresh)
-        return self._call(obs.SPAN_PREFILL_CALL, ("prefill", (b, s)),
-                          self._rows(tokens, step, length) + (lane_idx,),
-                          kv,
-                          {"rows": b, "bucket": s,
-                           "tokens": int(np.sum(length)),
-                           "resets": fresh,
-                           "lane_bytes": b * self._lane_bytes})
+        return self._call(
+            obs.SPAN_PREFILL_CALL, ("prefill", (b, s)),
+            self._rows("prefill", tokens, step, length) + (lane_idx,),
+            kv,
+            {"rows": b, "bucket": s, "tokens": int(np.sum(length)),
+             "resets": fresh, "lane_bytes": b * self._lane_bytes})
 
     def decode(self, tokens: np.ndarray, step: np.ndarray, kv,
                length=None) -> Tuple["DeviceLogits", Any]:
@@ -886,16 +906,16 @@ class GenerateRunner:
             length = np.ones((self._slots,), np.float32)
         on = length > 0
         return self._call(obs.SPAN_DECODE, ("decode", (self._slots,)),
-                          self._rows(tokens, step, length), kv,
+                          self._rows("decode", tokens, step, length), kv,
                           {"slots": self._slots, "active": int(on.sum()),
                            "context_tokens": int(step[on].sum())})
 
     @staticmethod
     def _first_maximum_of(entry, logits):
-        """The executable that finds each slot's first maximum of
-        ``logits (slots, 1, V)`` on the device, ``(slots,)`` int32;
+        """The executable that finds each row's first maximum of
+        ``logits (rows, 1, V)`` on the device, ``(rows,)`` int32;
         built at the entry's first run (a set-up's first run of the
-        decode program, never a token's)."""
+        program, never a token's)."""
         import jax
         import jax.numpy as jnp
         fn = entry.get("first_maximum")
@@ -907,12 +927,13 @@ class GenerateRunner:
 
     def _call(self, name: str, bucket: Tuple,
               host_rows: Sequence[np.ndarray], kv,
-              counts: Dict[str, int]) -> Tuple[Any, Any]:
+              counts: Dict[str, int]) -> Tuple["DeviceLogits", Any]:
         """One executable call, in the region ``name`` with its three
-        children: the host rows staged on the device, the call itself,
-        and what comes back — a prefill's logits, a decode step's first
-        maxima (``logits_bytes`` counts the logits the call made,
-        ``fetched_bytes`` what the fetch brought over)."""
+        children: the host rows staged on the device, the call itself
+        with the search for each row's first maximum behind it, and
+        what comes back — those maxima (``logits_bytes`` counts the
+        logits the call made, ``fetched_bytes`` what the fetch brought
+        over: 4 bytes a row)."""
         import jax
         with self._region(name, **counts) as rg:
             entry = self._entry(bucket)
@@ -927,20 +948,13 @@ class GenerateRunner:
                     guards.no_implicit_transfers(self._guards):
                 logits, kv = entry["compiled"](*staged, kv,
                                                self._param_vals)
-                first = self._first_maximum_of(entry, logits)(logits) \
-                    if bucket[0] == "decode" else None
-            made = logits.nbytes
+                first = self._first_maximum_of(entry, logits)(logits)
             with self._region(name + obs.SPAN_FETCH):
-                if first is None:
-                    # mxlint: sync-point — deliberate D2H: the batcher samples a prefill's first tokens on host
-                    logits = fetched = np.asarray(logits)
-                else:
-                    # mxlint: sync-point — deliberate D2H: token ids only
-                    fetched = np.asarray(first)
-                    logits = DeviceLogits(logits, fetched)
-            rg.set(logits_bytes=made, fetched_bytes=fetched.nbytes,
+                # mxlint: sync-point — deliberate D2H: token ids only
+                first = np.asarray(first)
+            rg.set(logits_bytes=logits.nbytes, fetched_bytes=first.nbytes,
                    kv_kernel_writes=entry["kv_kernel_writes"])
-        return logits, kv
+        return DeviceLogits(logits, first), kv
 
     # -- introspection / contracts ----------------------------------------
     def default_bucket(self, kind: str = "decode") -> Tuple:
@@ -1357,7 +1371,7 @@ class GenerateBatcher:
                                      if r.trace_id is not None]):
             if self._kv is None:
                 self._kv = runner.new_cache()
-            first_logits: List[Optional[np.ndarray]] = \
+            first_logits: List[Optional[DeviceLogits.Row]] = \
                 [None] * len(pairs)
             for c in range(chunks):
                 base = c * s
@@ -1381,9 +1395,9 @@ class GenerateBatcher:
                 for row in range(len(pairs)):
                     last = need[row] - 1
                     if base <= last < base + s:
-                        first_logits[row] = logits[
-                            row, 0 if runner.last_logits_only
-                            else last - base]
+                        # the chunk that holds the prompt's end: its
+                        # call kept that position's row for this lane
+                        first_logits[row] = logits[row, 0]
             with self._cond:
                 if self._closed:
                     # the batcher died between admit and commit: these

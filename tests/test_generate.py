@@ -25,8 +25,8 @@ from mxtpu.cache import ExecutableCache
 from mxtpu.gluon.block import HybridBlock
 from mxtpu.models.transformer import BERTModel, TransformerModel
 from mxtpu.ops.registry import get_op
-from mxtpu.serving import (FleetGenerateRequest, FleetRouter,
-                           FleetWorker, GenerateBatcher,
+from mxtpu.serving import (DeviceLogits, FleetGenerateRequest,
+                           FleetRouter, FleetWorker, GenerateBatcher,
                            GenerateRunner, InferenceServer,
                            RequestTimeout, ServerBusy, WorkerLost,
                            sample_token)
@@ -706,7 +706,8 @@ def _write_by_slice_and_stack(table, new, step, layer=0, plane=0):
 
 
 def _serve_by_hand(r):
-    """Every logits array and the final table of: two lanes prefilled
+    """Every logits array (a prefill's: each row's last position) and
+    the final table of: two lanes prefilled
     together, lane 0's prompt continued by a second chunk, two decode
     steps at frontiers (8, 3), lane 1 handed to a new prompt (its stale
     rows stay), three more decode steps at (10, 4)."""
@@ -718,7 +719,8 @@ def _serve_by_hand(r):
         nonlocal kv
         logits, kv = r.prefill(np.array(tokens, f32), np.array(step, f32),
                                np.array(lanes, f32), kv)
-        out.append(logits)
+        assert logits.shape == (len(tokens), 1, V)
+        out.append(np.asarray(logits))
 
     def decode(tokens, step):
         nonlocal kv
@@ -770,6 +772,135 @@ def test_in_place_table_equals_slice_and_stack(any_export, any_runner,
     np.testing.assert_allclose(got_kv, want_kv, rtol=1e-6, atol=1e-6)
     # the scratch slot aside, the two lanes did get rows
     assert np.abs(got_kv[:, :, :LANES, :, :10]).sum() > 0
+
+
+# ------------------- a prefill hands back one row a prompt (ISSUE 33)
+
+def _whole_logits_prefill(r):
+    """The one-table prefill program as it was before ISSUE 33, kept as
+    the reference: the graph's logits over every position, ``(b, s,
+    V)``, brought to the host whole, beside the new table."""
+    def fn(tokens, step, lanes, state, params):
+        idx = lanes.astype(jnp.int32)
+        logits, (new,) = r._eval_incremental(
+            (tokens, step), (state[:, :, idx],), params)
+        return logits, state.at[:, :, idx].set(new.astype(state.dtype))
+
+    jitted = jax.jit(fn)
+
+    def call(tokens, step, lanes, kv):
+        logits, kv = jitted(*(jnp.asarray(a, jnp.float32)
+                              for a in (tokens, step, lanes)),
+                            kv, r._param_vals)
+        return np.asarray(logits), kv
+
+    return call
+
+
+# (tokens, step, length, lanes) of each call; 2 is the scratch slot
+_LAST_ROW_CASES = {
+    "full_rows": [([[3, 7, 1, 4], [5, 2, 6, 9]], [0, 0], [4, 4], [0, 1])],
+    "short_rows": [([[3, 7, 0, 0], [5, 2, 6, 0]], [0, 0], [2, 3], [1, 0])],
+    "padding_row": [([[3, 7, 1, 0], [0, 0, 0, 0]], [0, 0], [3, 0],
+                     [1, LANES])],
+    "two_chunks": [([[3, 7, 1, 4], [5, 2, 0, 0]], [0, 0], [4, 2], [0, 1]),
+                   ([[9, 8, 2, 0], [0, 0, 0, 0]], [4, 0], [3, 0],
+                    [0, LANES])],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAST_ROW_CASES))
+def test_prefill_returns_each_rows_last_valid_position(any_runner, case):
+    """``prefill`` hands back what ``decode`` does: a ``DeviceLogits``
+    over ``(b, 1, V)`` whose row is, bit for bit, the row of the
+    graph's whole logits at the prompt's last valid position — for a
+    full row, a short one, a prompt continued by a second call, and
+    beside a padding row on the scratch slot (any finite row) — with
+    each row's first maximum found on the device, and the table the
+    whole-logits program leaves."""
+    r = any_runner
+    whole = _whole_logits_prefill(r)
+    kv, ref_kv = r.new_cache(), r.new_cache()
+    f32 = np.float32
+    for tokens, step, length, lanes in _LAST_ROW_CASES[case]:
+        got, kv = r.prefill(np.array(tokens, f32), np.array(step, f32),
+                            np.array(lanes, f32), kv,
+                            np.array(length, f32))
+        want, ref_kv = whole(tokens, step, lanes, ref_kv)
+        assert isinstance(got, DeviceLogits) and got._host is None
+        assert got.shape == (len(tokens), 1, V)
+        assert want.shape == (len(tokens), 4, V)
+        assert got.first_maximum.dtype == np.int32
+        host = np.asarray(got)
+        assert np.isfinite(host).all()
+        for row, n in enumerate(length):
+            if n == 0:
+                continue            # a row nobody reads
+            assert np.array_equal(host[row, 0], want[row, n - 1]), row
+            assert got[row, 0].first_maximum == \
+                int(np.argmax(want[row, n - 1]))
+        np.testing.assert_array_equal(np.asarray(kv), np.asarray(ref_kv))
+
+
+def test_prefill_without_a_length_keeps_the_last_position(any_runner):
+    """A caller that gives no ``length`` (the benchmark's warm-up) has
+    rows that are valid to their end."""
+    r = any_runner
+    tokens = np.array([[3, 7, 1, 4]], np.float32)
+    got, _ = r.prefill(tokens, np.zeros(1, np.float32),
+                       np.zeros(1, np.float32), r.new_cache())
+    want, _ = _whole_logits_prefill(r)(tokens, [0], [0], r.new_cache())
+    assert np.array_equal(np.asarray(got)[0, 0], want[0, 3])
+
+
+def _serve_from_whole_logits(r, prompt, n, *, top_k, seed, prefix=()):
+    """One request's stream by the path the batcher took before ISSUE
+    33: the prompt (and a replay's prefix) prefilled in chunks by the
+    whole-logits program, the first token drawn on the host from
+    ``logits[row, last]``, every later one from a decode step's
+    numbers."""
+    whole = _whole_logits_prefill(r)
+    kv, full = r.new_cache(), list(prompt) + list(prefix)
+    s = r.prompt_bucket_for(len(full))
+    for base in range(0, len(full), s):
+        tokens = np.zeros((1, s), np.float32)
+        valid = min(s, len(full) - base)
+        tokens[0, :valid] = full[base:base + valid]
+        logits, kv = whole(tokens, [base], [0], kv)
+    stream = [sample_token(logits[0, valid - 1], position=len(full),
+                           seed=seed, top_k=top_k)]
+    slots = r.max_lanes + 1
+    while len(prefix) + len(stream) < n:
+        tokens = np.zeros((slots, 1), np.float32)
+        step = np.zeros(slots, np.float32)
+        tokens[0, 0], step[0] = stream[-1], len(full) + len(stream) - 1
+        logits, kv = r.decode(tokens, step, kv)
+        stream.append(sample_token(
+            np.asarray(logits)[0, 0], position=len(full) + len(stream),
+            seed=seed, top_k=top_k))
+    return list(prefix) + stream
+
+
+@pytest.mark.parametrize("top_k", [1, 4], ids=["greedy", "top_k4"])
+def test_served_tokens_are_those_drawn_from_whole_logits(runner, top_k):
+    """The replay contract across a prefill: what a batcher serves for
+    seeded requests — a short prompt, one chunked over two calls, a
+    replay that resumes behind a prefix — is what drawing on the host
+    from the whole ``(b, s, V)`` logits gave, greedy and top-k."""
+    asks = [dict(prompt=[1, 2, 3], seed=7),
+            dict(prompt=[4, 5, 6, 7, 8, 9, 10, 11, 12], seed=11),
+            dict(prompt=[5, 6, 7], seed=13, prefix=[9, 3])]
+    want = [_serve_from_whole_logits(runner, a["prompt"], 6, top_k=top_k,
+                                     seed=a["seed"],
+                                     prefix=a.get("prefix", ()))
+            for a in asks]
+    clk = FakeClock()
+    b = _batcher(runner, clk)
+    reqs = [b.submit(a["prompt"], max_tokens=6, top_k=top_k,
+                     seed=a["seed"], prefix=a.get("prefix", ()))
+            for a in asks]
+    _drive(b, clk, *reqs)
+    assert [q.result(0) for q in reqs] == want
 
 
 @pytest.mark.parametrize("T,write", [(3, "loop"), (1, "loop"),

@@ -22,7 +22,7 @@ from mxtpu import nd, obs, profiler
 from mxtpu import symbol as sym_mod
 from mxtpu.models.hybrid import HybridDecoderModel
 from mxtpu.ndarray import rnn_impl
-from mxtpu.serving import GenerateBatcher, GenerateRunner
+from mxtpu.serving import DeviceLogits, GenerateBatcher, GenerateRunner
 
 from benchmark import reference_granite as ref
 from benchmark import weights_granite
@@ -100,10 +100,13 @@ def _prefill_rows(runner, kv, rows, bucket):
             tok[r, :valid] = t[base:base + valid]
             step[r], length[r], lane[r] = base, valid, at
         logits, kv = runner.prefill(tok, step, lane, kv, length)
+        assert isinstance(logits, DeviceLogits)
         assert logits.shape == (b, 1, CFG["vocab_size"])
         for r in range(len(rows)):
             if base <= need[r] - 1 < base + bucket:
-                out[r] = logits[r, 0]
+                assert logits[r, 0].first_maximum == \
+                    np.argmax(np.asarray(logits)[r, 0])
+                out[r] = np.asarray(logits[r, 0])
     return out, kv
 
 
@@ -153,7 +156,11 @@ def test_tables_follow_the_spec(net):
                                                      CAP, 16)
     assert ssm.dtype == conv.dtype == jnp.float32
     assert ssm.shape[1] == conv.shape[1] == LANES + 1
-    assert r.kv_spec == (2, 2, LANES, 2, CAP, 16) and r.last_logits_only
+    assert r.kv_spec == (2, 2, LANES, 2, CAP, 16)
+    # both kinds of program are told each row's valid length
+    assert [len(r._structs(b)) for b in (("prefill", (1, 4)),
+                                         r.default_bucket("decode"))] \
+        == [5, 4]
     assert [t.name for t in r.state_spec] == ["kv", "ssm", "conv"]
     series = obs.snapshot()["mxtpu_gen_state_bytes"]["series"]
     got = {v["labels"]["table"]: int(v["value"]) for v in series}
@@ -168,8 +175,9 @@ def test_tables_follow_the_spec(net):
 
 
 def test_a_six_tuple_runner_is_what_it_was(net):
-    """``kv_spec`` as six ints: one float32 table, whole logits, the
-    three-input graph."""
+    """``kv_spec`` as six ints: one float32 table and the three-input
+    graph, whose prefill program alone is told each row's length and
+    keeps that position's row of the graph's whole logits."""
     from mxtpu.models.transformer import BERTModel
     bert = BERTModel(40, 16, 32, 2, 2, max_length=16, dropout=0.0,
                      use_token_type=False, causal=True)
@@ -181,13 +189,17 @@ def test_a_six_tuple_runner_is_what_it_was(net):
     r = GenerateRunner(sym_mod.Group(list(out)), params,
                        bert.kv_cache_spec(2, 16), prompt_buckets=(4,),
                        cache=None)
-    assert not r.last_logits_only and len(r.state_spec) == 1
+    assert len(r.state_spec) == 1
+    assert [len(r._structs(b)) for b in (("prefill", (1, 4)),
+                                         r.default_bucket("decode"))] \
+        == [5, 3]
     kv = r.new_cache()
     assert kv.shape == (2, 2, 3, 2, 16, 8) and kv.dtype == jnp.float32
     logits, kv = r.prefill(np.ones((1, 4), np.float32),
                            np.zeros(1, np.float32),
                            np.zeros(1, np.float32), kv)
-    assert logits.shape == (1, 4, 40) and kv.shape == (2, 2, 3, 2, 16, 8)
+    assert isinstance(logits, DeviceLogits)
+    assert logits.shape == (1, 1, 40) and kv.shape == (2, 2, 3, 2, 16, 8)
 
 
 @pytest.mark.parametrize("plen", [1, 5, 8])

@@ -185,19 +185,68 @@ def test_generate_regions_land_in_the_xplane(runner, tmp_path):
     assert (pre["rows"], pre["rung"], pre["bucket"], pre["chunks"]) == \
         (2, 2, 4, 1)
     vocab_bytes = 4 * V
+    # either call makes one row of logits a row, which stays on the
+    # device, and a token id a row crosses
     assert s.named("gen/prefill/call/done")[0][3]["logits_bytes"] == \
-        2 * 4 * vocab_bytes
+        2 * vocab_bytes
     assert s.named("gen/decode/done")[0][3]["logits_bytes"] == \
         (LANES + 1) * vocab_bytes
-    # a prefill's logits come over whole; a decode step's stay on the
-    # device and a token id a slot crosses
     assert s.named("gen/prefill/call/done")[0][3]["fetched_bytes"] == \
-        2 * 4 * vocab_bytes
+        2 * 4
     assert s.named("gen/decode/done")[0][3]["fetched_bytes"] == \
         (LANES + 1) * 4
     assert s.named("gen/decode")[0][3]["slots"] == LANES + 1
     steps = [e[3]["step"] for e in s.named("gen/step")]
     assert steps == list(range(1, len(steps) + 1))
+
+
+@pytest.fixture(scope="module")
+def state_spec_runner():
+    """A tiny hybrid decoder: a graph with a state spec, which is told
+    each row's length and makes ``(b, 1, V)`` itself."""
+    from mxtpu import symbol as sym_mod
+    from mxtpu.models.hybrid import HybridDecoderModel
+    net = HybridDecoderModel.from_config({
+        "vocab_size": V + 3, "hidden_size": 32,
+        "shared_intermediate_size": 64,
+        "layer_types": ["mamba", "attention"],
+        "num_attention_heads": 2, "num_key_value_heads": 1,
+        "mamba_n_heads": 2, "mamba_d_head": 16, "mamba_d_state": 8,
+        "mamba_d_conv": 4, "mamba_chunk_size": 4, "mamba_n_groups": 1,
+        "rms_norm_eps": 1e-5, "embedding_multiplier": 12,
+        "residual_multiplier": 0.22, "attention_multiplier": 0.0625,
+        "logits_scaling": 8, "num_local_experts": 0,
+        "position_embedding_type": "nope"})
+    net.initialize()
+    out = net(*[sym_mod.var(f"data{i}") for i in range(6)])
+    params = {p.name: p.data() for p in net.collect_params().values()}
+    r = GenerateRunner(sym_mod.Group(list(out)), params,
+                       net.state_spec(LANES, L), prompt_buckets=(4, 8),
+                       cache=None)
+    r.warmup()
+    return r
+
+
+@pytest.mark.parametrize("graph", ["one_table", "state_spec"])
+def test_a_prefill_call_fetches_four_bytes_a_row(graph, request, tmp_path):
+    """Whichever kind of graph: every ``gen/prefill/call`` — a rung of
+    two, a rung of one, the second chunk of a long prompt — makes one
+    row of logits a row and brings over its first maximum, 4 bytes."""
+    r = request.getfixturevalue(
+        "runner" if graph == "one_table" else "state_spec_runner")
+    vocab = V if graph == "one_table" else V + 3
+    with _Session(tmp_path) as s:
+        streams, _ = _serve(r, prompts=((1, 2, 3), (4, 5, 6, 7)))
+        more, _ = _serve(r, prompts=(tuple(range(1, 10)),))
+    assert all(len(t) == 4 for t in streams + more)
+    calls = s.named("gen/prefill/call")
+    done = s.named("gen/prefill/call/done")
+    assert [c[3]["rows"] for c in calls] == [2, 1, 1]
+    assert len(done) == len(calls)
+    for call, end in zip(calls, done):
+        assert s.parent_of(end) == "gen/prefill/call"
+        assert end[3]["fetched_bytes"] == 4 * call[3]["rows"]
+        assert end[3]["logits_bytes"] == call[3]["rows"] * vocab * 4
 
 
 def test_gen_prefill_has_its_real_length(runner, tmp_path):

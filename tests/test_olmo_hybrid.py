@@ -26,7 +26,7 @@ from mxtpu import symbol as sym_mod
 from mxtpu.models.hybrid import (GatedDeltaNetMixer, HybridDecoderModel,
                                  olmo_hybrid_7b)
 from mxtpu.ndarray import rnn_impl
-from mxtpu.serving import GenerateBatcher, GenerateRunner
+from mxtpu.serving import DeviceLogits, GenerateBatcher, GenerateRunner
 
 from benchmark import reference_olmo_hybrid as ref
 from benchmark import weights_olmo_hybrid
@@ -117,10 +117,13 @@ def _prefill_rows(runner, kv, rows, bucket):
             tok[r, :valid] = t[base:base + valid]
             step[r], length[r], lane[r] = base, valid, at
         logits, kv = runner.prefill(tok, step, lane, kv, length)
+        assert isinstance(logits, DeviceLogits)
         assert logits.shape == (b, 1, CFG["vocab_size"])
         for r in range(len(rows)):
             if base <= need[r] - 1 < base + bucket:
-                out[r] = logits[r, 0]
+                assert logits[r, 0].first_maximum == \
+                    np.argmax(np.asarray(logits)[r, 0])
+                out[r] = np.asarray(logits[r, 0])
     return out, kv
 
 
@@ -620,7 +623,7 @@ def test_a_decode_step_brings_back_token_ids_not_logits(runner, weights):
     A batcher's streams, greedy and top-k lanes side by side, are what
     they were (and the greedy ones are held against the reference
     above)."""
-    from mxtpu.serving.generate import DeviceLogits, sample_token
+    from mxtpu.serving.generate import sample_token
     seq = _prompt(9, salt=8)
     (_,), kv = _prefill_rows(runner, runner.new_cache(), [(1, seq[:8])], 8)
     slots = runner.max_lanes + 1
