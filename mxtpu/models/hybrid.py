@@ -1,13 +1,14 @@
-"""Hybrid recurrent / attention decoders: the ``GraniteMoeHybrid``
-family with no routed experts (IBM granite-4.0-h) and the ``OlmoHybrid``
-family (Ai2 Olmo-Hybrid).
+"""Hybrid decoders: recurrent or windowed layers beside full attention,
+dense or routed feed-forward — the ``GraniteMoeHybrid`` family with no
+routed experts (IBM granite-4.0-h), the ``OlmoHybrid`` family (Ai2
+Olmo-Hybrid) and the ``mellum`` family (JetBrains Mellum 2: sliding and
+full attention, every feed-forward a layer of routed experts).
 
 A decoder whose layer pattern is read from a list.  Every layer is a
-mixer and a gated (SwiGLU) MLP round a residual stream, with RMS norms
-placed by ``layout``: ``"pre"`` is ``x + r * f(norm(x))`` (granite, with
-a residual multiplier ``r``), ``"post"`` is ``x + r * norm(f(x))``, the
-norm on the sublayer's output (OLMo 2's reordered norm).  The mixer is
-one of
+mixer and a feed-forward round a residual stream, with RMS norms placed
+by ``layout``: ``"pre"`` is ``x + r * f(norm(x))`` (with a residual
+multiplier ``r``), ``"post"`` is ``x + r * norm(f(x))``, the norm on
+the sublayer's output (OLMo 2's reordered norm).  The mixer is one of
 
 * a Mamba-2 block (``"mamba"``; Dao & Gu 2024, arXiv:2405.21060:
   depthwise causal convolution, selective state-space scan, gated RMS
@@ -16,9 +17,21 @@ one of
   Hatamizadeh 2024, arXiv:2412.06464: depthwise causal convolution, the
   gated delta rule on a matrix of state a head, RMS norm a head, then
   the gate);
-* grouped-query attention with no position signal at all
-  (``"attention"`` / ``"full_attention"``), with or without an RMS norm
-  of queries and keys (``qk_norm``).
+* grouped-query attention over the whole prefix (``"attention"`` /
+  ``"full_attention"``) or over a window of it
+  (``"sliding_attention"``: a query reads itself and the ``window - 1``
+  positions before it, and the layer's keys and values live on a ring
+  of ``window + the largest chunk`` columns, not on ``max_len``).
+  Position comes from nowhere (granite, Olmo: the recurrent layers
+  carry it) or from a rotation of queries and keys by absolute position
+  (``rope``: one frequency table a kind of layer, plain or YaRN), keys
+  rotated before they are cached; queries and keys may pass an RMS norm
+  first, over all heads' outputs (``qk_norm="all"``, OLMo 2) or over
+  each head's own (``"head"``).
+
+The feed-forward is a gated (SwiGLU) MLP or, with ``num_experts``, a
+layer of routed SwiGLU experts: softmax router, ``experts_per_token`` a
+token, no capacity and no dropped token (``routed_experts``).
 
 The embedding is tied to the output head or not
 (``tie_embeddings``), and both may be scaled (``embedding_multiplier``,
@@ -26,20 +39,24 @@ The embedding is tied to the output head or not
 
 The model is served, so it has ONE signature, the incremental one:
 
-    logits, kv, rec, conv = net(tokens, step, length, kv, rec, conv)
+    logits, *tables[, counts] = net(tokens, step, length, *tables)
 
 ``tokens`` (B, T) are the T new tokens of each lane, of which row b's
 first ``length_b`` are valid; ``step`` (B,) is each lane's frontier (0:
-the lane starts from zero state whatever it held).  The three state
-tables are what :meth:`HybridDecoderModel.state_spec` declares: ``kv``
-holds the attention layers' keys and values by position; ``rec`` (named
-``ssm`` with Mamba layers, ``delta`` with Gated DeltaNet layers) and
-``conv`` hold the recurrent layers' state, whose size does not depend
-on the context.  All are threaded whole through the layers and written
-in place (``kv_cache_write``, ``ssm_conv``, ``ssm_scan``,
-``delta_rule``).  ``logits`` is (B, 1, V): one row a lane, at its last
-valid position.  A full forward over a sequence is the same call with
-``step`` 0 and fresh tables.
+the lane starts from zero state whatever it held).  The state tables
+are what :meth:`HybridDecoderModel.state_spec` declares, in its order:
+``kv`` holds the full-attention layers' keys and values by position;
+``kv_win`` the sliding layers' on their ring; ``rec`` (named ``ssm``
+with Mamba layers, ``delta`` with Gated DeltaNet layers) and ``conv``
+hold the recurrent layers' state, whose size does not depend on the
+context.  A model declares the tables its kinds of layer need and no
+other.  All are threaded whole through the layers and written in place
+(``kv_cache_write``, ``ssm_conv``, ``ssm_scan``, ``delta_rule``).
+``logits`` is (B, 1, V): one row a lane, at its last valid position.
+With routed experts a last output ``counts`` (1,) int32 follows the
+tables: experts given a token, summed over the layers
+(:meth:`HybridDecoderModel.counter_spec`).  A full forward over a
+sequence is the same call with ``step`` 0 and fresh tables.
 """
 from __future__ import annotations
 
@@ -47,7 +64,8 @@ from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 
-__all__ = ["RMSNorm", "GatedMLP", "GroupedQueryAttention", "Mamba2Mixer",
+__all__ = ["RMSNorm", "GatedMLP", "SparseMLP", "GroupedQueryAttention",
+           "Mamba2Mixer",
            "GatedDeltaNetMixer", "HybridDecoderLayer", "HybridDecoderModel",
            "granite_4_0_h_micro", "olmo_hybrid_7b"]
 
@@ -89,20 +107,57 @@ class GatedMLP(HybridBlock):
         return self.w_out(g * F.sigmoid(g) * v)
 
 
+class SparseMLP(HybridBlock):
+    """``num_experts`` SwiGLU experts of ``hidden_size``, of which each
+    token takes ``top_k`` by a softmax router (renormalised over the
+    chosen where ``norm_topk``); no bias, no shared expert, no capacity:
+    no token is dropped (``routed_experts``).  Leaves stacked on the
+    expert axis: ``router`` (units, E), ``w_in`` (E, units, 2 hidden)
+    gate over up, ``w_out`` (E, hidden, units).  Takes the rows'
+    ``length`` (padded tokens are routed nowhere) and hands back the
+    count of experts touched beside the output."""
+
+    def __init__(self, units, hidden_size, num_experts, top_k,
+                 norm_topk=True, **kwargs):
+        super().__init__(**kwargs)
+        self._attrs = {"top_k": int(top_k), "norm_topk": bool(norm_topk)}
+        self.router = self.params.get(
+            "router", shape=(units, num_experts), init="normal")
+        self.w_in = self.params.get(
+            "w_in", shape=(num_experts, units, 2 * hidden_size),
+            init="normal")
+        self.w_out = self.params.get(
+            "w_out", shape=(num_experts, hidden_size, units), init="normal")
+
+    def hybrid_forward(self, F, x, length, router=None, w_in=None,
+                       w_out=None):
+        return F.routed_experts(x, router, w_in, w_out, length,
+                                **self._attrs)
+
+
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with ``num_heads`` query heads over
-    ``num_kv_heads`` key/value heads, no biases, no positions;
-    ``cache_layer`` names this block's planes of the ``kv`` table.
-    With ``qk_norm_eps`` the projected queries and keys each pass an
-    RMS norm over ALL their heads' outputs before they are cut into
-    heads (OLMo 2's QK-norm; scope ``qk_norm``)."""
+    ``num_kv_heads`` key/value heads, no biases; ``cache_layer`` names
+    this block's planes of its table (``kv``, or ``kv_win`` for a
+    windowed block).  ``qk_norm``: with ``"all"`` the projected queries
+    and keys each pass an RMS norm over ALL their heads' outputs before
+    they are cut into heads (OLMo 2's QK-norm); with ``"head"`` each
+    head's ``head_dim`` outputs are normed alone, one weight of
+    ``head_dim`` for the queries and one for the keys (scope
+    ``qk_norm`` both).  ``rope`` = (frequencies, scale) rotates queries
+    and keys by absolute position (after the norm, keys before they
+    are cached); ``window`` > 0 bounds a query's context and makes the
+    block's table a ring of columns."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 sm_scale, cache_layer=0, qk_norm_eps=None, **kwargs):
+                 sm_scale, cache_layer=0, qk_norm=None, qk_norm_eps=1e-5,
+                 rope=None, window=0, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_heads} query heads do not divide "
                              f"into {num_kv_heads} key/value heads")
+        if qk_norm not in (None, "all", "head"):
+            raise MXNetError(f"unknown QK-norm form {qk_norm!r}")
         self._dims = (num_heads, num_kv_heads, head_dim)
         self._scale = float(sm_scale)
         self._cache_layer = int(cache_layer)
@@ -110,12 +165,20 @@ class GroupedQueryAttention(HybridBlock):
         self.k = _dense(num_kv_heads * head_dim, units)
         self.v = _dense(num_kv_heads * head_dim, units)
         self.o = _dense(units, num_heads * head_dim)
-        self._qk_norm = qk_norm_eps is not None
-        if self._qk_norm:
-            self.q_norm = RMSNorm(num_heads * head_dim, qk_norm_eps,
-                                  scope="qk_norm")
-            self.k_norm = RMSNorm(num_kv_heads * head_dim, qk_norm_eps,
-                                  scope="qk_norm")
+        self._qk_norm = qk_norm
+        if qk_norm:
+            per = 1 if qk_norm == "head" else None
+            self.q_norm = RMSNorm(head_dim * (per or num_heads),
+                                  qk_norm_eps, scope="qk_norm")
+            self.k_norm = RMSNorm(head_dim * (per or num_kv_heads),
+                                  qk_norm_eps, scope="qk_norm")
+        self._rope = None if rope is None else {
+            "inv_freq": tuple(float(f) for f in rope[0]),
+            "scale": float(rope[1])}
+        # attributes a plain block's ops are not given: its graph is the
+        # one it always was
+        self._write = {"ring": True} if window else {}
+        self._read = {"window": int(window)} if window else {}
 
     def hybrid_forward(self, F, x, step, kv):
         hq, hk, d = self._dims
@@ -125,19 +188,29 @@ class GroupedQueryAttention(HybridBlock):
             return F.transpose(F.reshape(t, shape=(0, -1, n, d)),
                                axes=(0, 2, 1, 3))
 
-        k = self.k(x)
-        if self._qk_norm:
-            k = self.k_norm(k)
-        kv = F.kv_cache_write(kv, heads(k, hk), step, layer=at, plane=0)
+        def placed(t, n, norm):
+            """Projected queries or keys, normed, cut into heads and
+            rotated."""
+            if self._qk_norm == "all":
+                t = norm(t)
+            t = heads(t, n)
+            if self._qk_norm == "head":
+                t = norm(t)
+            if self._rope is not None:
+                t = F.rope(t, step, **self._rope)
+            return t
+
+        k_norm, q_norm = (self.k_norm, self.q_norm) if self._qk_norm \
+            else (None, None)
+        kv = F.kv_cache_write(kv, placed(self.k(x), hk, k_norm), step,
+                              layer=at, plane=0, **self._write)
         kv = F.kv_cache_write(kv, heads(self.v(x), hk), step, layer=at,
-                              plane=1)
-        q = self.q(x)
-        if self._qk_norm:
-            q = self.q_norm(q)
+                              plane=1, **self._write)
         out = F.cached_attention(
-            heads(q, hq), F.kv_cache_read(kv, layer=at, plane=0),
+            placed(self.q(x), hq, q_norm),
+            F.kv_cache_read(kv, layer=at, plane=0),
             F.kv_cache_read(kv, layer=at, plane=1), step,
-            sm_scale=self._scale)
+            sm_scale=self._scale, **self._read)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(0, -1, hq * d))
         return self.o(out), kv
@@ -258,44 +331,59 @@ class GatedDeltaNetMixer(HybridBlock):
 
 
 class HybridDecoderLayer(HybridBlock):
-    """A mixer and an MLP round the residual stream.  ``kind`` is
-    ``"attention"`` (the mixer takes ``kv``) or a recurrent kind (it
-    takes ``rec`` and ``conv``); ``layout`` puts each norm on its
+    """A mixer and a feed-forward round the residual stream.
+    ``tables`` names the state tables the mixer takes, in its order
+    (``("kv",)``, ``("kv_win",)``, or the recurrent table and
+    ``"conv"``): the layer is called with those and hands them back,
+    ``(x, *tables)``; a ``SparseMLP`` for ``mlp`` adds its count of
+    experts touched at the end.  ``layout`` puts each norm on its
     sublayer's input (``"pre"``) or on its output (``"post"``)."""
 
-    def __init__(self, kind, mixer, units, hidden_size, residual, eps,
+    def __init__(self, tables, mixer, units, mlp, residual, eps,
                  layout="pre", **kwargs):
         super().__init__(**kwargs)
-        self._kind, self._residual = kind, float(residual)
+        self.tables = tuple(tables)
+        self._attention = len(self.tables) == 1
+        self._residual = float(residual)
         self._post = layout == "post"
+        self.sparse = isinstance(mlp, SparseMLP)
         self.norm1 = RMSNorm(units, eps)
         self.mixer = mixer
         self.norm2 = RMSNorm(units, eps)
-        self.mlp = GatedMLP(units, hidden_size)
+        self.mlp = mlp
 
-    def hybrid_forward(self, F, x, step, length, kv, rec, conv):
+    def hybrid_forward(self, F, x, step, length, *state):
         h = x if self._post else self.norm1(x)
-        if self._kind == "attention":
-            h, kv = self.mixer(h, step, kv)
+        if self._attention:
+            h, *state = self.mixer(h, step, *state)
         else:
-            h, rec, conv = self.mixer(h, step, length, rec, conv)
-        if self._post:
-            x = x + self.norm1(h) * self._residual
-            x = x + self.norm2(self.mlp(x)) * self._residual
+            h, *state = self.mixer(h, step, length, *state)
+        x = x + (self.norm1(h) if self._post else h) * self._residual
+        h = x if self._post else self.norm2(x)
+        counts = ()
+        if self.sparse:
+            h, touched = self.mlp(h, length)
+            counts = (touched,)
         else:
-            x = x + h * self._residual
-            x = x + self.mlp(self.norm2(x)) * self._residual
-        return x, kv, rec, conv
+            h = self.mlp(h)
+        x = x + (self.norm2(h) if self._post else h) * self._residual
+        return (x,) + tuple(state) + counts
 
 
 class HybridDecoderModel(HybridBlock):
     """See the module text.  ``layer_types`` is a list of ``"mamba"``,
-    ``"linear_attention"`` and ``"attention"`` (or ``"full_attention"``);
-    a model has one recurrent kind.  The i-th attention layer owns
-    planes ``[i]`` of ``kv`` and the j-th recurrent layer planes ``[j]``
-    of the recurrent table and of ``conv``."""
+    ``"linear_attention"``, ``"attention"`` (or ``"full_attention"``)
+    and ``"sliding_attention"``; a model has at most one recurrent
+    kind.  The i-th full-attention layer owns planes ``[i]`` of ``kv``,
+    the i-th sliding layer planes ``[i]`` of ``kv_win``, and the j-th
+    recurrent layer planes ``[j]`` of the recurrent table and of
+    ``conv``.  ``rope`` maps a kind of attention layer
+    (``"full_attention"``, ``"sliding_attention"``) to its
+    (frequencies, scale); ``num_experts`` > 0 makes every feed-forward
+    a ``SparseMLP`` of experts ``hidden_size`` wide."""
 
     ATTENTION = ("attention", "full_attention")
+    SLIDING = "sliding_attention"
     RECURRENT = {"mamba": "ssm", "linear_attention": "delta"}
 
     def __init__(self, vocab_size, units, hidden_size, layer_types,
@@ -305,10 +393,13 @@ class HybridDecoderModel(HybridBlock):
                  delta_neg_eigval=False, conv_kernel=4,
                  chunk=256, eps=1e-5, embedding_multiplier=1.0,
                  residual_multiplier=1.0, attention_multiplier=-1.0,
-                 logits_scaling=1.0, layout="pre", qk_norm=False,
+                 logits_scaling=1.0, layout="pre", qk_norm=None,
+                 rope=None, sliding_window=0, num_experts=0,
+                 experts_per_token=0, norm_topk=True,
                  tie_embeddings=True, **kwargs):
         super().__init__(**kwargs)
-        bad = set(layer_types) - set(self.ATTENTION) - set(self.RECURRENT)
+        bad = set(layer_types) - set(self.ATTENTION) - {self.SLIDING} \
+            - set(self.RECURRENT)
         if bad:
             raise MXNetError(f"unknown layer types {sorted(bad)}")
         recurrent = sorted(set(layer_types) & set(self.RECURRENT))
@@ -317,21 +408,26 @@ class HybridDecoderModel(HybridBlock):
                              f"{recurrent}")
         if layout not in ("pre", "post"):
             raise MXNetError(f"unknown block layout {layout!r}")
+        if self.SLIDING in layer_types and sliding_window < 1:
+            raise MXNetError("sliding_attention layers need a "
+                             "sliding_window")
         head_dim = units // num_heads if head_dim is None else head_dim
+        # True is the form the first QK-normed family had
+        qk_norm = {True: "all", False: None}.get(qk_norm, qk_norm)
         self._vocab, self._units = int(vocab_size), int(units)
         self._attn = (num_kv_heads, head_dim)
-        if not recurrent:
-            raise MXNetError("a hybrid decoder has recurrent layers: "
-                             f"{sorted(self.RECURRENT)}")
+        self._window = int(sliding_window)
+        self._top_k = int(experts_per_token) if num_experts else 0
         # the recurrent table's name, a lane's shape in it, and the
         # channels of a lane's convolution window
+        self._rec = None
         if recurrent == ["linear_attention"]:
-            lane = (delta_heads, delta_key_dim, delta_value_dim)
-            channels = delta_heads * (2 * delta_key_dim + delta_value_dim)
-        else:
-            lane = (ssm_heads, ssm_head_dim, ssm_state)
-            channels = ssm_heads * ssm_head_dim + 2 * ssm_state
-        self._rec = (self.RECURRENT[recurrent[0]], lane, channels)
+            self._rec = ("delta",
+                         (delta_heads, delta_key_dim, delta_value_dim),
+                         delta_heads * (2 * delta_key_dim + delta_value_dim))
+        elif recurrent:
+            self._rec = ("ssm", (ssm_heads, ssm_head_dim, ssm_state),
+                         ssm_heads * ssm_head_dim + 2 * ssm_state)
         self._conv_kernel = int(conv_kernel)
         self._embed_scale = float(embedding_multiplier)
         self._logit_scale = 1.0 / float(logits_scaling)
@@ -342,47 +438,66 @@ class HybridDecoderModel(HybridBlock):
         if not self._tied:
             self.head = self.params.get("head", shape=(vocab_size, units),
                                         init="normal")
+        rope = rope or {}
         self.layers = nn.HybridSequential()
-        n_attn = n_rec = 0
+        n_attn = n_win = n_rec = 0
         for kind in self.layer_types:
-            if kind in self.ATTENTION:
-                kind = "attention"
+            if kind in self.ATTENTION or kind == self.SLIDING:
+                sliding = kind == self.SLIDING
                 mixer = GroupedQueryAttention(
                     units, num_heads, num_kv_heads, head_dim,
-                    attention_multiplier, cache_layer=n_attn,
-                    qk_norm_eps=eps if qk_norm else None)
-                n_attn += 1
+                    attention_multiplier,
+                    cache_layer=n_win if sliding else n_attn,
+                    qk_norm=qk_norm, qk_norm_eps=eps,
+                    rope=rope.get(self.SLIDING if sliding
+                                  else "full_attention"),
+                    window=self._window if sliding else 0)
+                if sliding:
+                    tables, n_win = ("kv_win",), n_win + 1
+                else:
+                    tables, n_attn = ("kv",), n_attn + 1
             elif kind == "mamba":
                 mixer = Mamba2Mixer(units, ssm_heads, ssm_head_dim,
                                     ssm_state, conv_kernel, chunk, eps,
                                     cache_layer=n_rec)
+                tables = ("ssm", "conv")
                 n_rec += 1
             else:
                 mixer = GatedDeltaNetMixer(
                     units, delta_heads, delta_key_dim, delta_value_dim,
                     conv_kernel, chunk, eps, neg_eigval=delta_neg_eigval,
                     cache_layer=n_rec)
+                tables = ("delta", "conv")
                 n_rec += 1
+            mlp = SparseMLP(units, hidden_size, num_experts,
+                            experts_per_token, norm_topk) if num_experts \
+                else GatedMLP(units, hidden_size)
             self.layers.add(HybridDecoderLayer(
-                kind, mixer, units, hidden_size, residual_multiplier, eps,
+                tables, mixer, units, mlp, residual_multiplier, eps,
                 layout=layout))
-        self._counts = (n_attn, n_rec)
+        self._counts = (n_attn, n_win, n_rec)
+        # the tables the model's kinds of layer need, in the spec's order
+        self._tables = ("kv",) + (("kv_win",) if n_win else ()) \
+            + ((self._rec[0], "conv") if n_rec else ())
         self.final_norm = RMSNorm(units, eps)
 
     @classmethod
     def from_config(cls, cfg):
         """The model of a published ``config.json`` (as a dict), by its
         ``model_type``: ``granitemoehybrid`` (also where the key is
-        absent) that routes to no expert, or ``olmo_hybrid``.  A
-        configuration cut in depth (``layer_types`` shorter than the
-        published list) builds the model's first layers."""
+        absent) that routes to no expert, ``olmo_hybrid``, or
+        ``mellum``.  A configuration cut in depth (``layer_types``
+        shorter than the published list) builds the model's first
+        layers."""
         kind = cfg.get("model_type", "granitemoehybrid")
         if kind == "granitemoehybrid":
             return cls._from_granite(cfg)
         if kind == "olmo_hybrid":
             return cls._from_olmo_hybrid(cfg)
+        if kind == "mellum":
+            return cls._from_mellum(cfg)
         raise MXNetError(f"HybridDecoderModel: unknown model_type {kind!r} "
-                         f"(granitemoehybrid, olmo_hybrid)")
+                         f"(granitemoehybrid, olmo_hybrid, mellum)")
 
     @classmethod
     def _from_granite(cls, cfg):
@@ -434,7 +549,46 @@ class HybridDecoderModel(HybridBlock):
             delta_neg_eigval=bool(cfg.get("linear_allow_neg_eigval")),
             conv_kernel=cfg["linear_conv_kernel_dim"],
             chunk=cfg.get("linear_chunk_size", 64),
-            eps=cfg["rms_norm_eps"], layout="post", qk_norm=True,
+            eps=cfg["rms_norm_eps"], layout="post", qk_norm="all",
+            tie_embeddings=bool(cfg.get("tie_word_embeddings")))
+
+    @classmethod
+    def _from_mellum(cls, cfg):
+        """Sliding and full attention in the published pattern, each
+        kind with its own rotary table (``rope_parameters``: plain, or
+        YaRN applied at every length with the config's own
+        ``attention_factor``), per-head QK-norm, every feed-forward a
+        layer of routed experts."""
+        from ..ndarray.rnn_impl import rope_frequencies
+        cls._known_kinds(cfg, ("sliding_attention", "full_attention"))
+        if set(cfg.get("mlp_layer_types", ["sparse"])) != {"sparse"}:
+            raise MXNetError("HybridDecoderModel: a mellum model with "
+                             "dense feed-forward layers")
+        if cfg.get("attention_bias"):
+            raise MXNetError("HybridDecoderModel: a mellum model with "
+                             "attention biases")
+        if not cfg.get("use_sliding_window", True):
+            raise MXNetError("HybridDecoderModel: a mellum model whose "
+                             "sliding layers are switched off")
+        rope = {}
+        for kind, r in cfg["rope_parameters"].items():
+            yarn = r.get("rope_type", "default") == "yarn"
+            if not yarn and r.get("rope_type", "default") != "default":
+                raise MXNetError(f"HybridDecoderModel: rope_type "
+                                 f"{r['rope_type']!r}")
+            rope[kind] = (rope_frequencies(cfg["head_dim"], r["rope_theta"],
+                                           r if yarn else None),
+                          r.get("attention_factor", 1.0) if yarn else 1.0)
+        return cls(
+            cfg["vocab_size"], cfg["hidden_size"],
+            cfg["moe_intermediate_size"], cfg["layer_types"],
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            head_dim=cfg["head_dim"], eps=cfg["rms_norm_eps"],
+            qk_norm="head", rope=rope,
+            sliding_window=cfg["sliding_window"],
+            num_experts=cfg["num_experts"],
+            experts_per_token=cfg["num_experts_per_tok"],
+            norm_topk=bool(cfg.get("norm_topk_prob", True)),
             tie_embeddings=bool(cfg.get("tie_word_embeddings")))
 
     @staticmethod
@@ -445,24 +599,49 @@ class HybridDecoderModel(HybridBlock):
                 f"HybridDecoderModel: a {cfg.get('model_type')} model has "
                 f"no layer of kind {bad} (it has {list(kinds)})")
 
-    def state_spec(self, lanes, max_len, kv_dtype="float32"):
+    def state_spec(self, lanes, max_len, kv_dtype="float32",
+                   max_chunk=None):
         """The state tables of incremental mode, as
         ``GenerateRunner`` takes them: ``(name, shape, lane axis,
-        dtype)`` each.  ``kv`` grows with the context (``max_len``
-        positions a lane); the recurrent table (``ssm``: heads x head
-        size x state; ``delta``: heads x key size x value size) and
-        ``conv`` (channels minor) do not, and stay
-        float32: a state is a sum over every token so far, so its
-        rounding compounds where a key's does not."""
-        n_attn, n_rec = self._counts
+        dtype)`` each, those the model's kinds of layer need and no
+        other.  ``kv`` grows with the context (``max_len`` positions a
+        lane); ``kv_win``, the sliding layers' ring, holds
+        ``sliding_window + max_chunk`` columns a lane whatever the
+        context (``max_chunk``: the most tokens one call writes, the
+        largest prompt bucket — so a chunk's first query still finds
+        its window after the chunk is written); the recurrent table
+        (``ssm``: heads x head size x state; ``delta``: heads x key
+        size x value size) and ``conv`` (channels minor) do not grow
+        either, and stay float32: a state is a sum over every token so
+        far, so its rounding compounds where a key's does not."""
+        n_attn, n_win, n_rec = self._counts
         hk, d = self._attn
-        name, lane, channels = self._rec
         lanes = int(lanes)
-        return (
-            ("kv", (n_attn, 2, lanes, hk, int(max_len), d), 2, kv_dtype),
-            (name, (n_rec, lanes) + tuple(lane), 1, "float32"),
-            ("conv", (n_rec, lanes, self._conv_kernel - 1, channels), 1,
-             "float32"))
+        spec = [("kv", (n_attn, 2, lanes, hk, int(max_len), d), 2, kv_dtype)]
+        if n_win:
+            if max_chunk is None:
+                raise MXNetError("state_spec: sliding layers' ring needs "
+                                 "max_chunk, the largest prompt bucket")
+            ring = min(self._window + int(max_chunk), int(max_len))
+            spec.append(("kv_win", (n_win, 2, lanes, hk, ring, d), 2,
+                         kv_dtype))
+        if n_rec:
+            name, lane, channels = self._rec
+            spec += [(name, (n_rec, lanes) + tuple(lane), 1, "float32"),
+                     ("conv", (n_rec, lanes, self._conv_kernel - 1,
+                               channels), 1, "float32")]
+        return tuple(spec)
+
+    def counter_spec(self):
+        """What a call of this model counts, for ``GenerateRunner``:
+        ``device``: the names of the graph's ``counts`` output's
+        entries (found on the device, one int32 each a call);
+        ``per_token``: counts that are a multiple of a call's valid
+        tokens, known on the host."""
+        if not self._top_k:
+            return {"device": (), "per_token": {}}
+        return {"device": ("moe_experts_touched",),
+                "per_token": {"moe_assignments": self._top_k}}
 
     def named_leaves(self):
         """``{reference leaf name: Parameter}``: the reference's leaves
@@ -471,7 +650,7 @@ class HybridDecoderModel(HybridBlock):
         for i, layer in enumerate(self.layers):
             p, m = f"l{i}.", layer.mixer
             out[p + "norm1"] = layer.norm1.gamma
-            if layer._kind == "attention":
+            if isinstance(m, GroupedQueryAttention):
                 out.update({p + "q": m.q.weight, p + "k": m.k.weight,
                             p + "v": m.v.weight, p + "o": m.o.weight})
                 if m._qk_norm:
@@ -490,22 +669,38 @@ class HybridDecoderModel(HybridBlock):
                     p + "conv_w": m.conv_weight, p + "a_log": m.a_log,
                     p + "dt_bias": m.dt_bias, p + "o_norm": m.norm_gamma})
             out[p + "norm2"] = layer.norm2.gamma
-            out[p + "mlp_in"] = layer.mlp.w_in.weight
-            out[p + "mlp_out"] = layer.mlp.w_out.weight
+            if layer.sparse:
+                out.update({p + "router": layer.mlp.router,
+                            p + "w_in": layer.mlp.w_in,
+                            p + "w_out": layer.mlp.w_out})
+            else:
+                out[p + "mlp_in"] = layer.mlp.w_in.weight
+                out[p + "mlp_out"] = layer.mlp.w_out.weight
         out["final_norm"] = self.final_norm.gamma
         if not self._tied:
             out["head"] = self.head
         return out
 
-    def hybrid_forward(self, F, tokens, step, length, kv, rec, conv,
-                       embed=None, head=None):
+    def hybrid_forward(self, F, tokens, step, length, *tables, embed=None,
+                       head=None):
+        names = self._tables
+        if len(tables) != len(names):
+            raise MXNetError(f"HybridDecoderModel: {len(tables)} state "
+                             f"tables given, the model has {list(names)}")
+        state = dict(zip(names, tables))
         # rows gathered as the table holds them (bfloat16 when served
         # so), brought to float32 before they are scaled
         x = F.cast(F.Embedding(tokens, embed, input_dim=self._vocab,
                                output_dim=self._units),
                    dtype="float32") * self._embed_scale
+        touched = None
         for layer in self.layers:
-            x, kv, rec, conv = layer(x, step, length, kv, rec, conv)
+            x, *rest = layer(x, step, length,
+                             *[state[n] for n in layer.tables])
+            state.update(zip(layer.tables, rest))
+            if layer.sparse:
+                touched = rest[-1] if touched is None \
+                    else touched + rest[-1]
         # one row a lane leaves the program: the last valid position's
         last = F.expand_dims(F.SequenceLast(
             x, length, use_sequence_length=True, axis=1), axis=1)
@@ -513,7 +708,8 @@ class HybridDecoderModel(HybridBlock):
                                   embed if self._tied else head,
                                   no_bias=True, num_hidden=self._vocab,
                                   flatten=False) * self._logit_scale
-        return logits, kv, rec, conv
+        out = (logits,) + tuple(state[n] for n in names)
+        return out if touched is None else out + (touched,)
 
 
 def granite_4_0_h_micro():
@@ -541,5 +737,5 @@ def olmo_hybrid_7b(num_layers=32):
     return HybridDecoderModel(
         100352, 3840, 11008, kinds, 30, 30, delta_heads=30,
         delta_key_dim=96, delta_value_dim=192, delta_neg_eigval=True,
-        conv_kernel=4, chunk=64, eps=1e-6, layout="post", qk_norm=True,
+        conv_kernel=4, chunk=64, eps=1e-6, layout="post", qk_norm="all",
         tie_embeddings=False)

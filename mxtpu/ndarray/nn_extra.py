@@ -732,3 +732,28 @@ register_op("_contrib_MoEFFN", num_inputs=6, num_outputs=2,
                     Param("activation", str, "relu",
                           enum=("relu", "gelu", "tanh"))],
             aliases=("MoEFFN",))(_contrib_moe_ffn)
+
+
+def _routed_experts_op(data, router, w_in, w_out, length, top_k=2,
+                       norm_topk=True):
+    """Sparse SwiGLU feed-forward of a served decoder: top-k routing
+    with no capacity and no dropped token (``parallel.moe
+    .routed_experts``).  ``data``: (B, T, D), of which row b's first
+    ``length_b`` tokens are valid (the rest are routed nowhere);
+    ``router``: (D, E); ``w_in``: (E, D, 2 F) gate over up; ``w_out``:
+    (E, F, D).  Returns ``(y (B, T, D) in data's dtype, touched (1,)
+    int32)``: how many experts this call gave a token."""
+    from ..parallel.moe import routed_experts  # lazy: avoids an import cycle
+    valid = jnp.arange(data.shape[1], dtype=jnp.int32)[None, :] \
+        < jnp.asarray(length).astype(jnp.int32)[:, None]
+    y, touched = routed_experts(data, router, w_in, w_out, valid,
+                                top_k=int(top_k),
+                                renormalise=bool(norm_topk))
+    return y.astype(data.dtype), touched.reshape(1)
+
+
+register_op("routed_experts", num_inputs=5, num_outputs=2,
+            differentiable=False,
+            params=[Param("top_k", int, 2, lower=1),
+                    Param("norm_topk", bool, True)],
+            doc=_routed_experts_op.__doc__)(_routed_experts_op)

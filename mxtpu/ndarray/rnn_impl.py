@@ -222,7 +222,7 @@ def _resident_layout(x):
         np.dtype(x.dtype), tuple(x.shape), dev))
 
 
-def _kv_cache_write_op(table, new, step, layer=0, plane=0):
+def _kv_cache_write_op(table, new, step, layer=0, plane=0, ring=False):
     """In-place write of one layer's new keys (``plane=0``) or values
     (``plane=1``) into the whole KV slot table of incremental decode
     (mxtpu.serving.generate).  ``table``: (layers, 2, B, H, L, D) —
@@ -242,10 +242,20 @@ def _kv_cache_write_op(table, new, step, layer=0, plane=0):
     symbol's JSON.  Values are cast to the table's dtype on write, so
     a bf16 cache under mxtpu.amp stays bf16 regardless of compute
     dtype; a write that would run past ``L`` is clamped to end there,
-    as ``dynamic_update_slice`` does."""
+    as ``dynamic_update_slice`` does.  With ``ring`` the table's ``L``
+    columns are a ring: position ``p`` lives at column ``p mod L``, so a
+    lane keeps its last ``L`` positions however long its context grows
+    (a sliding-window layer's table: ``cached_attention(window=...)``
+    reads it).  One token is the same column store at
+    ``step mod L``; ``T`` tokens, which may straddle the wrap, are one
+    masked store of the plane (``_write_ring``)."""
     from ..kernels import kv_write
     new = new.astype(table.dtype)
     idx = jnp.asarray(step).astype(jnp.int32)
+    if ring:
+        idx = idx % table.shape[4]
+        if new.shape[2] > 1:
+            return _write_ring(table, new, idx, layer, plane)
     if new.shape[2] == 1 and _capacity_is_minor(table):
         return kv_write.kv_write(
             table, new, jnp.clip(idx, 0, table.shape[4] - 1),
@@ -299,6 +309,29 @@ def _write_lanes(table, new, idx, layer, plane):
     return lax.fori_loop(0, new.shape[0], one_lane, table)
 
 
+def _write_ring(table, new, start, layer, plane):
+    """``T`` positions a lane into a ring table: row b's columns
+    ``(start_b + t) mod L`` take ``new[b, :, t]``, every other column
+    stays.  The new rows are grown to the ring's width, turned by
+    ``start_b`` and stored under a mask of the columns they cover: one
+    pass over the plane, whether or not the run straddles the wrap (a
+    run is cut at the wrap at a place known only when the program runs,
+    so it is no two slices of static size).  The table here is a
+    prefill's few gathered lanes, so the pass is small."""
+    L, T = table.shape[4], new.shape[2]
+    if T > L:
+        raise MXNetError(f"kv_cache_write: {T} positions into a ring "
+                         f"of {L}")
+    with jax.named_scope("kv_ring_write"):
+        wide = jnp.pad(new, ((0, 0), (0, 0), (0, L - T), (0, 0)))
+        turned = jax.vmap(lambda rows, by: jnp.roll(rows, by, axis=1))(
+            wide, start)
+        covered = (jnp.arange(L, dtype=jnp.int32)[None, :]
+                   - start[:, None]) % L < T
+        return table.at[layer, plane].set(jnp.where(
+            covered[:, None, :, None], turned, table[layer, plane]))
+
+
 def write_whole_lanes(table, rows, lanes, axis):
     """``table`` with lane ``lanes[r]`` (along ``axis``) replaced whole
     by row ``r`` of ``rows``, for every r in turn: the way a prefill's
@@ -341,7 +374,8 @@ def read_whole_lanes(table, lanes, axis):
 
 register_op("kv_cache_write", num_inputs=3, differentiable=False,
             params=[Param("layer", int, 0, lower=0),
-                    Param("plane", int, 0, enum=(0, 1))],
+                    Param("plane", int, 0, enum=(0, 1)),
+                    Param("ring", bool, False)],
             doc=_kv_cache_write_op.__doc__)(_kv_cache_write_op)
 
 
@@ -360,7 +394,8 @@ register_op("kv_cache_read", num_inputs=1, differentiable=False,
             doc=_kv_cache_read_op.__doc__)(_kv_cache_read_op)
 
 
-def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
+def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
+                         window=0):
     """Decode-step attention over a preallocated KV cache.  ``q``:
     (B, H, T, D) — the T new query tokens of each lane sit at absolute
     positions ``step_b + t``; ``k_cache``/``v_cache``: (B, H_kv, L, D)
@@ -374,7 +409,22 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
     and the probs @ V contraction all accumulate in f32 and only the
     final output is cast back to the query dtype: the zero-hazard
     bf16-decode/f32-accum recipe contracts/prec/generate_decode.json
-    pins.  ``sm_scale < 0`` means 1/sqrt(D)."""
+    pins.  ``sm_scale < 0`` means 1/sqrt(D).
+
+    ``window`` > 0 makes the cache's ``L`` columns a ring written by
+    ``kv_cache_write(ring=True)`` and bounds a query's context to itself
+    and the ``window - 1`` positions before it (a sliding-window layer):
+    column ``c`` holds for the query at ``p`` the position ``p' = p -
+    ((p - c) mod L)``, the newest one at or before ``p`` that lives
+    there, and the mask is on that position — ``p - p' < window`` and
+    ``p' >= 0`` — so a column nobody has written since the lane was
+    taken, one a previous occupant left, and the later positions of the
+    query's own chunk (``L >= window + T - 1`` puts them a window away)
+    are unreachable by construction, as the frontier mask makes them
+    without a window (a table that holds every position is a ring no
+    wrap has reached).  Its work lies under the scope
+    ``window_attention``; with no window the op is the program it
+    was."""
     B, H, T, D = q.shape
     Hk, L = k_cache.shape[1], k_cache.shape[2]
     if H % Hk:
@@ -382,11 +432,21 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
                          f"{Hk} key/value heads")
     scale = (1.0 / float(np.sqrt(D))) \
         if (sm_scale is None or sm_scale < 0) else float(sm_scale)
-    with jax.named_scope("cached_attention"):
+    window = int(window)
+    if window > L - T + 1:
+        raise MXNetError(
+            f"cached_attention: a ring of {L} columns holds a window of "
+            f"1..{L - T + 1} positions for {T} new tokens, not {window}")
+    with jax.named_scope("window_attention" if window
+                         else "cached_attention"):
         s = jnp.asarray(step).astype(jnp.int32)
         pos_q = s[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
         pos_k = jnp.arange(L, dtype=jnp.int32)
-        mask = pos_k[None, None, :] <= pos_q[:, :, None]
+        if window:
+            back = (pos_q[:, :, None] - pos_k[None, None, :]) % L
+            mask = (back < window) & (back <= pos_q[:, :, None])
+        else:
+            mask = pos_k[None, None, :] <= pos_q[:, :, None]
         q32 = q.astype(jnp.float32)
         k32, v32 = k_cache.astype(jnp.float32), v_cache.astype(jnp.float32)
         if H == Hk:
@@ -410,8 +470,72 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0):
 
 
 register_op("cached_attention", num_inputs=4, differentiable=False,
-            params=[Param("sm_scale", float, -1.0)],
+            params=[Param("sm_scale", float, -1.0),
+                    Param("window", int, 0, lower=0)],
             doc=_cached_attention_op.__doc__)(_cached_attention_op)
+
+
+def rope_frequencies(head_dim, theta, yarn=None):
+    """The ``head_dim / 2`` rotary frequencies of a layer, in numpy
+    (they are a static attribute of ``rope``).  Plain: ``theta^(-2j /
+    head_dim)``.  ``yarn`` (Peng et al. 2023, arXiv:2309.00071; a dict
+    with ``factor``, ``original_max_position_embeddings`` and
+    optionally ``beta_fast`` 32, ``beta_slow`` 1) divides the slow
+    frequencies by ``factor`` and leaves the fast ones, with a linear
+    ramp between the dimensions that turn ``beta_fast`` and
+    ``beta_slow`` times over the original length."""
+    j = np.arange(0, head_dim, 2, dtype=np.float64)  # mxlint: disable=dtype-hygiene (host-side table, a static attribute: float64 on purpose)
+    plain = float(theta) ** (-j / head_dim)
+    if not yarn:
+        return plain
+    factor = float(yarn["factor"])
+    original = float(yarn["original_max_position_embeddings"])
+
+    def dim_of(turns):
+        return head_dim * np.log(original / (turns * 2 * np.pi)) \
+            / (2 * np.log(float(theta)))
+
+    lo = max(int(np.floor(dim_of(float(yarn.get("beta_fast", 32.0))))), 0)
+    hi = min(int(np.ceil(dim_of(float(yarn.get("beta_slow", 1.0))))),
+             head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - lo)  # mxlint: disable=dtype-hygiene (host-side table)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def _rope_op(x, step, inv_freq=(), scale=1.0):
+    """Rotary position embedding of queries or keys at their absolute
+    positions.  ``x``: (B, H, T, D), row b's token t at position
+    ``step_b + t``; ``inv_freq``: the ``D / 2`` frequencies, a static
+    attribute (``rope_frequencies``); ``scale`` multiplies cos and sin
+    (YaRN's ``attention_factor``).  Halves convention: ``x * cos +
+    rotate_half(x) * sin`` with ``rotate_half(x) = [-x2, x1]`` over the
+    two halves of ``D`` and the angles repeated over both.  Angles,
+    cos and sin in float32 whatever ``x`` is.  Keys are rotated before
+    ``kv_cache_write``, so a cache holds rotated keys and a decode step
+    rotates one position a lane."""
+    D = x.shape[-1]
+    freq = np.asarray(inv_freq, np.float32)
+    if freq.shape != (D // 2,):
+        raise MXNetError(f"rope: {freq.size} frequencies for a head of "
+                         f"{D}")
+    with jax.named_scope("rope"):
+        pos = jnp.asarray(step).astype(jnp.float32)[:, None] \
+            + jnp.arange(x.shape[2], dtype=jnp.float32)[None, :]
+        angle = pos[:, None, :, None] * freq            # (B, 1, T, D/2)
+        cos = jnp.cos(angle) * float(scale)
+        sin = jnp.sin(angle) * float(scale)
+        x32 = x.astype(jnp.float32)
+        x1, x2 = x32[..., :D // 2], x32[..., D // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
+        return out.astype(x.dtype)
+
+
+register_op("rope", num_inputs=2, differentiable=False,
+            params=[Param("inv_freq", tuple, ()),
+                    Param("scale", float, 1.0)],
+            doc=_rope_op.__doc__)(_rope_op)
 
 
 # ----------------------------------------------------------------------

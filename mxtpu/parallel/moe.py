@@ -20,6 +20,15 @@ dispatch/return collectives over ICI exactly like the reference
 NCCL/MPI frameworks hand-code.  Tokens over capacity are dropped
 (their combine weight is 0 and the residual path carries them) —
 Switch semantics.
+
+The serving path routes otherwise (``topk_router``,
+``routed_experts``): softmax over the experts, the ``top_k`` largest a
+token, renormalised, NO capacity and no dropped token.  Tokens are
+sorted by expert and the experts' products are grouped matrix products
+over the experts held (``_grouped_dot``: on the TPU the megablox
+grouped-matmul Pallas kernel, which walks the groups' tiles of rows and
+skips an empty group; elsewhere ``jax.lax.ragged_dot``): the routed work
+only, each touched expert's weights read once.
 """
 from __future__ import annotations
 
@@ -31,7 +40,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["moe_ffn", "switch_router", "MoEFFN"]
+__all__ = ["moe_ffn", "switch_router", "MoEFFN", "topk_router",
+           "routed_experts"]
 
 
 def switch_router(x2d, gate_w, capacity: int, *, key=None,
@@ -148,3 +158,105 @@ class MoEFFN:
                        capacity_factor=self.capacity_factor,
                        mesh=mesh, ep_axis=ep_axis, key=key,
                        jitter=jitter)
+
+
+def topk_router(x2d, router_w, top_k: int, *, renormalise: bool = True):
+    """Top-k routing with nothing dropped: ``(weights (T, k), experts
+    (T, k) int32)``.  ``x2d``: (T, D); ``router_w``: (D, E).  The
+    router's product and its softmax are float32 at HIGHEST precision
+    whatever the inputs are (E multiply-adds a token and feature: its
+    cost is nothing, and a rounded router picks other experts at
+    near-ties); the ``top_k`` largest probabilities, the lower index
+    first among equals, divided by their sum where ``renormalise``."""
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, int(top_k))
+    if renormalise:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e.astype(jnp.int32)
+
+
+# a tile of the grouped kernel: 128 rows by the whole contraction by as
+# much of the output as keeps an expert's block of weights near 4 MB
+# (two of them in flight fit the chip's fast memory beside the rows).
+# At this cell's widths that is (128, 2304, 896) and (128, 896, 2304):
+# 1.7 ms a layer's two products for a decode step's 184 rows over 62
+# experts where the compiler's own ragged-dot kernel took 4.5 and the
+# weights alone need 0.94 (my chip runs, PR 34: PERF.md sec. 6)
+_TILE_ROWS = 128
+_TILE_RHS_BYTES = 4.25 * 2 ** 20
+
+
+def _grouped_dot(rows, w, sizes):
+    """``rows[start_g : start_g + sizes_g] @ w[g]`` for every group g,
+    float32 out: ``rows`` (M, K) sorted by group, ``w`` (G, K, N),
+    ``sizes`` (G,) int32.  Rows past the last group hold no number.  On
+    the TPU ``M`` is a whole number of ``_TILE_ROWS``."""
+    from .. import kernels
+    if not kernels.pallas_enabled():
+        return jax.lax.ragged_dot(rows, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    k, n = w.shape[1:]
+    tile_n = n
+    while k * tile_n * w.dtype.itemsize > _TILE_RHS_BYTES \
+            and tile_n % 256 == 0:
+        tile_n //= 2
+    return gmm(rows, w, sizes, preferred_element_type=jnp.float32,
+               tiling=(_TILE_ROWS, k, tile_n),
+               interpret=kernels.interpret_mode())
+
+
+def routed_experts(x, router_w, w_in, w_out, valid=None, *, top_k: int,
+                   renormalise: bool = True):
+    """A sparse SwiGLU feed-forward: every token through its ``top_k``
+    experts, weighted and summed; no bias, no shared expert, no
+    capacity.  ``x``: (..., D); ``router_w``: (D, E); ``w_in``: (E, D,
+    2 F), each expert's gate over its up projection; ``w_out``: (E, F,
+    D); ``valid``: (...) bool, False for a padded token, which is
+    routed nowhere: it is in no expert's group, reads no weight, and
+    its output is zero.  Returns ``(y (..., D) float32, touched)``,
+    ``touched`` the int32 count of experts with a token.
+
+    The ``T x top_k`` assignments are sorted by expert (scope
+    ``moe/dispatch``); the two products run grouped over the experts'
+    runs of rows (``moe/experts``: inputs in the weights' dtype,
+    accumulated in float32); the rows go back to token order, are
+    weighted and summed (``moe/combine``).  Every assignment of every
+    valid token is in the sum under any imbalance, all tokens on one
+    expert included: a group is as long as its expert's tokens are
+    many."""
+    lead, D = x.shape[:-1], x.shape[-1]
+    E, k = router_w.shape[-1], int(top_k)
+    x2d = x.reshape(-1, D)
+    with jax.named_scope("moe/route"):
+        weights, experts = topk_router(x2d, router_w, k,
+                                       renormalise=renormalise)
+        if valid is not None:
+            # expert E is nobody: past every group
+            experts = jnp.where(valid.reshape(-1, 1), experts, E)
+    with jax.named_scope("moe/dispatch"):
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=jnp.int32),
+                        axis=0, dtype=jnp.int32)
+        # whole tiles of rows for the grouped kernel: the rows added
+        # repeat token 0 past every group, where nothing is computed
+        fill = (-order.shape[0]) % _TILE_ROWS
+        rows = x2d.astype(w_in.dtype)[jnp.pad(order // k, (0, fill))]
+    with jax.named_scope("moe/experts"):
+        h = _grouped_dot(rows, w_in, sizes)
+        half = h.shape[-1] // 2
+        act = (jax.nn.silu(h[:, :half]) * h[:, half:]).astype(w_out.dtype)
+        out = _grouped_dot(act, w_out, sizes)
+    with jax.named_scope("moe/combine"):
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        out = out[back].reshape(-1, k, D)
+        # a row past the last group is not computed: it holds no number
+        y = jnp.sum(jnp.where((experts < E)[..., None],
+                              out * weights[..., None], 0.0), axis=1)
+        touched = jnp.sum(sizes > 0, dtype=jnp.int32)
+    return y.reshape(lead + (D,)), touched

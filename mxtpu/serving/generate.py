@@ -254,7 +254,12 @@ class GenerateRunner:
         — or a state spec: a sequence of :class:`StateTable` ``(name,
         shape, lane_axis, dtype)`` such as
         ``HybridDecoderModel.state_spec`` declares, one of them a 6-D
-        table named ``"kv"`` whose axis 4 is the capacity.  The runner
+        table named ``"kv"`` whose axis 4 is the capacity: how many
+        positions a lane may reach, and the one bound the batcher
+        evicts on.  It is that table's alone: another table holds what
+        its layers need (a sliding-window layer's ring ``kv_win`` keeps
+        window + one chunk of columns, recurrent state no positions at
+        all).  The runner
         allocates ONE extra scratch slot in every table (prefill batch
         padding scatters there; its contents are garbage by
         construction and never read), so the device tables have
@@ -272,6 +277,16 @@ class GenerateRunner:
         where lanes are large the upper rungs of the ladder need more
         memory than the device has: the ladder then ends at this rung
         and the batcher admits at most so many requests a step.
+    counters : dict, optional
+        What a call of the graph counts beside its logits
+        (``HybridDecoderModel.counter_spec``): ``{"device": names,
+        "per_token": {name: multiple}}``.  A graph with ``device``
+        counters has one more output after its tables, an int32 vector
+        with an entry a name; the numbers come over in the fetch that
+        brings the token ids.  A ``per_token`` count is the call's
+        valid tokens times its multiple, known on the host.  Each is a
+        fact of the call's region and adds to the counter
+        ``mxtpu_<name>_total``.
     quant_scales : dict, optional — calibrated activation thresholds
         (from a :class:`ModelRunner` ``calibrate()`` over the same
         architecture) arming the int8 trace path; required when
@@ -286,7 +301,8 @@ class GenerateRunner:
                  device=None, donate: Optional[bool] = None,
                  max_prefill_batch: Optional[int] = None,
                  cache: Any = "auto", amp=None, quant=None,
-                 quant_scales: Optional[Dict[str, float]] = None):
+                 quant_scales: Optional[Dict[str, float]] = None,
+                 counters: Optional[Dict[str, Any]] = None):
         import jax
 
         from .. import amp as _amp_mod
@@ -295,6 +311,9 @@ class GenerateRunner:
         self._quant = _quant_mod.resolve(quant)
         self._quant_scales = dict(quant_scales) if quant_scales else None
         self._symbol = symbol
+        counters = counters or {}
+        self._device_counters = tuple(counters.get("device", ()))
+        self._token_counters = dict(counters.get("per_token", {}))
         # one KV table as a 6-tuple of ints, or a declared state spec
         self._one_table = not isinstance(kv_spec[0], (tuple, list))
         if self._one_table:
@@ -451,6 +470,13 @@ class GenerateRunner:
             "mxtpu_gen_state_reset_total",
             "Prefill rows that started a lane from zero state "
             "(step 0): admissions and replays, not later chunks.")
+        self._m_counted = {
+            name: obs.counter(f"mxtpu_{name}_total",
+                              f"{name}, summed over the generation "
+                              f"programs' calls (GenerateRunner's "
+                              f"counters).")
+            for name in self._device_counters
+            + tuple(self._token_counters)}
 
         from .. import cache as cache_mod
         self._cache = cache_mod.default_cache() if cache == "auto" \
@@ -643,12 +669,15 @@ class GenerateRunner:
         finally:
             autograd.set_training(prev_train)
             autograd.set_recording(prev_rec)
-        if len(outs) != 1 + len(tables):
+        counted = 1 if self._device_counters else 0
+        if len(outs) != 1 + len(tables) + counted:
             raise MXNetError(
                 f"generate: incremental graph must output (logits, "
-                f"cache) — one output a state table — got "
-                f"{len(outs)} outputs")
-        return outs[0].data, tuple(o.data for o in outs[1:])
+                f"cache) — one output a state table, then its counts "
+                f"if it declares any — got {len(outs)} outputs")
+        # the graph's counts, if any, ride behind the tables it hands back
+        return (outs[0].data, tuple(o.data for o in outs[1:1 + len(tables)]),
+                *(o.data for o in outs[1 + len(tables):]))
 
     def _prefill_pure(self):
         """(tokens (b,s), step (b,), length (b,), lane_idx (b,),
@@ -685,13 +714,13 @@ class GenerateRunner:
                         state.at[:, :, idx].set(
                             new_small.astype(state.dtype))
                 axes = [t.lane_axis for t in self.state_spec]
-                logits, new = self._eval_incremental(
+                logits, new, *counts = self._eval_incremental(
                     rows, tuple(read_whole_lanes(t, idx, a)
                                 for t, a in zip(state, axes)),
                     param_vals)
-                return logits, tuple(
+                return (logits, tuple(
                     write_whole_lanes(t, n, idx, a)
-                    for t, n, a in zip(state, new, axes))
+                    for t, n, a in zip(state, new, axes)), *counts)
 
         return fn
 
@@ -911,18 +940,23 @@ class GenerateRunner:
                            "context_tokens": int(step[on].sum())})
 
     @staticmethod
-    def _first_maximum_of(entry, logits):
+    def _first_maximum_of(entry, logits, *counts):
         """The executable that finds each row's first maximum of
-        ``logits (rows, 1, V)`` on the device, ``(rows,)`` int32;
-        built at the entry's first run (a set-up's first run of the
-        program, never a token's)."""
+        ``logits (rows, 1, V)`` on the device, ``(rows,)`` int32, with
+        the graph's counts (if it has any) behind them in the same
+        array, so that one fetch brings both; built at the entry's
+        first run (a set-up's first run of the program, never a
+        token's)."""
         import jax
         import jax.numpy as jnp
         fn = entry.get("first_maximum")
         if fn is None:
-            fn = entry["first_maximum"] = jax.jit(
-                lambda rows: jnp.argmax(rows[:, 0, :], axis=-1).astype(
-                    jnp.int32)).lower(logits).compile()
+            def first(rows, *counts):
+                at = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+                return jnp.concatenate((at,) + counts) if counts else at
+
+            fn = entry["first_maximum"] = jax.jit(first).lower(
+                logits, *counts).compile()
         return fn
 
     def _call(self, name: str, bucket: Tuple,
@@ -935,6 +969,9 @@ class GenerateRunner:
         logits the call made, ``fetched_bytes`` what the fetch brought
         over: 4 bytes a row)."""
         import jax
+        tokens = counts.get("tokens", counts.get("active", 0))
+        counts = dict(counts, **{n: tokens * by for n, by
+                                 in self._token_counters.items()})
         with self._region(name, **counts) as rg:
             entry = self._entry(bucket)
             with self._region(name + obs.SPAN_STAGE):
@@ -946,14 +983,24 @@ class GenerateRunner:
                 self._churn.note_call()
             with self._region(name + obs.SPAN_DISPATCH), \
                     guards.no_implicit_transfers(self._guards):
-                logits, kv = entry["compiled"](*staged, kv,
-                                               self._param_vals)
-                first = self._first_maximum_of(entry, logits)(logits)
+                logits, kv, *counted = entry["compiled"](
+                    *staged, kv, self._param_vals)
+                first = self._first_maximum_of(entry, logits, *counted)(
+                    logits, *counted)
             with self._region(name + obs.SPAN_FETCH):
-                # mxlint: sync-point — deliberate D2H: token ids only
+                # mxlint: sync-point — deliberate D2H: token ids (and the graph's counts behind them)
                 first = np.asarray(first)
             rg.set(logits_bytes=logits.nbytes, fetched_bytes=first.nbytes,
                    kv_kernel_writes=entry["kv_kernel_writes"])
+            if self._m_counted:
+                rows = logits.shape[0]
+                counts.update(zip(self._device_counters,
+                                  (int(c) for c in first[rows:])))
+                first = first[:rows]
+                rg.set(**{n: counts[n] for n in self._device_counters})
+                if self._obs:
+                    for n, m in self._m_counted.items():
+                        m.inc(counts[n])
         return DeviceLogits(logits, first), kv
 
     # -- introspection / contracts ----------------------------------------

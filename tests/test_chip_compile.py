@@ -372,3 +372,81 @@ def test_a_prefill_reads_its_lanes_where_they_lie_on_v5e(
     assert traced[0] == 0 and "tpu_custom_call" not in text
     assert mem["alias_size_in_bytes"] == 32 * lane
     assert mem["temp_size_in_bytes"] < lane, mem
+
+
+@pytest.fixture
+def mellum_stage(topo, one_chip, monkeypatch):
+    """A ``GenerateRunner`` of the routed-experts cell's model at its
+    published widths, cut to two layers (a sliding one and a full one),
+    on the described chip: nothing can be put on such a device, so the
+    weights are shapes and ``jax.device_put`` hands shapes back."""
+    import json
+    import os
+    from mxtpu import symbol as sym_mod
+    from mxtpu.models.hybrid import HybridDecoderModel
+    from mxtpu.serving import GenerateRunner
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2_12b_a2_5b.json")) as f:
+        cfg = json.load(f)
+    cfg["layer_types"] = cfg["layer_types"][2:4]
+    net = HybridDecoderModel.from_config(cfg)
+    out = net(*[sym_mod.var(f"data{i}") for i in range(5)])
+
+    class Leaf:
+        def __init__(self, shape):
+            self.shape = tuple(shape)
+
+        def asnumpy(self):
+            return jax.ShapeDtypeStruct(self.shape, jnp.bfloat16,
+                                        sharding=one_chip)
+
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda v, d=None: v if isinstance(
+        v, jax.ShapeDtypeStruct) else put(v, d))
+    monkeypatch.setattr(GenerateRunner, "_as_np",
+                        staticmethod(lambda v: v.asnumpy()))
+    return GenerateRunner(
+        sym_mod.Group(list(out)),
+        {p.name: Leaf(p.shape) for p in net.collect_params().values()},
+        net.state_spec(23, 8448, kv_dtype="bfloat16", max_chunk=256),
+        prompt_buckets=(128, 256), max_prefill_batch=1, amp=True,
+        device=topo.devices[0], cache=None, counters=net.counter_spec())
+
+
+@pytest.mark.parametrize("bucket", [("decode", (24,)), ("prefill", (1, 256))],
+                         ids=["decode", "prefill-1x256"])
+def test_routed_experts_beside_a_ring_compile_for_v5e(
+        bucket, mellum_stage, on_tpu, chip_layouts, no_persistent_cache):
+    """The routed-experts cell's decode step (24 slots) and one prefill
+    call (a row of 256) at the published widths — hidden 2,304, 32 query
+    over 4 key/value heads of 128, 64 experts of 896 of which a token
+    takes 8, a window of 1,024 on a ring of 1,280 beside a full table of
+    8,448 positions, bfloat16 — two of the twelve layers, the model's own
+    graph through ``GenerateRunner``'s own program.  The chip's compiler
+    must take the grouped products as the grouped-matmul kernel (a dense
+    product over all 64 experts would be eight times the work), alias the
+    donated tables to the results, and need far less than a table of
+    temporaries (a prefill row's float32 scores over the full table are
+    277 MB); the prefill's write of 256 positions into the ring, which
+    may straddle the wrap, compiles as one masked store."""
+    from mxtpu import analysis
+    r = mellum_stage
+    fn = r._prefill_pure() if bucket[0] == "prefill" else r._decode_pure()
+    structs = r._structs(bucket)
+    text, mem = analysis.compiled_artifact(
+        fn, *structs, r._param_structs, donate_argnums=(len(structs) - 1,))
+    tables = sum(r.state_bytes().values())
+    assert tables == 24 * 2 * 4 * (8448 + 1280) * 128 * 2
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < \
+        (0.05 if bucket[0] == "decode" else 1.0) * tables, mem
+    grouped = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/moe/experts/" in line]
+    assert len(grouped) == 4 and "ragged-dot" not in text, \
+        "the experts' products are not the grouped-matmul kernel"
+    assert ("kv_ring_write" in text) == (bucket[0] == "prefill")
+    for scope in ("window_attention", "cached_attention", "rope",
+                  "moe/route", "moe/dispatch", "moe/experts",
+                  "moe/combine"):
+        assert "/" + scope + "/" in text, scope

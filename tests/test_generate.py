@@ -690,7 +690,8 @@ def test_compiled_programs_never_move_a_cache_plane(any_runner, bucket):
         assert len(moves) <= 1, moves
 
 
-def _write_by_slice_and_stack(table, new, step, layer=0, plane=0):
+def _write_by_slice_and_stack(table, new, step, layer=0, plane=0,
+                              ring=False):
     """The plain formulation the programs had before ISSUE 26, kept as
     the reference: cut the plane out, write each lane's rows at its own
     frontier, stack the planes into a new table."""
