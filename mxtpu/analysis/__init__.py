@@ -55,6 +55,7 @@ __all__ = [
     "load_contract", "contract_path", "CONTRACTS_DIR",
     "DEFAULT_TOLERANCES", "COLLECTIVE_OPS", "BRACKET_OPS",
     "HOST_TRANSFER_OPS", "mem_stats", "compiled_artifact",
+    "executable_artifact",
     "compiled_summary", "compiled_evidence", "maybe_audit",
     "audit_mode", "dtypeflow", "dtype_summary", "cast_flows",
     "hazard_findings", "format_hazard", "master_weight_findings",
@@ -72,7 +73,13 @@ def compiled_artifact(fn, *args, **jit_kwargs
     compiled program (keeps raw ``.lower()``/``.hlo_text()`` calls
     out of ``tests/``)."""
     import jax
-    compiled = jax.jit(fn, **jit_kwargs).lower(*args).compile()
+    return executable_artifact(
+        jax.jit(fn, **jit_kwargs).lower(*args).compile())
+
+
+def executable_artifact(compiled) -> Tuple[str, Optional[Dict[str, int]]]:
+    """``(hlo_text, mem_stats)`` of a program already compiled (a
+    runner's entry, built by the runner's own route)."""
     return compiled.as_text(), mem_stats(compiled)
 
 

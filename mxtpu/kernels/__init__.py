@@ -17,7 +17,8 @@ __all__ = ["layer_norm", "flash_attention", "pallas_enabled",
            "precision_metadata", "layout_metadata"]
 
 
-_KERNELS = ("flash_attention", "layer_norm", "batch_norm", "kv_write")
+_KERNELS = ("flash_attention", "layer_norm", "batch_norm", "kv_write",
+            "ssm_update")
 
 
 def layout_metadata():

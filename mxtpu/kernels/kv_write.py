@@ -14,15 +14,15 @@ touched, and the donated buffer is updated where it lies.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ._sites import CallSites
 
 _LANES = 128
 # one block of the table in VMEM; the pipeline holds four (in and out,
@@ -55,27 +55,9 @@ LAYOUT = {
                 "other write keeps the lanes' loop",
 }
 
-class _Open(threading.local):
-    """The tallies of the ``call_sites`` blocks this thread is in."""
-
-    def __init__(self):
-        self.tallies = []
-
-
-_open = _Open()
-
-
-@contextlib.contextmanager
-def call_sites():
-    """Counts the kernel call sites traced inside the block: yields a
-    one-element list whose entry is the count so far.  What a program
-    that was lowered inside the block holds of this kernel."""
-    tally = [0]
-    _open.tallies.append(tally)
-    try:
-        yield tally
-    finally:
-        _open.tallies.remove(tally)
+# ``with call_sites() as traced``: the kernel call sites traced inside
+# the block, what a program that was lowered there holds of this kernel
+call_sites = CallSites()
 
 
 def _kernel(lp_ref, idx_ref, new_ref, table_ref, out_ref):
@@ -111,8 +93,7 @@ def kv_write(table, new, idx, layer, plane):
     that one traced kernel serves every call site of a program, and an
     eager call is one program whose swaps are bitcasts."""
     from . import interpret_mode
-    for tally in _open.tallies:
-        tally[0] += 1
+    call_sites.note()
     return _column_store(table, new, idx, layer, plane,
                          interpret=interpret_mode())
 

@@ -593,13 +593,19 @@ register_op("gated_rms_norm", num_inputs=3,
             doc=_gated_rms_norm_op.__doc__)(_gated_rms_norm_op)
 
 
-def _fresh(state, step, length):
-    """A lane that takes its first tokens (``step`` 0, ``length`` > 0)
-    starts from zero state whatever it held: admission needs no zeroing
-    of the table from the host.  A row with nothing valid keeps what
-    its lane holds."""
-    keep = (jnp.asarray(step).astype(jnp.int32) > 0) \
+def _keeps_state(step, length):
+    """(B,) bool: False for a lane that takes its first tokens (``step``
+    0, ``length`` > 0) and so starts from zero state whatever it held:
+    admission needs no zeroing of the table from the host.  A row with
+    nothing valid keeps what its lane holds."""
+    return (jnp.asarray(step).astype(jnp.int32) > 0) \
         | (jnp.asarray(length).astype(jnp.int32) <= 0)
+
+
+def _fresh(state, step, length):
+    """``state`` with the lanes that ``_keeps_state`` says start anew
+    zeroed."""
+    keep = _keeps_state(step, length)
     return jnp.where(keep.reshape((-1,) + (1,) * (state.ndim - 1)),
                      state, jnp.zeros((), state.dtype))
 
@@ -709,7 +715,16 @@ def _ssm_scan_op(table, x, dt, b_mat, c_mat, a_log, d_skip, dt_bias,
     ``ssm/scan``); T = 1 is one read and one write of the plane (scope
     ``ssm/state_update``), fenced by ``optimization_barrier`` so that
     the compiler fuses nothing of its neighbours into it and a trace
-    can time it alone.  ``layer`` and ``chunk`` are static."""
+    can time it alone.  One token on a TPU that keeps a float32 table
+    with ``N`` minor in whole tiles is one Pallas kernel over the lanes
+    (``mxtpu.kernels.ssm_update``) that sums ``y`` from each block of
+    the new state while it holds it; every other one-token update is
+    XLA's: a fusion that reads and writes the plane and a second that
+    reads it again for ``y``.  Which of the two is decided from what is
+    observed here — ``T``, the backend, the table's dtype, shape and
+    layout on the device (``_state_in_whole_tiles``) — and both compute
+    the same float32 products and sums.  ``layer`` and ``chunk`` are
+    static."""
     B, T, H = dt.shape
     P = x.shape[-1] // H
     f32 = jnp.float32
@@ -725,24 +740,70 @@ def _ssm_scan_op(table, x, dt, b_mat, c_mat, a_log, d_skip, dt_bias,
         valid = jnp.arange(T, dtype=jnp.int32)[None, :] < n[:, None]
         dtp = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32)) \
             * valid[..., None].astype(f32)
+        by_kernel = T == 1 and _state_in_whole_tiles(table)
+        if by_kernel:
+            x = _held_as_rows(x)
         xh = x.astype(f32).reshape(B, T, H, P)
-        s0 = _fresh(table[layer], step, n).astype(f32)
-        if T == 1:
-            d1, x1 = dtp[:, 0], xh[:, 0]
-            s_new = s0 * jnp.exp(d1 * a_head)[..., None, None] \
-                + (d1[..., None] * x1)[..., None] \
-                * b_mat.astype(f32)[:, 0, None, None, :]
-            y = jnp.sum(s_new * c_mat.astype(f32)[:, 0, None, None, :],
-                        axis=-1)[:, None]
+        if by_kernel:
+            from ..kernels import ssm_update
+            d1 = dtp[:, 0]
+            y, table = ssm_update.ssm_update(
+                table, _keeps_state(step, n), jnp.exp(d1 * a_head),
+                d1[..., None] * xh[:, 0], b_mat.astype(f32)[:, 0],
+                c_mat.astype(f32)[:, 0], jnp.int32(layer))
+            y = y[:, None]
         else:
-            y, s_new = _ssd_chunked(xh, dtp, a_head, b_mat.astype(f32),
-                                    c_mat.astype(f32), s0, chunk)
+            s0 = _fresh(table[layer], step, n).astype(f32)
+            if T == 1:
+                d1, x1 = dtp[:, 0], xh[:, 0]
+                s_new = s0 * jnp.exp(d1 * a_head)[..., None, None] \
+                    + (d1[..., None] * x1)[..., None] \
+                    * b_mat.astype(f32)[:, 0, None, None, :]
+                y = jnp.sum(
+                    s_new * c_mat.astype(f32)[:, 0, None, None, :],
+                    axis=-1)[:, None]
+            else:
+                y, s_new = _ssd_chunked(xh, dtp, a_head, b_mat.astype(f32),
+                                        c_mat.astype(f32), s0, chunk)
         y = y + d_skip.astype(f32)[None, None, :, None] * xh
         y = y.reshape(B, T, H * P).astype(x.dtype)
-        table = table.at[layer].set(s_new.astype(table.dtype))
+        if by_kernel:               # which wrote the plane itself
+            y = _held_as_rows(y)
+        else:
+            table = table.at[layer].set(s_new.astype(table.dtype))
     if T == 1:
         y = lax.optimization_barrier(y)
     return y, table
+
+
+def _held_as_rows(z):
+    """``z`` (B, 1, C) held as the device holds a matrix (B, C), a lane
+    a row: how the projections on either side of the mixer leave and
+    take a decode step's activations.  The kernel's operands have a
+    layout of their own ((B, H, P): a head a row), which the compiler
+    would otherwise hand on to everything that feeds it and everything
+    it feeds — the input projection's product, the convolution, the
+    gate and the norm all a row a tile, an eighth of each tile used
+    (PERF.md, PR 35)."""
+    from jax.experimental.layout import with_layout_constraint
+    flat = z.reshape(z.shape[0], -1)
+    return with_layout_constraint(flat, _resident_layout(flat)).reshape(
+        z.shape)
+
+
+def _state_in_whole_tiles(table):
+    """Whether the Pallas kernel may take a one-token update of the
+    ``ssm`` table (layers, B, H, P, N): Pallas kernels run here, the
+    state is float32, and the device keeps the table with ``N`` minor
+    and ``P`` next, both filling whole (8, 128) tiles, so that a block
+    of a lane's heads is the bytes as they lie."""
+    from .. import kernels
+    if not kernels.pallas_enabled() or table.dtype != jnp.float32:
+        return False
+    if table.shape[-1] % 128 or table.shape[-2] % 8:
+        return False
+    order = tuple(_resident_layout(table).major_to_minor)
+    return order == (0, 1, 2, 3, 4)
 
 
 register_op("ssm_scan", num_inputs=10, num_outputs=2,

@@ -791,7 +791,7 @@ class GenerateRunner:
             kv_argnum = len(in_structs) - 1
             t0 = time.perf_counter()
             from mxtpu import analysis
-            from ..kernels import kv_write
+            from ..kernels import kv_write, ssm_update
             compiled, source, ckey, cmeta = None, "cold", None, {}
             with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
                               kind=kind, bucket=str(bucket[1])) as rg:
@@ -811,11 +811,17 @@ class GenerateRunner:
                     jitted = jax.jit(
                         fn, donate_argnums=(kv_argnum,)
                         if apply_donate else ())
-                    with kv_write.call_sites() as traced:
-                        compiled = jitted.lower(
-                            *in_structs, self._param_structs).compile()
+                    with kv_write.call_sites() as traced, \
+                            ssm_update.call_sites() as updated:
+                        lowered = jitted.lower(
+                            *in_structs, self._param_structs)
+                    compiled = lowered.compile(compiler_options=(
+                        ssm_update.COMPILER_OPTIONS
+                        if updated[0] and self._device.platform == "tpu"
+                        else None))
                     cmeta = dict(analysis.audit_stamp(),
-                                 kv_kernel_writes=traced[0])
+                                 kv_kernel_writes=traced[0],
+                                 ssm_kernel_updates=updated[0])
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
                     if ckey is not None:
@@ -824,17 +830,22 @@ class GenerateRunner:
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
                 # the one-token writes of the KV table that this program
-                # makes by the column-store kernel (0: the lanes' loop);
-                # a program loaded from disk says what its writer traced
+                # makes by the column-store kernel (0: the lanes' loop),
+                # and the one-token updates of the ssm table it makes by
+                # the state-update kernel (0: XLA's two fusions); a
+                # program loaded from disk says what its writer traced
                 kv_kernel_writes = int(cmeta.get("kv_kernel_writes", 0))
-                rg.set(source=source, kv_kernel_writes=kv_kernel_writes)
+                ssm_kernel_updates = int(cmeta.get("ssm_kernel_updates", 0))
+                rg.set(source=source, kv_kernel_writes=kv_kernel_writes,
+                       ssm_kernel_updates=ssm_kernel_updates)
                 temp_bytes = (analysis.mem_stats(compiled) or {}).get(
                     "temp_size_in_bytes")
                 if temp_bytes is not None:
                     rg.set(temp_bytes=temp_bytes)
             self.compile_seconds[bucket] = time.perf_counter() - t0
             entry = {"compiled": compiled, "in_structs": in_structs,
-                     "kv_kernel_writes": kv_kernel_writes}
+                     "kv_kernel_writes": kv_kernel_writes,
+                     "ssm_kernel_updates": ssm_kernel_updates}
             self._entries[bucket] = entry
             self._compile_sources[bucket] = source
             if self._obs:

@@ -242,16 +242,13 @@ def test_a_prefill_write_keeps_the_lanes_loop_on_v5e(
     assert " while(" in text and "tpu_custom_call" not in text
 
 
-def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,
-                                                    no_persistent_cache):
+def _recurrent_step(one_chip):
     """The hybrid cell's recurrent state at its real widths (48 slots,
     64 heads of 64 with state 128, 4352 convolution channels; two of
     the 36 layers): one decode step through ``ssm_conv`` and
-    ``ssm_scan`` replaces each layer's plane of both tables.  The
-    chip's compiler must alias the donated tables to the results and
-    need less than one ``ssm`` plane (100 MB) of temporaries — a plane
-    rebuilt beside the table shows as a plane's worth, a table copied
-    as 75 MB a lane."""
+    ``ssm_scan`` that replaces each layer's plane of both tables.
+    ``(compiled text, memory, bytes of an ssm plane, of both tables)``
+    of that step on the described chip, the tables donated."""
     from mxtpu.ndarray import rnn_impl
     layers, slots, heads, p, n, k = 2, 48, 64, 64, 128, 4
     chan = heads * p + 2 * n
@@ -273,15 +270,136 @@ def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     from mxtpu import analysis
-    _, mem = analysis.compiled_artifact(
+    text, mem = analysis.compiled_artifact(
         step, sds(layers, slots, heads, p, n), sds(layers, slots, k - 1, chan),
         sds(layers, slots, 1, chan), sds(layers, slots, 1, heads),
         sds(chan, k), sds(chan), sds(heads), sds(heads), sds(heads),
         sds(slots), sds(slots), donate_argnums=(0, 1))
     plane = slots * heads * p * n * 4
-    tables = layers * (plane + slots * (k - 1) * chan * 4)
+    return text, mem, plane, layers * (plane + slots * (k - 1) * chan * 4)
+
+
+def test_recurrent_state_is_updated_in_place_on_v5e(one_chip,
+                                                    no_persistent_cache):
+    """XLA's form of the one-token state update (what a table the
+    kernel is refused takes; here the CPU's answers stand, so no Pallas
+    kernel is asked for): the chip's compiler must alias the donated
+    tables to the results and need less than one ``ssm`` plane (100 MB)
+    of temporaries — a plane rebuilt beside the table shows as a
+    plane's worth, a table copied as 75 MB a lane."""
+    text, mem, plane, tables = _recurrent_step(one_chip)
     assert mem["alias_size_in_bytes"] == tables
     assert mem["temp_size_in_bytes"] < plane, mem
+    assert "tpu_custom_call" not in text
+
+
+def test_recurrent_state_kernel_compiles_in_place_on_v5e(
+        one_chip, on_tpu, chip_layouts, no_persistent_cache):
+    """The same step as the chip takes it (Pallas on, the chip's own
+    layout of the table: ``N`` minor in (8, 128) tiles): each layer's
+    update is the Mosaic kernel ``ssm_state_update`` — one traced
+    kernel, two call sites, a whole lane (2 MB) a block — the donated
+    tables are aliased to the results through it, and nothing of a
+    plane's size is laid beside them."""
+    from mxtpu.kernels import ssm_update
+    from mxtpu.ndarray import rnn_impl
+    held = rnn_impl._resident_layout(
+        jax.ShapeDtypeStruct((2, 48, 64, 64, 128), jnp.float32))
+    assert held.major_to_minor == (0, 1, 2, 3, 4)
+    assert tuple(held.tiling[0]) == (8, 128)
+    with ssm_update.call_sites() as traced:
+        text, mem, plane, tables = _recurrent_step(one_chip)
+    assert traced[0] == 2
+    assert text.count("tpu_custom_call") == 2 and "ssm_state_update" in text
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < plane, mem
+
+
+def _described_runner(monkeypatch, topo, one_chip, config, inputs, cut=None,
+                      **runner_kw):
+    """A ``GenerateRunner`` of the model of ``benchmark/configs/<config>``
+    at its published widths (``cut``: a slice of its layers) on the
+    described chip: nothing can be put on such a device, so the weights
+    are bfloat16 shapes and ``jax.device_put`` hands shapes back.
+    ``runner_kw['spec']`` is called with the net for the state spec,
+    ``runner_kw['counters']`` likewise where given.  Returns the
+    runner."""
+    import json
+    import os
+    from mxtpu import symbol as sym_mod
+    from mxtpu.models.hybrid import HybridDecoderModel
+    from mxtpu.serving import GenerateRunner
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", config)) as f:
+        cfg = json.load(f)
+    if cut is not None:
+        cfg["layer_types"] = cfg["layer_types"][cut]
+    net = HybridDecoderModel.from_config(cfg)
+    out = net(*[sym_mod.var(f"data{i}") for i in range(inputs)])
+
+    class Leaf:
+        def __init__(self, shape):
+            self.shape = tuple(shape)
+
+        def asnumpy(self):
+            return jax.ShapeDtypeStruct(self.shape, jnp.bfloat16,
+                                        sharding=one_chip)
+
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda v, d=None: v if isinstance(
+        v, jax.ShapeDtypeStruct) else put(v, d))
+    monkeypatch.setattr(GenerateRunner, "_as_np",
+                        staticmethod(lambda v: v.asnumpy()))
+    spec = runner_kw.pop("spec")(net)
+    if "counters" in runner_kw:
+        runner_kw["counters"] = runner_kw["counters"](net)
+    return GenerateRunner(
+        sym_mod.Group(list(out)),
+        {p.name: Leaf(p.shape) for p in net.collect_params().values()},
+        spec, amp=True, device=topo.devices[0], cache=None, **runner_kw)
+
+
+def test_hybrid_decode_program_holds_the_kernel_and_nothing_else_moves_on_v5e(
+        topo, one_chip, on_tpu, chip_layouts, no_persistent_cache,
+        monkeypatch):
+    """The hybrid cell's decode program WHOLE (granite-4.0-h-micro: 40
+    layers at published widths, 48 slots, bfloat16 weights as shapes),
+    built by ``GenerateRunner``'s own ``_entry``: 36 state updates by
+    the kernel, the tables aliased, and the program round them left as
+    it was.  Two things a chain of such calls did to it on the chip
+    (PERF.md, PR 35): the compiler's rematerialization pass, which
+    counts each call's table out as a second table, re-laid the
+    ``conv`` table before and after every layer (``remat`` in 70
+    instruction names, 20 ms a step) — ``ssm_update.COMPILER_OPTIONS``
+    keeps it off; and the kernel's operand layout spread to the
+    projections and the convolution, a row a tile — ``_held_as_rows``
+    stops it at the mixer's edge.  Only at full depth beside 6.4 GB of
+    weights does the first show."""
+    import re
+    r = _described_runner(
+        monkeypatch, topo, one_chip, "granite_4_0_h_micro.json", 6,
+        spec=lambda net: net.state_spec(47, 1280, kv_dtype="bfloat16"),
+        prompt_buckets=(128,), max_prefill_batch=1)
+    # the tables are donated where the backend honours it
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        entry = r._entry(("decode", (48,)))
+    assert (entry["ssm_kernel_updates"], entry["kv_kernel_writes"]) == (36, 8)
+    from mxtpu import analysis
+    text, mem = analysis.executable_artifact(entry["compiled"])
+    tables = sum(r.state_bytes().values())
+    assert tables == 4_217_438_208
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < 100e6, mem     # XLA's form: 84 MB
+    assert "remat" not in text
+    assert not re.search(r"= f32\[36,48,3,4352\]\S* copy\(", text)
+    kernels_ = [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "ssm_state_update" in line]
+    assert len(kernels_) == 36
+    assert all("/ssm/state_update/" in line for line in kernels_)
+    # the input projection's product a lane a row, not a row a tile
+    assert len(re.findall(r"= f32\[48,1,8512\]\{2,0,1:T\(8,128\)\S*\} "
+                          r"fusion\(", text)) == 36
 
 
 def test_delta_state_is_updated_in_place_on_v5e(one_chip,
@@ -378,40 +496,14 @@ def test_a_prefill_reads_its_lanes_where_they_lie_on_v5e(
 def mellum_stage(topo, one_chip, monkeypatch):
     """A ``GenerateRunner`` of the routed-experts cell's model at its
     published widths, cut to two layers (a sliding one and a full one),
-    on the described chip: nothing can be put on such a device, so the
-    weights are shapes and ``jax.device_put`` hands shapes back."""
-    import json
-    import os
-    from mxtpu import symbol as sym_mod
-    from mxtpu.models.hybrid import HybridDecoderModel
-    from mxtpu.serving import GenerateRunner
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "mellum2_12b_a2_5b.json")) as f:
-        cfg = json.load(f)
-    cfg["layer_types"] = cfg["layer_types"][2:4]
-    net = HybridDecoderModel.from_config(cfg)
-    out = net(*[sym_mod.var(f"data{i}") for i in range(5)])
-
-    class Leaf:
-        def __init__(self, shape):
-            self.shape = tuple(shape)
-
-        def asnumpy(self):
-            return jax.ShapeDtypeStruct(self.shape, jnp.bfloat16,
-                                        sharding=one_chip)
-
-    put = jax.device_put
-    monkeypatch.setattr(jax, "device_put", lambda v, d=None: v if isinstance(
-        v, jax.ShapeDtypeStruct) else put(v, d))
-    monkeypatch.setattr(GenerateRunner, "_as_np",
-                        staticmethod(lambda v: v.asnumpy()))
-    return GenerateRunner(
-        sym_mod.Group(list(out)),
-        {p.name: Leaf(p.shape) for p in net.collect_params().values()},
-        net.state_spec(23, 8448, kv_dtype="bfloat16", max_chunk=256),
-        prompt_buckets=(128, 256), max_prefill_batch=1, amp=True,
-        device=topo.devices[0], cache=None, counters=net.counter_spec())
+    on the described chip (``_described_runner``)."""
+    return _described_runner(
+        monkeypatch, topo, one_chip, "mellum2_12b_a2_5b.json", 5,
+        cut=slice(2, 4),
+        spec=lambda net: net.state_spec(23, 8448, kv_dtype="bfloat16",
+                                        max_chunk=256),
+        counters=lambda net: net.counter_spec(),
+        prompt_buckets=(128, 256), max_prefill_batch=1)
 
 
 @pytest.mark.parametrize("bucket", [("decode", (24,)), ("prefill", (1, 256))],

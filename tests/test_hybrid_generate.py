@@ -73,6 +73,43 @@ def runner(net):
                           prompt_buckets=BUCKETS, cache=None)
 
 
+N_MAMBA = CFG["layer_types"].count("mamba")
+
+
+def _take_the_kernel(patch):
+    """The chip's answers, given here: Pallas kernels run (under the
+    interpreter) and the ``ssm`` table is taken for whole tiles."""
+    patch.setenv("MXTPU_PALLAS", "interpret")
+    patch.setattr(rnn_impl, "_state_in_whole_tiles", lambda t: True)
+
+
+@pytest.fixture(scope="module")
+def kernel_runner(net):
+    """The same runner with every program built as the chip builds it,
+    so the decode program's one-token state updates are the kernel's
+    (``mxtpu.kernels.ssm_update``).  What a program is made of is
+    settled when it is built, so the patches end with the warm-up."""
+    symbol, params = _export(net)
+    with pytest.MonkeyPatch.context() as m:
+        _take_the_kernel(m)
+        r = GenerateRunner(symbol, params, net.state_spec(LANES, CAP),
+                           prompt_buckets=BUCKETS, cache=None)
+        r.warmup()
+    return r
+
+
+@pytest.fixture(params=["xla", "kernel"])
+def either_runner(request):
+    """The runner whose one-token state update is XLA's two fusions,
+    and the one whose update is the kernel."""
+    name = "runner" if request.param == "xla" else "kernel_runner"
+    r = request.getfixturevalue(name)
+    if request.param == "kernel":
+        assert r._entries[("decode", (LANES + 1,))][
+            "ssm_kernel_updates"] == N_MAMBA
+    return r
+
+
 def _logits(weights, tokens):
     return np.asarray(ref.forward(CFG, weights, np.asarray(tokens)[None]))[0]
 
@@ -203,7 +240,9 @@ def test_a_six_tuple_runner_is_what_it_was(net):
 
 
 @pytest.mark.parametrize("plen", [1, 5, 8])
-def test_prefill_then_decode_equals_the_full_forward(runner, weights, plen):
+def test_prefill_then_decode_equals_the_full_forward(either_runner, weights,
+                                                     plen):
+    runner = either_runner
     seq = _prompt(plen + 6, salt=plen)
     want = _logits(weights, seq)
     (first,), kv = _prefill_rows(runner, runner.new_cache(),
@@ -264,9 +303,11 @@ def test_a_reused_lane_starts_from_zero_state(runner, weights):
         np.testing.assert_allclose(got[2], want[at], atol=TOL, rtol=0)
 
 
-def test_an_idle_lanes_recurrent_state_is_untouched_by_decode(runner):
+def test_an_idle_lanes_recurrent_state_is_untouched_by_decode(
+        either_runner):
     """A row of length 0 in the decode program (a free lane) leaves its
     lane's planes of ``ssm`` and ``conv`` bit for bit as they were."""
+    runner = either_runner
     (_,), kv = _prefill_rows(runner, runner.new_cache(),
                              [(0, _prompt(8, salt=3))], 8)
     other = _prompt(5, salt=4)
@@ -277,6 +318,32 @@ def test_an_idle_lanes_recurrent_state_is_untouched_by_decode(runner):
         _, kv = _decode(runner, kv, {1: (other[4], 4)})
     for was, table in zip(before, kv[1:]):
         assert (np.asarray(table[:, 0]) == was).all()
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["xla", "kernel"])
+def test_compile_regions_say_how_the_state_is_updated(net, monkeypatch,
+                                                      forced):
+    """``ssm_kernel_updates`` on every ``compile`` region: the decode
+    program's count of one-token state updates made by the kernel — its
+    Mamba layers where the kernel is taken, 0 where XLA's form is — and
+    0 on a prefill program, whose scan is the chunked form either way."""
+    if forced:
+        _take_the_kernel(monkeypatch)
+    symbol, params = _export(net)
+    r = GenerateRunner(symbol, params, net.state_spec(LANES, CAP),
+                       prompt_buckets=(4,), cache=None)
+    profiler.set_state("run")
+    try:
+        r.warmup([("prefill", (1, 4)), ("decode", (LANES + 1,))])
+        events = profiler.events()
+    finally:
+        profiler.set_state("stop")
+        profiler.dumps(reset=True)
+    done = {e["args"]["kind"]: int(e["args"]["ssm_kernel_updates"])
+            for e in events if e["name"] == obs.SPAN_COMPILE}
+    assert done == {"prefill": 0, "decode": N_MAMBA if forced else 0}
+    assert {k[0]: e["ssm_kernel_updates"]
+            for k, e in r._entries.items()} == done
 
 
 # ---------------------------------------------------------- the batcher

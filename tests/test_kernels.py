@@ -389,3 +389,161 @@ def test_transformer_model_odd_seq_hits_kernel():
     fallback = [x for x in w if "falling back" in str(x.message)]
     assert not fallback, [str(x.message) for x in fallback]
     assert y.shape == (2, 13, 32)
+
+
+# ------------------------------------------------------ ssm_update
+def _bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+def _state_step(rng, layers=3, B=5, H=4, P=8, N=128):
+    """A small ``ssm`` table and one token a lane: lane 0 takes its
+    first token, lane 2 is idle (``length`` 0), lane 4 is idle at
+    ``step`` 0 (a free slot)."""
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    return dict(
+        table=arr(layers, B, H, P, N), x=arr(B, 1, H * P),
+        dt=arr(B, 1, H), b_mat=arr(B, 1, N), c_mat=arr(B, 1, N),
+        a_log=arr(H), d_skip=arr(H), dt_bias=arr(H),
+        step=jnp.asarray([0.0, 5.0, 3.0, 9.0, 0.0][:B]),
+        length=jnp.asarray([1.0, 1.0, 0.0, 1.0, 0.0][:B]))
+
+
+def _scan(monkeypatch, kernel, layer, **over):
+    """``ssm_scan``'s one-token step by the kernel or by XLA's form;
+    returns ``(y, table, kernel call sites)``."""
+    from mxtpu.kernels import ssm_update
+    from mxtpu.ndarray import rnn_impl
+    with monkeypatch.context() as m:
+        if not kernel:
+            m.setattr(rnn_impl, "_state_in_whole_tiles", lambda t: False)
+        with ssm_update.call_sites() as traced:
+            y, table = rnn_impl._ssm_scan_op(layer=layer, **over)
+    return y, table, traced[0]
+
+
+@pytest.mark.parametrize("heads,p,block_heads", [
+    (4, 8, 4), (4, 16, 2), (6, 8, 1), (2, 64, 2),
+], ids=["one-block", "two-blocks", "a-head-a-block", "p64"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_ssm_update_equals_the_xla_form(monkeypatch, heads, p, block_heads,
+                                        layer):
+    """The kernel against XLA's one-token form through the op: the
+    written plane and ``y`` to float32 rounding (the sum over the state
+    axis runs in another order), every other layer's plane bit for bit
+    what it was, one traced call site."""
+    from mxtpu.kernels import ssm_update
+    monkeypatch.setattr(ssm_update, "_BLOCK_BYTES",
+                        block_heads * p * 128 * 4)
+    ssm_update._update.clear_cache()
+    ins = _state_step(np.random.default_rng(3), H=heads, P=p)
+    want_y, want, none = _scan(monkeypatch, False, layer, **ins)
+    got_y, got, sites = _scan(monkeypatch, True, layer, **ins)
+    ssm_update._update.clear_cache()
+    assert (none, sites) == (0, 1)
+    assert got_y.dtype == want_y.dtype and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[layer]),
+                               np.asarray(want[layer]), rtol=1e-6,
+                               atol=1e-6)
+    assert (_bits(got[layer]) != _bits(ins["table"][layer])).any()
+    for other in set(range(3)) - {layer}:
+        np.testing.assert_array_equal(_bits(got[other]),
+                                      _bits(ins["table"][other]))
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "xla"])
+def test_ssm_update_keeps_an_idle_lane_bit_for_bit(monkeypatch, kernel):
+    """``length`` 0 is ``dt`` 0: no decay, no input.  The lane's state,
+    a zero among it, is the bits it was — whether the lane's ``step``
+    is 0 (a free slot) or not."""
+    ins = _state_step(np.random.default_rng(4))
+    ins["table"] = ins["table"].at[1, 2, 0, 0, 0].set(0.0)
+    _, got, sites = _scan(monkeypatch, kernel, 1, **ins)
+    assert sites == int(kernel)
+    for lane in (2, 4):
+        np.testing.assert_array_equal(_bits(got[1, lane]),
+                                      _bits(ins["table"][1, lane]))
+    assert (_bits(got[1, 1]) != _bits(ins["table"][1, 1])).any()
+
+
+@pytest.mark.parametrize("held", [np.nan, np.inf, 7.0],
+                         ids=["nan", "inf", "finite"])
+def test_ssm_update_starts_a_first_token_from_zeros(monkeypatch, held):
+    """A lane with ``step`` 0 and ``length`` 1 starts from zeros
+    whatever it held: a select, not a product with 0."""
+    ins = _state_step(np.random.default_rng(5))
+    clean = dict(ins, table=ins["table"].at[1, 0].set(0.0))
+    dirty = dict(ins, table=ins["table"].at[1, 0].set(held))
+    want_y, want, _ = _scan(monkeypatch, True, 1, **clean)
+    got_y, got, sites = _scan(monkeypatch, True, 1, **dirty)
+    assert sites == 1
+    np.testing.assert_array_equal(_bits(got[1]), _bits(want[1]))
+    np.testing.assert_array_equal(_bits(got_y), _bits(want_y))
+    assert np.isfinite(np.asarray(got[1])).all()
+    assert np.abs(np.asarray(got[1, 0])).max() > 0
+
+
+def test_ssm_update_applies_d_skip(monkeypatch):
+    """``y = S C + d_skip x``: the skip term is added to the kernel's
+    read-out, head by head."""
+    ins = _state_step(np.random.default_rng(6))
+    y, _, _ = _scan(monkeypatch, True, 0, **ins)
+    bare, _, sites = _scan(monkeypatch, True, 0,
+                           **dict(ins, d_skip=jnp.zeros(4)))
+    assert sites == 1
+    skip = np.repeat(np.asarray(ins["d_skip"]), 8) * np.asarray(ins["x"])
+    assert np.abs(skip).max() > 0.1
+    np.testing.assert_allclose(np.asarray(y) - np.asarray(bare), skip,
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("why,shape,dtype", [
+    ("state-of-96", (2, 3, 4, 8, 96), jnp.float32),
+    ("p-of-4", (2, 3, 4, 4, 128), jnp.float32),
+    ("bfloat16-table", (2, 3, 4, 16, 128), jnp.bfloat16),
+])
+def test_ssm_update_is_refused_what_is_not_whole_tiles(monkeypatch, why,
+                                                       shape, dtype):
+    """A state that does not fill whole (8, 128) float32 tiles takes
+    XLA's form, decided from the table alone."""
+    from mxtpu.ndarray import rnn_impl
+    layers, B, H, P, N = shape
+    ins = _state_step(np.random.default_rng(7), layers, B, H, P, N)
+    ins["table"] = ins["table"].astype(dtype)
+    assert not rnn_impl._state_in_whole_tiles(ins["table"])
+    y, got, sites = _scan(monkeypatch, True, 1, **ins)
+    want_y, want, _ = _scan(monkeypatch, False, 1, **ins)
+    assert sites == 0 and got.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
+def test_ssm_update_is_taken_by_what_is_observed(monkeypatch):
+    """One token a lane, Pallas kernels on, a float32 table the device
+    keeps with ``N`` minor: those together, and nothing a caller
+    sets; ``T`` > 1 keeps the chunked scan."""
+    from jax.experimental.layout import Layout
+    from mxtpu import kernels
+    from mxtpu.ndarray import rnn_impl
+    table = jnp.zeros((1, 2, 2, 8, 128))
+    assert rnn_impl._state_in_whole_tiles(table)    # interpreter, row-major
+    for order, whole in (((0, 1, 2, 3, 4), True), ((0, 1, 2, 4, 3), False),
+                         ((0, 1, 3, 4, 2), False)):
+        monkeypatch.setattr(
+            rnn_impl, "_resident_layout",
+            lambda x, order=order: Layout(major_to_minor=order,
+                                          tiling=((8, 128),)))
+        assert rnn_impl._state_in_whole_tiles(table) is whole
+    monkeypatch.undo()
+    monkeypatch.setattr(kernels, "pallas_enabled", lambda: False)
+    assert not rnn_impl._state_in_whole_tiles(table)
+    monkeypatch.undo()
+    monkeypatch.setenv("MXTPU_PALLAS", "interpret")
+    ins = _state_step(np.random.default_rng(8))
+    two = {k: jnp.concatenate([ins[k]] * 2, axis=1)
+           for k in ("x", "dt", "b_mat", "c_mat")}
+    _, _, sites = _scan(monkeypatch, True, 0, **dict(ins, **two))
+    assert sites == 0

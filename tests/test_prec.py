@@ -407,7 +407,8 @@ def test_amp_policy_is_machine_derived():
     assert "exponential" in policy["deny"]
     assert "reduce" in policy["fp32_force"]
     assert set(policy["custom_calls"]) == \
-        {"batch_norm", "flash_attention", "layer_norm", "kv_write"}
+        {"batch_norm", "flash_attention", "layer_norm", "kv_write",
+         "ssm_update"}
     for name, meta in policy["custom_calls"].items():
         # the column store of the KV table only moves bits
         assert meta["accum_dtype"] == \
