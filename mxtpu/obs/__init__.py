@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 from .. import knobs
 from ..base import MXNetError
+from . import gcpause as gcpause
 from . import http as http
 from . import metrics as metrics
 from . import recorder as recorder
@@ -63,14 +64,17 @@ from .trace import (NULL_REGION, SPAN_BACKOFF, SPAN_COMPILE,
                     SPAN_SHED, SPAN_STAGE, SPAN_STEAL, SPAN_SUBMIT,
                     SPAN_TOKEN, SPAN_TRAIN_DISPATCH, SPAN_TRAIN_PREP,
                     SPAN_TRAIN_STEP, SPAN_TRAIN_WRITEBACK,
-                    new_trace_id, region, region_writer, span,
-                    trace_of)
+                    SPAN_DECODE_ROWS, SPAN_PREFILL_ROWS, SPAN_COMMIT,
+                    SPAN_COMPLETE, SPAN_BETWEEN,
+                    new_trace_id, recording, region, region_writer,
+                    span, trace_of)
 
 __all__ = [
     "enabled", "registry", "counter", "gauge", "histogram",
     "prometheus_text", "snapshot", "summary", "reset",
     "flight", "flight_recorders", "dump_all", "dump_on_error_path",
     "new_trace_id", "span", "region", "region_writer", "trace_of",
+    "recording", "gc_pauses",
     "self_check",
     "sampler", "slo_engine", "debug_server",
     "MetricsRegistry", "FlightRecorder", "Sampler", "SLOEngine",
@@ -87,7 +91,8 @@ __all__ = [
     "SPAN_DECODE", "SPAN_SAMPLE", "SPAN_FIRE", "SPAN_COMPILE",
     "SPAN_TRAIN_STEP", "SPAN_TRAIN_PREP", "SPAN_TRAIN_DISPATCH",
     "SPAN_TRAIN_WRITEBACK", "SPAN_STAGE", "SPAN_DISPATCH",
-    "SPAN_FETCH", "SPAN_DONE", "NULL_REGION",
+    "SPAN_FETCH", "SPAN_DONE", "NULL_REGION", "SPAN_DECODE_ROWS",
+    "SPAN_PREFILL_ROWS", "SPAN_COMMIT", "SPAN_COMPLETE", "SPAN_BETWEEN",
 ]
 
 _REGISTRY = MetricsRegistry()
@@ -99,6 +104,15 @@ _SAMPLER: Optional[Sampler] = None       # guarded-by: _FLIGHT_LOCK
 def enabled() -> bool:
     """Observability on?  ``MXTPU_OBS`` (default on; ``0`` = off)."""
     return bool(knobs.get("MXTPU_OBS"))
+
+
+# the collector's pauses: hooked once, at import, if observability is
+# on then; exported as mxtpu_gc_pause_seconds_total while it is on
+gc_pauses = gcpause.thread_pauses
+if enabled():
+    gcpause.install()
+_REGISTRY.add_collector(
+    lambda reg: gcpause.export(reg) if enabled() else None)
 
 
 def registry() -> MetricsRegistry:

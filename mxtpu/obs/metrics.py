@@ -29,7 +29,8 @@ from __future__ import annotations
 import re
 import threading
 from bisect import bisect_left
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, Optional,
+                    Sequence, Tuple)
 
 from ..base import MXNetError
 
@@ -369,6 +370,10 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._metrics: Dict[str, _Family] = {}  # guarded-by: _lock
+        # called with the registry before each export, outside the lock:
+        # instruments whose source cannot take a lock bring their
+        # families up to date here (mxtpu.obs.gcpause)
+        self._collectors: List[Callable[["MetricsRegistry"], None]] = []  # guarded-by: _lock
 
     def _get_or_create(self, kind: str, name: str, help: str,
                        labels: Sequence[str],
@@ -408,7 +413,18 @@ class MetricsRegistry:
         with self._lock:
             return sorted(self._metrics)
 
+    def add_collector(self, fn: Callable[["MetricsRegistry"], None]
+                      ) -> None:
+        """Run ``fn(self)`` before every export; kept across
+        :meth:`reset`, which drops only the families."""
+        with self._lock:
+            self._collectors.append(fn)
+
     def _families(self) -> List[_Family]:
+        with self._lock:
+            collectors = list(self._collectors)
+        for fn in collectors:
+            fn(self)
         with self._lock:
             return [self._metrics[n] for n in sorted(self._metrics)]
 

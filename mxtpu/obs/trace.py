@@ -44,7 +44,9 @@ __all__ = ["new_trace_id", "span", "region", "region_writer",
            "SPAN_DECODE", "SPAN_SAMPLE", "SPAN_FIRE", "SPAN_COMPILE",
            "SPAN_TRAIN_STEP", "SPAN_TRAIN_PREP", "SPAN_TRAIN_DISPATCH",
            "SPAN_TRAIN_WRITEBACK", "SPAN_STAGE", "SPAN_DISPATCH",
-           "SPAN_FETCH", "SPAN_DONE"]
+           "SPAN_FETCH", "SPAN_DONE", "SPAN_DECODE_ROWS",
+           "SPAN_PREFILL_ROWS", "SPAN_COMMIT", "SPAN_COMPLETE",
+           "SPAN_BETWEEN", "recording"]
 
 # Request-phase span names (the committed vocabulary; tests and the
 # README's reconstruction example key off these).
@@ -90,6 +92,15 @@ SPAN_STAGE = "/stage"
 SPAN_DISPATCH = "/dispatch"
 SPAN_FETCH = "/fetch"
 SPAN_DONE = "/done"
+# the serve loop's host work between those, one leaf each (ISSUE 36):
+# the decode's host rows, each prefill chunk's, the lane-table commits
+# under the batcher's lock, the futures' completions, and the serving
+# thread's work between two steps
+SPAN_DECODE_ROWS = "gen/decode_rows"
+SPAN_PREFILL_ROWS = "gen/prefill/rows"
+SPAN_COMMIT = "gen/commit"
+SPAN_COMPLETE = "gen/complete"
+SPAN_BETWEEN = "gen/between"
 # what every region's TraceAnnotation is named by in the xplane
 REGION_PREFIX = "mxtpu:"
 
@@ -208,6 +219,13 @@ def region(name: str, trace_id: Optional[str] = None,
     It reads no knob: an owner asks :func:`region_writer` once, at
     construction, as it asks for its instruments."""
     return _Region(name, trace_id, counts)
+
+
+def recording() -> bool:
+    """Is a trace being written (a ``jax.profiler`` session, or
+    ``mxtpu.profiler``)?  What an owner asks once per step before it
+    reads a clock only a region's counts want."""
+    return TraceAnnotation.is_enabled() or profiler.is_active()
 
 
 def _null_region(name: str, trace_id: Optional[str] = None,

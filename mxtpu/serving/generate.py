@@ -976,43 +976,47 @@ class GenerateRunner:
         """One executable call, in the region ``name`` with its three
         children: the host rows staged on the device, the call itself
         with the search for each row's first maximum behind it, and
-        what comes back — those maxima (``logits_bytes`` counts the
-        logits the call made, ``fetched_bytes`` what the fetch brought
-        over: 4 bytes a row)."""
+        what comes back — those maxima, 4 bytes a row.
+        The call writes a closing child only for a graph's late counts
+        (``moe_experts_touched``)."""
         import jax
         tokens = counts.get("tokens", counts.get("active", 0))
         counts = dict(counts, **{n: tokens * by for n, by
                                  in self._token_counters.items()})
+        # the host work between the children lies inside them: the
+        # entry's lookup in the staging, the staged rows' release in the
+        # dispatch, the graph's counts read in the fetch
         with self._region(name, **counts) as rg:
-            entry = self._entry(bucket)
             with self._region(name + obs.SPAN_STAGE):
+                entry = self._entry(bucket)
                 staged = [
                     jax.device_put(np.asarray(a, np.float32),  # mxlint: sync-point — staging host rows for device_put
                                    self._device)
                     for a in host_rows]
-            if self._guards:
-                self._churn.note_call()
+                if self._guards:
+                    self._churn.note_call()
             with self._region(name + obs.SPAN_DISPATCH), \
                     guards.no_implicit_transfers(self._guards):
                 logits, kv, *counted = entry["compiled"](
                     *staged, kv, self._param_vals)
                 first = self._first_maximum_of(entry, logits, *counted)(
                     logits, *counted)
+                del staged, counted
             with self._region(name + obs.SPAN_FETCH):
                 # mxlint: sync-point — deliberate D2H: token ids (and the graph's counts behind them)
                 first = np.asarray(first)
-            rg.set(logits_bytes=logits.nbytes, fetched_bytes=first.nbytes,
-                   kv_kernel_writes=entry["kv_kernel_writes"])
+                if self._m_counted:
+                    rows = logits.shape[0]
+                    counts.update(zip(self._device_counters,
+                                      (int(c) for c in first[rows:])))
+                    first = first[:rows]
+                    if self._obs:
+                        for n, m in self._m_counted.items():
+                            m.inc(counts[n])
+                out = DeviceLogits(logits, first)
             if self._m_counted:
-                rows = logits.shape[0]
-                counts.update(zip(self._device_counters,
-                                  (int(c) for c in first[rows:])))
-                first = first[:rows]
                 rg.set(**{n: counts[n] for n in self._device_counters})
-                if self._obs:
-                    for n, m in self._m_counted.items():
-                        m.inc(counts[n])
-        return DeviceLogits(logits, first), kv
+        return out, kv
 
     # -- introspection / contracts ----------------------------------------
     def default_bucket(self, kind: str = "decode") -> Tuple:
@@ -1285,8 +1289,18 @@ class GenerateBatcher:
             self._step_no += 1
             with self._region(obs.SPAN_GEN_STEP, step=self._step_no,
                               max_lanes=self.max_lanes) as rg:
+                # the thread's CPU time and collector pauses over the
+                # step, read only while a trace is written
+                timed = self._obs and obs.recording()
+                if timed:
+                    cpu0, (gc0, gcn0) = (time.thread_time_ns(),
+                                         obs.gc_pauses())
                 out = self._step_locked(
                     self._clock() if now is None else now)
+                if timed:
+                    gc1, gcn1 = obs.gc_pauses()
+                    rg.set(cpu_us=(time.thread_time_ns() - cpu0) // 1000,
+                           gc_us=(gc1 - gc0) // 1000, gc_n=gcn1 - gcn0)
                 rg.set(**out)
             return out
 
@@ -1320,17 +1334,22 @@ class GenerateBatcher:
         if admitted:
             self._prefill_locked(admitted, now, emissions, finished,
                                  completions)
-        with self._cond:
-            active = [(i, l) for i, l in enumerate(self._lanes)
-                      if l is not None]
-        if self._obs:
-            self._m_lanes.set(len(active))
+        with self._region(obs.SPAN_DECODE_ROWS, step=self._step_no):
+            with self._cond:
+                active = [(i, l) for i, l in enumerate(self._lanes)
+                          if l is not None]
+            if self._obs:
+                self._m_lanes.set(len(active))
+            rows = self._decode_rows(active) if active else None
         if active:
-            self._decode_locked(active, emissions, finished,
+            self._decode_locked(active, rows, emissions, finished,
                                 completions)
         self._fire(emissions, self._step_no)
-        for r, value in completions:
-            r._complete(value, now)
+        if completions:
+            with self._region(obs.SPAN_COMPLETE, step=self._step_no,
+                              requests=len(completions)):
+                for r, value in completions:
+                    r._complete(value, now)
         return {"admitted": len(admitted), "active": len(active),
                 "emitted": len(emissions),
                 "finished": len(finished), "queued": queued}
@@ -1427,36 +1446,44 @@ class GenerateBatcher:
                           chunks=chunks,
                           trace_ids=[r.trace_id for _, r in pairs
                                      if r.trace_id is not None]):
-            if self._kv is None:
-                self._kv = runner.new_cache()
-            first_logits: List[Optional[DeviceLogits.Row]] = \
-                [None] * len(pairs)
+            # the call of the chunk that holds each prompt's end kept
+            # that position's row for its lane
+            ends = [(n - 1) // s for n in need]
+            kept: Dict[int, DeviceLogits] = {}
             for c in range(chunks):
                 base = c * s
-                tokens = np.zeros((b, s), np.float32)
-                step = np.zeros((b,), np.float32)
-                length = np.zeros((b,), np.float32)
-                lidx = np.full((b,), runner.scratch_slot, np.float32)
-                for row, (lane, r) in enumerate(pairs):
-                    if base >= need[row]:
-                        continue  # this row finished in an earlier chunk
-                    valid = min(s, need[row] - base)
-                    tokens[row, :valid] = full[row][base:base + valid]
-                    # step 0 starts the lane's recurrent state from
-                    # zero; a later chunk carries it on; the padded
-                    # positions past ``valid`` leave it as it is
-                    step[row] = base
-                    length[row] = valid
-                    lidx[row] = lane
+                with self._region(obs.SPAN_PREFILL_ROWS, chunk=c):
+                    if self._kv is None:
+                        # the slot table: the first call's input too
+                        self._kv = runner.new_cache()
+                    tokens = np.zeros((b, s), np.float32)
+                    step = np.zeros((b,), np.float32)
+                    length = np.zeros((b,), np.float32)
+                    lidx = np.full((b,), runner.scratch_slot, np.float32)
+                    for row, (lane, r) in enumerate(pairs):
+                        if base >= need[row]:
+                            continue  # this row finished in an earlier chunk
+                        valid = min(s, need[row] - base)
+                        tokens[row, :valid] = full[row][base:base + valid]
+                        # step 0 starts the lane's recurrent state from
+                        # zero; a later chunk carries it on; the padded
+                        # positions past ``valid`` leave it as it is
+                        step[row] = base
+                        length[row] = valid
+                        lidx[row] = lane
                 logits, self._kv = runner.prefill(tokens, step, lidx,
                                                   self._kv, length)
-                for row in range(len(pairs)):
-                    last = need[row] - 1
-                    if base <= last < base + s:
-                        # the chunk that holds the prompt's end: its
-                        # call kept that position's row for this lane
-                        first_logits[row] = logits[row, 0]
-            with self._cond:
+                if c in ends:
+                    kept[c] = logits
+            with self._region(obs.SPAN_SAMPLE, step=self._step_no,
+                              lanes=len(pairs)):
+                # need[row]: the absolute position of the 1st new token
+                firsts = [sample_token(kept[ends[row]][row, 0],
+                                       position=need[row], seed=r.seed,
+                                       top_k=r.top_k)
+                          for row, (_, r) in enumerate(pairs)]
+            with self._region(obs.SPAN_COMMIT, step=self._step_no), \
+                    self._cond:
                 if self._closed:
                     # the batcher died between admit and commit: these
                     # joiners were already off the queue, so close()
@@ -1470,45 +1497,39 @@ class GenerateBatcher:
                             finished.append(r)
                     return
                 self._commit_first_tokens_locked(
-                    pairs, need, first_logits, emissions, finished,
+                    pairs, need, firsts, emissions, finished,
                     completions)
                 self._cond.notify_all()
 
-    def _commit_first_tokens_locked(self, pairs, need, first_logits,
+    def _commit_first_tokens_locked(self, pairs, need, firsts,
                                     emissions, finished, completions
                                     ) -> None:
-        """Sample each joiner's first token and seat it in its lane
-        (under ``_cond``).  The tokens exist only now, after the
-        prefill: TTFT and the lanes' next gaps count from this reading
-        of the batcher's clock, not from the step's start."""
+        """Seat each joiner's sampled first token in its lane (under
+        ``_cond``).  The tokens are the joiners' only once seated: TTFT
+        and the lanes' next gaps count from this reading of the
+        batcher's clock, the lock's wait included, not from the step's
+        start."""
         t_emit = self._clock()
-        with self._region(obs.SPAN_SAMPLE, step=self._step_no,
-                          lanes=len(pairs)):
-            for row, (lane, r) in enumerate(pairs):
-                pos = need[row]  # absolute position of the 1st new token
-                tok = sample_token(first_logits[row], position=pos,
-                                   seed=r.seed, top_k=r.top_k)
-                ln = _Lane(r, frontier=need[row], last_token=tok,
-                           t_last=t_emit)
-                r.tokens.append(tok)
-                emissions.append((r, tok, len(r.prefix), True,
-                                  t_emit - r.t_submit))
-                reason = self._finish_reason(r, ln)
-                if reason is not None:
-                    r.finish_reason = reason
-                    completions.append(
-                        (r, list(r.prefix) + list(r.tokens)))
-                    finished.append(r)
-                else:
-                    self._lanes[lane] = ln
+        for row, (lane, r) in enumerate(pairs):
+            tok = firsts[row]
+            ln = _Lane(r, frontier=need[row], last_token=tok,
+                       t_last=t_emit)
+            r.tokens.append(tok)
+            emissions.append((r, tok, len(r.prefix), True,
+                              t_emit - r.t_submit))
+            reason = self._finish_reason(r, ln)
+            if reason is not None:
+                r.finish_reason = reason
+                completions.append(
+                    (r, list(r.prefix) + list(r.tokens)))
+                finished.append(r)
+            else:
+                self._lanes[lane] = ln
 
-    def _decode_locked(self, active: List[Tuple[int, _Lane]],
-                       emissions, finished, completions) -> None:
-        """ONE decode dispatch over the whole slot table (each lane's
-        last token written at its own frontier), then per-lane
-        sampling, finish evaluation, and lane release."""
-        runner = self.runner
-        slots = runner.max_lanes + 1
+    def _decode_rows(self, active: List[Tuple[int, _Lane]]):
+        """The decode's host rows: each lane's last token, written at
+        its own frontier; idle slots decode nothing."""
+        slots = self.runner.max_lanes + 1
         tokens = np.zeros((slots, 1), np.float32)
         steps = np.zeros((slots,), np.float32)
         length = np.zeros((slots,), np.float32)
@@ -1516,18 +1537,26 @@ class GenerateBatcher:
             tokens[i, 0] = lane.last_token
             steps[i] = lane.frontier
             length[i] = 1
+        return tokens, steps, length
+
+    def _decode_locked(self, active: List[Tuple[int, _Lane]], rows,
+                       emissions, finished, completions) -> None:
+        """ONE decode dispatch over the whole slot table (``rows``:
+        :meth:`_decode_rows`), then per-lane sampling, finish
+        evaluation, and lane release."""
         # a greedy lane needs its slot's first maximum and nothing else
         # of 400 KB of logits: the runner finds it on the device, and a
         # host whose argmax runs at half speed in one process of two
         # (PERF.md, PR 32) no longer sets the step's length
-        logits, self._kv = runner.decode(tokens, steps, self._kv,
-                                         length)
-        # the tokens exist only now, after the decode: gaps are read
-        # off the batcher's clock here, not at the step's start
-        t_emit = self._clock()
+        tokens, steps, length = rows
+        logits, self._kv = self.runner.decode(tokens, steps, self._kv,
+                                              length)
         done: List[Tuple[int, _Lane, str]] = []
         with self._region(obs.SPAN_SAMPLE, step=self._step_no,
                           lanes=len(active)):
+            # the tokens exist only now, after the decode: gaps are read
+            # off the batcher's clock here, not at the step's start
+            t_emit = self._clock()
             for i, lane in active:
                 r = lane.req
                 lane.frontier += 1   # last_token is now in the cache
@@ -1542,7 +1571,8 @@ class GenerateBatcher:
                 reason = self._finish_reason(r, lane)
                 if reason is not None:
                     done.append((i, lane, reason))
-        with self._cond:
+        with self._region(obs.SPAN_COMMIT, step=self._step_no), \
+                self._cond:
             self._steps += 1
             for i, lane, reason in done:
                 if self._lanes[i] is lane:

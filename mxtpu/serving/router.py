@@ -314,6 +314,7 @@ class FleetWorker:
         # firings, evictions — dumped by the router on death.  The
         # shared no-op when MXTPU_OBS=0.
         self.recorder = obs.flight(f"fleet/{name}", clock=clock)
+        self._region = obs.region_writer(obs.enabled())
         self.batcher = DynamicBatcher(
             max_batch_size=runner.max_batch_size if runner is not None
             else 1,
@@ -467,7 +468,9 @@ class FleetWorker:
         if self._stop.is_set() or \
                 self.health.state == WorkerState.DEAD:
             return False
-        if self.generator.drain():
+        with self._region(obs.SPAN_BETWEEN):
+            idle = self.generator.drain()
+        if idle:
             return False
         try:
             out = self.generator.step(now)

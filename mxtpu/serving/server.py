@@ -54,6 +54,7 @@ class _GenEndpoint:
             runner, max_queue=max_queue, stats=self.stats,
             on_timeout=self.stats.record_timeout)
         self._stop = threading.Event()
+        self._region = obs.region_writer(obs.enabled())
         self.thread = threading.Thread(
             target=self._work, daemon=True,
             name=f"mxtpu-gen-{name}-v{version}")
@@ -63,7 +64,9 @@ class _GenEndpoint:
 
     def _work(self) -> None:
         while not self._stop.is_set():
-            if self.batcher.drain():
+            with self._region(obs.SPAN_BETWEEN):
+                idle = self.batcher.drain()
+            if idle:
                 # idle: no lanes, no queue — park briefly
                 self._stop.wait(0.005)
                 continue
@@ -77,7 +80,8 @@ class _GenEndpoint:
                 self.stats.bump("step_failures")
                 self._stop.wait(0.01)
                 continue
-            self.stats.maybe_log()
+            with self._region(obs.SPAN_BETWEEN):
+                self.stats.maybe_log()
 
     def stop(self) -> None:
         # same wind-down order as _Endpoint: let the stepping thread

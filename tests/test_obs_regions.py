@@ -131,27 +131,28 @@ GEN_PARENTS = {
     "gen/admit": "gen/step", "gen/prefill": "gen/step",
     "gen/decode": "gen/step", "gen/fire": "gen/step",
     "gen/step/done": "gen/step", "gen/admit/done": "gen/admit",
+    "gen/decode_rows": "gen/step", "gen/complete": "gen/step",
+    "gen/prefill/rows": "gen/prefill",
     "gen/prefill/call": "gen/prefill",
     "gen/prefill/call/stage": "gen/prefill/call",
     "gen/prefill/call/dispatch": "gen/prefill/call",
     "gen/prefill/call/fetch": "gen/prefill/call",
-    "gen/prefill/call/done": "gen/prefill/call",
     "gen/decode/stage": "gen/decode",
     "gen/decode/dispatch": "gen/decode",
-    "gen/decode/fetch": "gen/decode", "gen/decode/done": "gen/decode",
+    "gen/decode/fetch": "gen/decode",
 }
 GEN_COUNTS = {
     "gen/step": {"step", "max_lanes"},
     "gen/step/done": {"active", "admitted", "queued", "emitted",
-                      "finished"},
+                      "finished", "cpu_us", "gc_us", "gc_n"},
     "gen/admit/done": {"admitted", "evicted", "wait_us_sum"},
     "gen/prefill": {"rows", "rung", "bucket", "chunks"},
+    "gen/prefill/rows": {"chunk"},
     "gen/prefill/call": {"rows", "bucket"},
-    "gen/prefill/call/done": {"logits_bytes", "fetched_bytes"},
     "gen/decode": {"slots"},
-    "gen/decode/done": {"logits_bytes", "fetched_bytes",
-                        "kv_kernel_writes"},
     "gen/sample": {"lanes"}, "gen/fire": {"tokens"},
+    "gen/decode_rows": {"step"}, "gen/commit": {"step"},
+    "gen/complete": {"requests"},
 }
 
 
@@ -169,9 +170,11 @@ def test_generate_regions_land_in_the_xplane(runner, tmp_path):
     for name, keys in GEN_COUNTS.items():
         for ev in s.named(name):
             assert keys <= set(ev[3]), (name, ev[3])
-    # first tokens are sampled inside the prefill, later ones in the step
-    assert {s.parent_of(e) for e in s.named("gen/sample")} == \
-        {"gen/prefill", "gen/step"}
+    # first tokens are sampled and seated inside the prefill, later ones
+    # in the step
+    for name in ("gen/sample", "gen/commit"):
+        assert {s.parent_of(e) for e in s.named(name)} == \
+            {"gen/prefill", "gen/step"}, name
     # the counts are the step's own: both requests join in step 1, on
     # the rung of two rows and the bucket of four, after no wait on a
     # clock that stands still
@@ -184,20 +187,189 @@ def test_generate_regions_land_in_the_xplane(runner, tmp_path):
     pre = s.named("gen/prefill")[0][3]
     assert (pre["rows"], pre["rung"], pre["bucket"], pre["chunks"]) == \
         (2, 2, 4, 1)
-    vocab_bytes = 4 * V
-    # either call makes one row of logits a row, which stays on the
-    # device, and a token id a row crosses
-    assert s.named("gen/prefill/call/done")[0][3]["logits_bytes"] == \
-        2 * vocab_bytes
-    assert s.named("gen/decode/done")[0][3]["logits_bytes"] == \
-        (LANES + 1) * vocab_bytes
-    assert s.named("gen/prefill/call/done")[0][3]["fetched_bytes"] == \
-        2 * 4
-    assert s.named("gen/decode/done")[0][3]["fetched_bytes"] == \
-        (LANES + 1) * 4
+    # the calls' constant counts are gone: a bertgen-shaped graph (one
+    # table, no counts of its own) writes no closing child of a call
+    assert s.named("gen/prefill/call/done") == []
+    assert s.named("gen/decode/done") == []
+    for ev in s.events:
+        assert not {"logits_bytes", "fetched_bytes"} & set(ev[3]), ev
     assert s.named("gen/decode")[0][3]["slots"] == LANES + 1
     steps = [e[3]["step"] for e in s.named("gen/step")]
     assert steps == list(range(1, len(steps) + 1))
+
+
+def _leaves(s):
+    """The session's leaf regions: no other region of its thread lies
+    inside (closing children are not regions)."""
+    regions = [e for e in s.events if not e[0].endswith("/done")]
+    return [e for e in regions
+            if not any(o is not e and o[4] == e[4] and e[1] <= o[1]
+                       and o[2] <= e[2] for o in regions)]
+
+
+def test_leaves_cover_every_step(runner, tmp_path, monkeypatch):
+    """Every stretch of the step's host work lies in one leaf: on every
+    step the leaves inside ``gen/step`` cover 98 % of its wall time or
+    more, none of them overlaps another, and the new leaves are all
+    there.  Each program takes 20 ms, as a decode step takes 11–30 on
+    the chip: the tiny model's own microseconds would leave the regions'
+    bookkeeping (a few microseconds each while a session runs) as the
+    step's largest part.  A loaded test machine can take the thread off
+    its CPU between two leaves for milliseconds, so the loop is served
+    twice and one of the two has to hold on every step: a leaf that is
+    missing leaves its gap in both."""
+    import time
+    for entry in runner._entries.values():
+        run = entry["compiled"]
+        monkeypatch.setitem(entry, "compiled",
+                            lambda *a, _run=run: (time.sleep(0.02),
+                                                  _run(*a))[1])
+    short = []
+    for attempt in range(2):
+        with _Session(tmp_path / str(attempt)) as s:
+            _serve(runner, prompts=((1, 2, 3), (4, 5, 6, 7)), max_tokens=6)
+            _serve(runner, prompts=(tuple(range(1, 11)),), max_tokens=3)
+        leaves = _leaves(s)
+        names = {e[0] for e in leaves}
+        assert {"gen/admit", "gen/prefill/rows", "gen/decode_rows",
+                "gen/sample", "gen/commit", "gen/fire", "gen/complete",
+                "gen/decode/fetch", "gen/prefill/call/fetch"} <= names, names
+        steps = s.named("gen/step")
+        assert len(steps) >= 6
+        short = []
+        for st in steps:
+            inside = sorted((e[1], e[2]) for e in leaves
+                            if e[4] == st[4] and st[1] <= e[1]
+                            and e[2] <= st[2])
+            for (_, end), (start, _) in zip(inside, inside[1:]):
+                assert end <= start
+            covered = sum(b - a for a, b in inside)
+            if covered < 0.98 * (st[2] - st[1]):
+                short.append((st[3]["step"], covered / (st[2] - st[1])))
+        if not short:
+            break
+    assert not short, short
+
+
+def test_fetch_is_one_leaf(runner, tmp_path):
+    """``/fetch`` of either call is one leaf: the one blocking copy of
+    the token ids, with nothing of its own inside (a split into a wait
+    and a copy cost the untraced run a second sync point a call)."""
+    with _Session(tmp_path) as s:
+        _serve(runner)
+    leaves = {e[0] for e in _leaves(s)}
+    for call in ("gen/decode", "gen/prefill/call"):
+        fetches = s.named(call + "/fetch")
+        assert fetches
+        for f in fetches:
+            assert not [e for e in s.events if e[4] == f[4] and e is not f
+                        and f[1] <= e[1] and e[2] <= f[2]]
+        assert call + "/fetch" in leaves
+
+
+def test_step_counts_its_cpu_time_and_its_collections(runner, tmp_path):
+    """``gen/step`` closes with the serving thread's CPU time over the
+    step, never more than the step's wall time, and the collector's
+    pauses on that thread: an ``on_token`` that collects makes its
+    step's ``gc_n`` one or more and ``gc_us`` positive."""
+    import gc
+    b = GenerateBatcher(runner, clock=FakeClock())
+    quiet = b.submit([1, 2, 3], max_tokens=8)
+    collecting = set()
+
+    def collect(tok, i):
+        collecting.add(b._step_no)
+        gc.collect()
+
+    with _Session(tmp_path) as s:
+        for _ in range(3):
+            b.step()
+        loud = b.submit([4, 5], max_tokens=2, on_token=collect)
+        while not (quiet.done() and loud.done()):
+            b.step()
+    steps, done = s.named("gen/step"), s.named("gen/step/done")
+    assert len(done) == len(steps) >= 7
+    for st, end in zip(steps, done):
+        assert 0 <= end[3]["cpu_us"] * 1000 <= st[2] - st[1]
+    assert collecting and collecting.isdisjoint({1, 2, 3})
+    for st, end in zip(steps, done):
+        if st[3]["step"] in collecting:
+            assert end[3]["gc_n"] >= 1 and end[3]["gc_us"] > 0, end
+
+
+def test_no_session_reads_no_thread_clock(runner, monkeypatch):
+    """With no session the step reads neither the thread's CPU clock
+    nor the collector's tallies: one boolean check a step."""
+    import time as time_mod
+
+    def boom():
+        raise AssertionError("thread_time_ns read with no session")
+
+    monkeypatch.setattr(time_mod, "thread_time_ns", boom)
+    monkeypatch.setattr(obs, "gc_pauses", boom)
+    streams, _ = _serve(runner)
+    assert all(len(t) == 4 for t in streams)
+
+
+def test_gc_pauses_are_the_operators_counter_too():
+    """The same hook counts the process's pauses by generation for
+    ``/metrics``; a collection on any thread moves its generation's
+    series and that thread's own tally."""
+    import gc
+    import threading
+    before = obs.gc_pauses()
+    got = {}
+
+    def work():
+        got["start"] = obs.gc_pauses()
+        gc.collect()
+        got["end"] = obs.gc_pauses()
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join()
+    assert got["start"] == (0, 0)
+    assert got["end"][1] == 1 and got["end"][0] > 0
+    assert obs.gc_pauses() == before     # not this thread's
+    text = obs.prometheus_text()
+    samples = obs.parse_prometheus_text(text)
+    gen2 = samples[("mxtpu_gc_pause_seconds_total",
+                    (("generation", "2"),))]
+    assert gen2 > 0
+    assert "# TYPE mxtpu_gc_pause_seconds_total counter" in text
+    gc.collect()
+    again = obs.parse_prometheus_text(obs.prometheus_text())
+    assert again[("mxtpu_gc_pause_seconds_total",
+                  (("generation", "2"),))] > gen2
+
+
+def test_server_loop_writes_its_between_leaf(export, tmp_path):
+    """The serving thread's work between two steps (``drain``, the
+    stats' log) is a leaf of its own, outside ``gen/step``; the fleet
+    worker's stepping path writes the same leaf."""
+    from mxtpu.serving.router import FleetWorker
+    srv = InferenceServer()
+    srv.register_generator("bert", _runner(export))
+    try:
+        with _Session(tmp_path / "server") as s:
+            req = srv.submit_generate("bert", [1, 2, 3], max_tokens=5)
+            assert len(req.result(timeout=60.0)) == 5
+    finally:
+        srv.close()
+    between = s.named("gen/between")
+    assert between and all(s.parent_of(e) is None for e in between)
+    assert "gen/between" in {e[0] for e in _leaves(s)}
+    w = FleetWorker(None, "w0", clock=FakeClock(),
+                    gen_runner=_runner(export))
+    with _Session(tmp_path / "fleet") as s:
+        req = w.generator.submit([1, 2, 3], max_tokens=2)
+        for _ in range(10):
+            w.pump_generate()
+            if req.done():
+                break
+    assert len(req.result(0)) == 2
+    assert s.named("gen/between")
+    assert all(s.parent_of(e) is None for e in s.named("gen/between"))
 
 
 @pytest.fixture(scope="module")
@@ -231,7 +403,8 @@ def state_spec_runner():
 def test_a_prefill_call_fetches_four_bytes_a_row(graph, request, tmp_path):
     """Whichever kind of graph: every ``gen/prefill/call`` — a rung of
     two, a rung of one, the second chunk of a long prompt — makes one
-    row of logits a row and brings over its first maximum, 4 bytes."""
+    row of logits a row and brings over its first maximum, 4 bytes,
+    in one fetch; no closing child says so any more."""
     r = request.getfixturevalue(
         "runner" if graph == "one_table" else "state_spec_runner")
     vocab = V if graph == "one_table" else V + 3
@@ -240,13 +413,18 @@ def test_a_prefill_call_fetches_four_bytes_a_row(graph, request, tmp_path):
         more, _ = _serve(r, prompts=(tuple(range(1, 10)),))
     assert all(len(t) == 4 for t in streams + more)
     calls = s.named("gen/prefill/call")
-    done = s.named("gen/prefill/call/done")
     assert [c[3]["rows"] for c in calls] == [2, 1, 1]
-    assert len(done) == len(calls)
-    for call, end in zip(calls, done):
-        assert s.parent_of(end) == "gen/prefill/call"
-        assert end[3]["fetched_bytes"] == 4 * call[3]["rows"]
-        assert end[3]["logits_bytes"] == call[3]["rows"] * vocab * 4
+    assert s.named("gen/prefill/call/done") == []
+    assert len(s.named("gen/prefill/call/fetch")) == len(calls)
+    # what a call hands back: (rows, 1, V) left on the device, and the
+    # rows' first maxima, int32, on the host
+    b, n = 2, 4
+    logits, kv = r.prefill(np.ones((b, n), np.float32),
+                           np.zeros(b, np.float32),
+                           np.full(b, r.scratch_slot, np.float32),
+                           r.new_cache())
+    assert logits.shape == (b, 1, vocab)
+    assert logits.first_maximum.nbytes == 4 * b
 
 
 def test_gen_prefill_has_its_real_length(runner, tmp_path):
@@ -382,8 +560,9 @@ def test_compile_regions_count_new_buckets_only(export, tmp_path,
                                                 request, write):
     """One ``compile`` region per entry built, none on a second call;
     each says how many of its program's one-token writes of the KV
-    table the column-store kernel makes, and ``gen/decode`` says it of
-    the program it ran."""
+    table the column-store kernel makes.  ``gen/decode`` no longer says
+    it of every call: it is the program's, and its ``compile`` region
+    and the runner's entry hold it."""
     if write == "kernel":
         request.getfixturevalue("column_store")
     r = _runner(export, prompt_buckets=(4,))
@@ -398,8 +577,9 @@ def test_compile_regions_count_new_buckets_only(export, tmp_path,
     # prefill is built first; its writes are four positions a lane
     sites = 2 * NL if write == "kernel" else 0
     assert [int(e[3]["kv_kernel_writes"]) for e in done] == [0, sites]
-    assert {int(e[3]["kv_kernel_writes"])
-            for e in s.named("gen/decode/done")} == {sites}
+    assert s.named("gen/decode/done") == []
+    assert r._entries[("decode", (LANES + 1,))]["kv_kernel_writes"] == \
+        sites
     # each program's temporary bytes: the count a rebuilt KV table
     # shows in, and the operator's gauge of the same number
     temps = sorted(int(e[3]["temp_bytes"]) for e in done)
