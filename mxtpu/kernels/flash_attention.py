@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._sites import CallSites
+
 _NEG_INF = -1e30
 
 # Declared numerics contract, aggregated by
@@ -106,6 +108,25 @@ def _block(n: int, prefer: int) -> int:
     return n
 
 
+def _online_update(s, v, pv_dims, precision, m_scr, l_scr, acc_scr, rows):
+    """One key block of the online softmax for the query rows ``rows``
+    of the scratch: ``s`` the block's masked float32 scores, ``v`` its
+    values, contracted with ``p`` over ``pv_dims``."""
+    m_prev = m_scr[rows]
+    l_prev = l_scr[rows]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, pv_dims,
+        preferred_element_type=jnp.float32, precision=precision)
+    acc_scr[rows] = acc_scr[rows] * alpha + pv
+    m_scr[rows] = m_new
+    l_scr[rows] = l_new
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                acc_scr, *, sm_scale, causal, bq, bk, nk, delta,
                valid_kv, precision):
@@ -148,19 +169,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             col = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + \
                 j * bk
             s = jnp.where(col < valid_kv, s, _NEG_INF)
-        m_prev = m_scr[:]
-        l_prev = l_scr[:]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=precision)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        _online_update(s, v_ref[0], (((1,), (0,)), ((), ())), precision,
+                       m_scr, l_scr, acc_scr, slice(None))
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -236,6 +246,155 @@ def _flash_forward(q3, k3, v3, causal, sm_scale, interpret,
         ],
         interpret=interpret,
     )(q3, k3, v3)
+
+
+# ----------------------------------------------------------------------
+# a chunk of new positions against a lane's cached keys and values:
+# each row stops at its own frontier
+# ----------------------------------------------------------------------
+def _last_block(step, T, bk, nk, L, window):
+    """The last key block that row ``step``'s chunk of ``T`` queries
+    can see: the one holding its last query's position, or every block
+    once a ring has wrapped."""
+    end = step + (T - 1)
+    last = jnp.minimum(jax.lax.div(end, bk), nk - 1)
+    if window:
+        last = jnp.where(end < L, last, nk - 1)
+    return last
+
+
+def _cached_kernel(layer_ref, step_ref, ring_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, sm_scale, group, T, bk, nk, L,
+                   window, keys_minor):
+    del layer_ref  # the index maps read it
+    b, j = pl.program_id(0), pl.program_id(2)
+    step = step_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j <= _last_block(step, T, bk, nk, L, window))
+    def _step():
+        t = jax.lax.broadcasted_iota(jnp.int32, (T, bk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (T, bk), 1) + j * bk
+        pos = step + t
+        if window:
+            # (pos - col) mod L, from the row's own column of its first
+            # query (ring_ref = step mod L): how far back the position
+            # that column holds lies
+            back = ring_ref[b] + t - col
+            back = jnp.where(back < 0, back + L,
+                             jnp.where(back >= L, back - L, back))
+            seen = (back < window) & (back <= pos)
+        else:
+            seen = col <= pos
+        k, v = k_ref[...], v_ref[...]
+        # keys laid (D, bk) are a plain product; laid (bk, D) they are
+        # contracted on their minor axis, as the training kernel does
+        qk_dims = (((1,), (0 if keys_minor else 1,)), ((), ()))
+        pv_dims = (((1,), (1 if keys_minor else 0,)), ((), ()))
+        # the g query heads of a group share the block of their key and
+        # value head and the mask of the chunk's positions
+        for h in range(group):
+            rows = slice(h * T, (h + 1) * T)
+            s = jax.lax.dot_general(
+                q_ref[rows, :], k, qk_dims,
+                preferred_element_type=jnp.float32) * sm_scale
+            _online_update(jnp.where(seen, s, _NEG_INF), v, pv_dims, None,
+                           m_scr, l_scr, acc_scr, rows)
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        # every row sees at least its own position, so l > 0
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+# ``with cached_call_sites() as traced``: the chunk kernel's call sites
+# traced inside the block, what a program lowered there holds of it
+cached_call_sites = CallSites()
+
+
+def cached_attention(q, table, step, sm_scale, layer, window=0,
+                     keys_minor=False):
+    """A chunk of ``T`` new queries a row against the row's cached keys
+    and values, blockwise, each row stopping at its own frontier: the
+    Pallas form of ``cached_attention``'s ``T > 1`` path
+    (``mxtpu.ndarray.rnn_impl``).  ``q`` (B, H, T, D); ``table`` the
+    slot table (layers, 2, B, H_kv, L, D) whose planes ``[layer, 0]``
+    and ``[layer, 1]`` are the keys and values, or (layers, 2, B, H_kv,
+    D, L) with ``keys_minor`` — the bytes as a table kept with ``L``
+    minor holds them — with ``H = g * H_kv``; the planes are read where
+    the table lies (a plane sliced out would be a copy made for the
+    call).  ``step`` (B,) int32, row b's query t at position ``step_b +
+    t``.  Masks as the op does: column ``c <= pos`` with no window, and
+    on a ring of ``L`` columns the position ``c`` holds for the query,
+    ``pos - ((pos - c) mod L)``, within ``window`` and not before 0.
+
+    Grid (row, key/value head, key block), key blocks innermost; the g
+    query heads of a group and the chunk's positions are one tile of
+    ``g * T`` rows, so a key/value head is read once for its group.  A
+    key block past the row's last query (``_last_block``) is neither
+    computed nor read: its index is clamped to that block, which the
+    pipeline then does not fetch again.  The products take the table's
+    dtype as their operands (the queries are cast once, the
+    probabilities a block) and accumulate in float32, as the op's
+    float32 products at the default precision feed the chip's matrix
+    unit; scores, running max and sum and the accumulator are float32;
+    one cast on output."""
+    from . import interpret_mode
+    cached_call_sites.note()
+    # the layer rides the scalar prefetch, so that one traced kernel
+    # serves every layer of a program that has the same shapes
+    return _cached(q, table, jnp.asarray(step, jnp.int32),
+                   jnp.full((1,), layer, jnp.int32),
+                   sm_scale=float(sm_scale), window=int(window),
+                   keys_minor=bool(keys_minor), interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "window", "keys_minor", "interpret"))
+def _cached(q, table, step, layer, *, sm_scale, window, keys_minor,
+            interpret):
+    B, H, T, D = q.shape
+    Hk = table.shape[3]
+    L = table.shape[5] if keys_minor else table.shape[4]
+    g = H // Hk
+    bk = _block(L, 512)
+    nk = L // bk
+
+    def plane(p):
+        def at(b, h, j, layer, step, ring):
+            j = jnp.minimum(j, _last_block(step[b], T, bk, nk, L, window))
+            return (layer[0], p, b, h) + ((0, j) if keys_minor else (j, 0))
+
+        return pl.BlockSpec((None,) * 4 + ((D, bk) if keys_minor
+                                           else (bk, D)), at)
+
+    tile = pl.BlockSpec((None, None, g * T, D), lambda b, h, j, *_: (b, h, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_cached_kernel, sm_scale=sm_scale, group=g, T=T,
+                          bk=bk, nk=nk, L=L, window=window,
+                          keys_minor=keys_minor),
+        name="cached_attention_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, Hk, nk),
+            in_specs=[tile, plane(0), plane(1)],
+            out_specs=tile,
+            scratch_shapes=[pltpu.VMEM((g * T, 1), jnp.float32),
+                            pltpu.VMEM((g * T, 1), jnp.float32),
+                            pltpu.VMEM((g * T, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, g * T, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(layer, step, step % L, q.astype(table.dtype).reshape(B, Hk, g * T, D),
+      table, table)
+    return out.reshape(B, H, T, D)
 
 
 # ----------------------------------------------------------------------

@@ -206,11 +206,11 @@ class GroupedQueryAttention(HybridBlock):
                               layer=at, plane=0, **self._write)
         kv = F.kv_cache_write(kv, heads(self.v(x), hk), step, layer=at,
                               plane=1, **self._write)
-        out = F.cached_attention(
-            placed(self.q(x), hq, q_norm),
-            F.kv_cache_read(kv, layer=at, plane=0),
-            F.kv_cache_read(kv, layer=at, plane=1), step,
-            sm_scale=self._scale, **self._read)
+        # the table whole, its planes named by ``layer``: a chunk's
+        # attention kernel reads them where the table lies
+        out = F.cached_attention(placed(self.q(x), hq, q_norm), kv, kv,
+                                 step, sm_scale=self._scale, layer=at,
+                                 **self._read)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(0, -1, hq * d))
         return self.o(out), kv

@@ -276,6 +276,41 @@ def _capacity_is_minor(table):
     return order == (0, 1, 2, 3, 5, 4)
 
 
+# The float32 scores (B, H, T, L) past which XLA's form of a chunk's
+# attention loses to the kernel.  On a v5e (PERF.md §6) XLA's
+# fusions keep 42-71 MB of scores on the chip and take 0.05-0.09 ms a
+# layer, as fast as the kernel or faster (a ring of 1,280, Olmo's one
+# row of 2,304); 277-283 MB go through HBM and take 1.2-1.4 ms, where
+# the kernel takes 0.2-0.6 (Mellum's full table, Olmo's four rows).
+_SCORES_FOR_KERNEL = 1 << 27
+
+
+def _attends_by_kernel(q, k_cache):
+    """Whether ``cached_attention`` takes the blockwise kernel: Pallas
+    kernels run here, the queries are a chunk (``T > 1``: a decode
+    step's one query a row keeps XLA's form and its program), the table
+    is bfloat16 — the dtype its products are fed at; a float32 table is
+    read at twice the bytes for the same bfloat16 products and keeps
+    XLA's form — the shapes tile (``T`` a whole number of bfloat16
+    sublane tiles, ``L`` of 128-column blocks), and XLA's form would
+    make ``_SCORES_FOR_KERNEL`` bytes of float32 scores or more."""
+    from .. import kernels
+    B, H, T = q.shape[:3]
+    L = k_cache.shape[2]
+    return (T > 1 and kernels.pallas_enabled()
+            and k_cache.dtype == jnp.bfloat16 and T % 16 == 0
+            and L % 128 == 0 and B * H * T * L * 4 >= _SCORES_FOR_KERNEL)
+
+
+def _keys_are_minor(table):
+    """Whether the device keeps a slot table of ``table``'s shape
+    (..., L, D) with ``L`` minor (a head of 64 lies so), so that the
+    kernel binds it with its last two axes swapped, where it lies."""
+    order = tuple(_resident_layout(table).major_to_minor)
+    n = len(order)
+    return order == tuple(range(n - 2)) + (n - 1, n - 2)
+
+
 @jax.jit
 def _write_lanes(table, new, idx, layer, plane):
     """The lanes' loop of ``kv_cache_write``: each turn one
@@ -395,7 +430,7 @@ register_op("kv_cache_read", num_inputs=1, differentiable=False,
 
 
 def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
-                         window=0):
+                         window=0, layer=-1):
     """Decode-step attention over a preallocated KV cache.  ``q``:
     (B, H, T, D) — the T new query tokens of each lane sit at absolute
     positions ``step_b + t``; ``k_cache``/``v_cache``: (B, H_kv, L, D)
@@ -424,7 +459,26 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
     without a window (a table that holds every position is a ring no
     wrap has reached).  Its work lies under the scope
     ``window_attention``; with no window the op is the program it
-    was."""
+    was.
+
+    ``layer`` >= 0 hands the slot table itself (layers, 2, B, H_kv, L,
+    D) as ``k_cache`` and ``v_cache``, the same table both, whose planes
+    ``[layer, 0]`` and ``[layer, 1]`` are the keys and values: what
+    ``kv_cache_read`` would slice out, so that the kernel below reads
+    them where the table lies (a plane sliced out is a copy the
+    compiler writes for a custom call's operand).
+
+    A chunk (``T > 1``) of such a table in bfloat16 on a TPU is the
+    blockwise Pallas kernel
+    ``mxtpu.kernels.flash_attention.cached_attention``, which writes no
+    scores out and stops at each row's frontier (``_attends_by_kernel``
+    decides from the shapes, the dtype and the backend); a decode step
+    (``T = 1``), a float32 table, planes handed alone and the CPU keep
+    the XLA form below, which is the kernel's parity oracle."""
+    table = None
+    if layer >= 0:
+        table = k_cache
+        k_cache, v_cache = k_cache[layer, 0], v_cache[layer, 1]
     B, H, T, D = q.shape
     Hk, L = k_cache.shape[1], k_cache.shape[2]
     if H % Hk:
@@ -440,6 +494,12 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
     with jax.named_scope("window_attention" if window
                          else "cached_attention"):
         s = jnp.asarray(step).astype(jnp.int32)
+        if table is not None and _attends_by_kernel(q, k_cache):
+            from ..kernels.flash_attention import cached_attention
+            minor = _keys_are_minor(table)
+            return cached_attention(
+                q, jnp.swapaxes(table, -1, -2) if minor else table, s,
+                scale, layer, window=window, keys_minor=minor)
         pos_q = s[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
         pos_k = jnp.arange(L, dtype=jnp.int32)
         if window:
@@ -471,7 +531,8 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
 
 register_op("cached_attention", num_inputs=4, differentiable=False,
             params=[Param("sm_scale", float, -1.0),
-                    Param("window", int, 0, lower=0)],
+                    Param("window", int, 0, lower=0),
+                    Param("layer", int, -1, lower=-1)],
             doc=_cached_attention_op.__doc__)(_cached_attention_op)
 
 

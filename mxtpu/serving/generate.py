@@ -792,6 +792,7 @@ class GenerateRunner:
             t0 = time.perf_counter()
             from mxtpu import analysis
             from ..kernels import kv_write, ssm_update
+            from ..kernels.flash_attention import cached_call_sites
             compiled, source, ckey, cmeta = None, "cold", None, {}
             with self._region(obs.SPAN_COMPILE, entry=self._entry_label,
                               kind=kind, bucket=str(bucket[1])) as rg:
@@ -812,7 +813,8 @@ class GenerateRunner:
                         fn, donate_argnums=(kv_argnum,)
                         if apply_donate else ())
                     with kv_write.call_sites() as traced, \
-                            ssm_update.call_sites() as updated:
+                            ssm_update.call_sites() as updated, \
+                            cached_call_sites() as attended:
                         lowered = jitted.lower(
                             *in_structs, self._param_structs)
                     compiled = lowered.compile(compiler_options=(
@@ -821,7 +823,8 @@ class GenerateRunner:
                         else None))
                     cmeta = dict(analysis.audit_stamp(),
                                  kv_kernel_writes=traced[0],
-                                 ssm_kernel_updates=updated[0])
+                                 ssm_kernel_updates=updated[0],
+                                 attention_kernel_sites=attended[0])
                     analysis.maybe_audit(
                         compiled, label=f"GenerateRunner{bucket}")
                     if ckey is not None:
@@ -831,13 +834,18 @@ class GenerateRunner:
                         compiled, label=f"GenerateRunner{bucket}")
                 # the one-token writes of the KV table that this program
                 # makes by the column-store kernel (0: the lanes' loop),
-                # and the one-token updates of the ssm table it makes by
-                # the state-update kernel (0: XLA's two fusions); a
-                # program loaded from disk says what its writer traced
+                # the one-token updates of the ssm table it makes by the
+                # state-update kernel (0: XLA's two fusions), and the
+                # chunks' attentions it makes by the blockwise kernel
+                # (0: XLA's form); a program loaded from disk says what
+                # its writer traced
                 kv_kernel_writes = int(cmeta.get("kv_kernel_writes", 0))
                 ssm_kernel_updates = int(cmeta.get("ssm_kernel_updates", 0))
+                attention_kernel_sites = int(
+                    cmeta.get("attention_kernel_sites", 0))
                 rg.set(source=source, kv_kernel_writes=kv_kernel_writes,
-                       ssm_kernel_updates=ssm_kernel_updates)
+                       ssm_kernel_updates=ssm_kernel_updates,
+                       attention_kernel_sites=attention_kernel_sites)
                 temp_bytes = (analysis.mem_stats(compiled) or {}).get(
                     "temp_size_in_bytes")
                 if temp_bytes is not None:
@@ -845,7 +853,8 @@ class GenerateRunner:
             self.compile_seconds[bucket] = time.perf_counter() - t0
             entry = {"compiled": compiled, "in_structs": in_structs,
                      "kv_kernel_writes": kv_kernel_writes,
-                     "ssm_kernel_updates": ssm_kernel_updates}
+                     "ssm_kernel_updates": ssm_kernel_updates,
+                     "attention_kernel_sites": attention_kernel_sites}
             self._entries[bucket] = entry
             self._compile_sources[bucket] = source
             if self._obs:
@@ -930,7 +939,8 @@ class GenerateRunner:
             self._rows("prefill", tokens, step, length) + (lane_idx,),
             kv,
             {"rows": b, "bucket": s, "tokens": int(np.sum(length)),
-             "resets": fresh, "lane_bytes": b * self._lane_bytes})
+             "resets": fresh, "lane_bytes": b * self._lane_bytes,
+             "context_tokens": int(np.sum(step) + np.sum(length))})
 
     def decode(self, tokens: np.ndarray, step: np.ndarray, kv,
                length=None) -> Tuple["DeviceLogits", Any]:

@@ -542,3 +542,63 @@ def test_routed_experts_beside_a_ring_compile_for_v5e(
                   "moe/route", "moe/dispatch", "moe/experts",
                   "moe/combine"):
         assert "/" + scope + "/" in text, scope
+
+
+def test_mellum_prefill_attention_stops_at_each_frontier_on_v5e(
+        topo, one_chip, on_tpu, chip_layouts, no_persistent_cache,
+        monkeypatch):
+    """The routed-experts cell's (1, 256) prefill program WHOLE (twelve
+    layers at published widths), built by ``GenerateRunner``'s own
+    ``_entry``: the three full layers' attention of the chunk is the
+    blockwise kernel (``attention_kernel_sites`` 3; the nine rings of
+    1,280, whose 42 MB of scores XLA's form holds on the chip, keep
+    it), so the program's temporaries fall below XLA's form's, whose
+    float32 scores of a full layer alone are 277 MB; the kernel binds
+    the gathered lanes where they lie, so no copy of a lane's keys or
+    values is added; and the decode program, one query a row, is the
+    text it was with the kernel refused."""
+    import re
+    from mxtpu import analysis
+    from mxtpu.ndarray import rnn_impl
+    r = _described_runner(
+        monkeypatch, topo, one_chip, "mellum2_12b_a2_5b.json", 5,
+        spec=lambda net: net.state_spec(23, 8448, kv_dtype="bfloat16",
+                                        max_chunk=256),
+        counters=lambda net: net.counter_spec(), prompt_buckets=(256,),
+        max_prefill_batch=1)
+    bucket = ("prefill", (1, 256))
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        entry = r._entry(bucket)
+    assert entry["attention_kernel_sites"] == 3
+    text, mem = analysis.executable_artifact(entry["compiled"])
+    structs = r._structs(bucket)
+    with monkeypatch.context() as m:
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        xla_text, xla_mem = analysis.compiled_artifact(
+            r._prefill_pure(), *structs, r._param_structs,
+            donate_argnums=(len(structs) - 1,))
+    assert mem["alias_size_in_bytes"] == xla_mem["alias_size_in_bytes"]
+    assert mem["temp_size_in_bytes"] < xla_mem["temp_size_in_bytes"] - 200e6
+    kernels_ = [line for line in text.splitlines()
+                if "tpu_custom_call" in line and "cached_attention_fwd" in line]
+    assert len(kernels_) == 3
+    assert all("/cached_attention/" in line for line in kernels_)
+
+    def lane_copies(t):
+        return len(re.findall(
+            r"= bf16\[1,4,(8448,128|128,8448|1280,128|128,1280)\]\S* copy\(",
+            t))
+
+    assert lane_copies(text) == lane_copies(xla_text)
+
+    def decode_text():
+        structs = r._structs(("decode", (24,)))
+        return re.sub(r", metadata=\{[^}]*\}", "", analysis.lowered_text(
+            r._decode_pure(), *structs, r._param_structs))
+
+    taken = decode_text()
+    with monkeypatch.context() as m:
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        assert decode_text() == taken
+    assert "cached_attention_fwd" not in taken

@@ -11,7 +11,9 @@ from mxtpu.kernels import (layer_norm, flash_attention)
 from mxtpu.kernels.layer_norm import (layer_norm_reference,
                                       _layer_norm_pallas)
 from mxtpu.kernels.flash_attention import (attention_reference,
-                                           _flash_attention_pallas)
+                                           _flash_attention_pallas,
+                                           cached_attention,
+                                           cached_call_sites)
 
 
 @pytest.fixture(autouse=True)
@@ -547,3 +549,263 @@ def test_ssm_update_is_taken_by_what_is_observed(monkeypatch):
            for k in ("x", "dt", "b_mat", "c_mat")}
     _, _, sites = _scan(monkeypatch, True, 0, **dict(ins, **two))
     assert sites == 0
+
+
+# ----------------------------------------------------------------------
+# cached_attention's chunk path: the blockwise kernel that stops at each
+# row's frontier, held against the op's XLA form
+# ----------------------------------------------------------------------
+def _exact_attention(q, k, v, step, window):
+    """Float64 attention of the op's masks: the reference both forms'
+    errors are measured against."""
+    q, k, v = (np.asarray(jnp.asarray(a, jnp.float32), np.float64)
+               for a in (q, k, v))
+    B, H, T, D = q.shape
+    Hk, L = k.shape[1], k.shape[2]
+    out = np.zeros_like(q)
+    for b in range(B):
+        pos = int(step[b]) + np.arange(T)[:, None]
+        col = np.arange(L)[None, :]
+        back = (pos - col) % L
+        seen = ((back < window) & (back <= pos)) if window else col <= pos
+        for h in range(H):
+            s = np.where(seen, q[b, h] @ k[b, h * Hk // H].T / np.sqrt(D),
+                         -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[b, h] = (p / p.sum(-1, keepdims=True)) @ v[b, h * Hk // H]
+    return out
+
+
+def _as_the_chip_feeds(q, k, v, step, window):
+    """The op's XLA form as the chip computes it: its float32 products
+    at the default precision take bfloat16 multiplicands (the CPU's
+    backend takes them whole)."""
+    from mxtpu.ndarray import rnn_impl
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    real = jnp.einsum
+
+    def einsum(spec, a, b, **kw):
+        return real(spec, bf(a), bf(b), **kw)
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(jnp, "einsum", einsum)
+        return rnn_impl._cached_attention_op(q, k, v, step, window=window)
+
+
+# (g, D, table dtype, T, L, window, the rows' steps): Mellum's heads
+# (8 a group, 128) and granite's (4, 64, keys with L minor) among them; a
+# ring of 640 for a window of 512 is Mellum's 1,280 / 1,024 halved
+_CHUNKS = {
+    "g1-d64-f32-step0-and-last-block": (1, 64, jnp.float32, 64, 512, 0,
+                                        (0, 448)),
+    "g8-d128-bf16-t256-last-block": (8, 128, jnp.bfloat16, 256, 1024, 0,
+                                     (768, 0)),
+    "g8-d128-bf16-mid-table": (8, 128, jnp.bfloat16, 64, 1024, 0,
+                               (300, 517)),
+    "g1-d128-bf16-blocks-apart": (1, 128, jnp.bfloat16, 256, 1536, 0,
+                                  (0, 1024)),
+    "g4-d64-bf16-t256": (4, 64, jnp.bfloat16, 256, 768, 0, (100, 512)),
+    "g8-d64-f32-t256": (8, 64, jnp.float32, 256, 512, 0, (256, 0)),
+    "g8-d128-bf16-ring-no-wrap": (8, 128, jnp.bfloat16, 64, 640, 512,
+                                  (0, 200)),
+    "g8-d128-bf16-ring-wrapped": (8, 128, jnp.bfloat16, 64, 640, 512,
+                                  (1900, 600)),
+    "g4-d64-bf16-ring-straddles": (4, 64, jnp.bfloat16, 128, 640, 512,
+                                   (576, 2500)),
+    "g1-d128-f32-ring-wrapped": (1, 128, jnp.float32, 64, 640, 512,
+                                 (1300, 0)),
+}
+
+
+def _chunk_inputs(g, D, dtype, T, L, steps, seed=11):
+    rng = np.random.default_rng(seed)
+    B, Hk = len(steps), 2
+    q = jnp.asarray(rng.normal(size=(B, g * Hk, T, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, Hk, L, D)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, Hk, L, D)), dtype)
+    return q, k, v, jnp.asarray(steps, jnp.int32)
+
+
+def _table_of(k, v):
+    """A slot table of two layers whose layer 1 holds ``k`` and ``v``
+    and whose layer 0 is NaN: a read of the wrong layer shows."""
+    planes = jnp.stack([k, v])
+    return jnp.stack([jnp.full_like(planes, jnp.nan), planes])
+
+
+@pytest.mark.parametrize("keys_minor", [False, True],
+                         ids=["head-minor", "capacity-minor"])
+@pytest.mark.parametrize("case", sorted(_CHUNKS))
+def test_cached_attention_kernel_matches_the_xla_form(monkeypatch, case,
+                                                      keys_minor):
+    """The kernel through the op, reading layer 1's planes of a slot
+    table as a model's graph hands it, against the op's XLA form, for
+    keys bound as they lie either way: a float32 table to float32
+    rounding; a bfloat16 table, whose products both forms feed the
+    chip's matrix unit at bfloat16, no further from float64 attention
+    than the XLA form as the chip computes it (mean and largest error),
+    and within that rounding of it as the CPU computes it.  Rows at step
+    0, in mid-table and with a frontier in the last block, rows whose
+    frontiers lie blocks apart, a scratch row (step 0), a ring with and
+    without a wrap and a chunk that straddles it; queries past a row's
+    valid length are positions like any other."""
+    from mxtpu.ndarray import rnn_impl
+    g, D, dtype, T, L, window, steps = _CHUNKS[case]
+    q, k, v, step = _chunk_inputs(g, D, dtype, T, L, steps)
+    table = _table_of(k, v)
+    monkeypatch.setattr(rnn_impl, "_keys_are_minor", lambda t: keys_minor)
+    monkeypatch.setattr(rnn_impl, "_SCORES_FOR_KERNEL", 0)
+    with cached_call_sites() as sites:
+        got = rnn_impl._cached_attention_op(q, table, table, step,
+                                            window=window, layer=1)
+    assert sites[0] == int(dtype == jnp.bfloat16)
+    if dtype == jnp.float32:        # the rule keeps XLA's form: call it
+        got = cached_attention(
+            q, jnp.swapaxes(table, -1, -2) if keys_minor else table, step,
+            1 / np.sqrt(D), 1, window=window, keys_minor=keys_minor)
+    with monkeypatch.context() as m:
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        xla = rnn_impl._cached_attention_op(q, k, v, step, window=window)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got, xla = np.asarray(got), np.asarray(xla)
+    exact = _exact_attention(q, k, v, steps, window)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, xla, rtol=2e-6, atol=2e-6)
+        return
+    chip = np.asarray(_as_the_chip_feeds(q, k, v, step, window))
+    err, chip_err = np.abs(got - exact), np.abs(chip - exact)
+    assert err.mean() <= chip_err.mean() and err.max() <= chip_err.max(), \
+        (err.mean(), chip_err.mean(), err.max(), chip_err.max())
+    np.testing.assert_allclose(got, xla, atol=4 * chip_err.max())
+
+
+def test_cached_attention_planes_alone_keep_the_xla_form(monkeypatch):
+    """The kernel reads a layer's planes where the slot table lies, so
+    only the table form takes it: the op handed the planes alone (as
+    ``kv_cache_read`` slices them) keeps XLA's form."""
+    from mxtpu.ndarray import rnn_impl
+    monkeypatch.setattr(rnn_impl, "_SCORES_FOR_KERNEL", 0)
+    q, k, v, step = _chunk_inputs(4, 64, jnp.bfloat16, 64, 640, (0, 900))
+    table = _table_of(k, v)
+    with cached_call_sites() as sites:
+        alone = rnn_impl._cached_attention_op(q, k, v, step, window=512)
+    assert sites[0] == 0
+    with cached_call_sites() as sites:
+        whole = rnn_impl._cached_attention_op(q, table, table, step,
+                                              window=512, layer=1)
+    assert sites[0] == 1
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        xla = rnn_impl._cached_attention_op(q, table, table, step,
+                                            window=512, layer=1)
+    np.testing.assert_array_equal(np.asarray(alone), np.asarray(xla))
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(xla),
+                               atol=2e-2)
+
+
+def test_cached_attention_kernel_reads_no_block_past_a_frontier():
+    """What a row cannot see is never read: keys and values past the
+    block of a row's last query (NaN here) leave its output finite and
+    equal to the same table with them zeroed; a ring that has wrapped
+    is read whole."""
+    q, k, v, step = _chunk_inputs(8, 128, jnp.bfloat16, 64, 1024, (0, 300))
+    past = (jnp.arange(1024) >= 512)[None, None, :, None]
+    poisoned = _table_of(*[jnp.where(past, jnp.nan, a).astype(a.dtype)
+                           for a in (k, v)])
+    zeroed = _table_of(*[jnp.where(past, 0, a).astype(a.dtype)
+                         for a in (k, v)])
+    got = cached_attention(q, poisoned, step, 0.1, 1)
+    want = cached_attention(q, zeroed, step, 0.1, 1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ring = cached_attention(q, poisoned, jnp.asarray([1100, 300]), 0.1, 1,
+                            window=512)
+    assert np.isnan(np.asarray(ring[0])).all()
+    assert np.isfinite(np.asarray(ring[1])).all()
+
+
+@pytest.mark.parametrize("why,shape,dtype,takes", [
+    ("mellum-chunk", ((1, 32, 256, 128), (1, 4, 8448, 128)), jnp.bfloat16,
+     True),
+    ("mellum-chunk-of-128", ((1, 32, 128, 128), (1, 4, 8448, 128)),
+     jnp.bfloat16, True),
+    ("granite-rung-8", ((8, 32, 128, 64), (8, 8, 1280, 64)), jnp.bfloat16,
+     True),
+    ("olmo-rung-4", ((4, 30, 256, 128), (4, 30, 2304, 128)), jnp.bfloat16,
+     True),
+    ("mellum-ring", ((1, 32, 256, 128), (1, 4, 1280, 128)), jnp.bfloat16,
+     False),
+    ("olmo-one-row", ((1, 30, 256, 128), (1, 30, 2304, 128)), jnp.bfloat16,
+     False),
+    ("granite-rung-2", ((2, 32, 256, 64), (2, 8, 1280, 64)), jnp.bfloat16,
+     False),
+    ("decode-step", ((24, 32, 1, 128), (24, 4, 8448, 128)), jnp.bfloat16,
+     False),
+    ("float32-table", ((2, 16, 128, 64), (2, 16, 512, 64)), jnp.float32,
+     False),
+    ("chunk-of-8", ((1, 4, 8, 16), (1, 2, 256, 16)), jnp.bfloat16, False),
+    ("ring-of-12", ((1, 4, 16, 16), (1, 2, 12, 16)), jnp.bfloat16, False),
+])
+def test_cached_attention_takes_the_kernel_by_shape_and_dtype(why, shape,
+                                                              dtype, takes):
+    """The rule, from both sides: a chunk of a bfloat16 table that tiles
+    and whose float32 scores under XLA's form would reach 128 MiB takes
+    the kernel (Mellum's full table, a granite rung of 8, an Olmo rung
+    of 4); a chunk whose scores XLA holds on the chip (a ring of 1,280,
+    Olmo's one row, a granite rung of 2), a decode step, a float32
+    table (bertgen's) and shapes that do not tile keep XLA's form — and
+    so does every shape where Pallas kernels do not run."""
+    from mxtpu.ndarray import rnn_impl
+    q, k = (jax.ShapeDtypeStruct(s, dtype) for s in shape)
+    assert rnn_impl._attends_by_kernel(q, k) is takes
+    with pytest.MonkeyPatch.context() as m:
+        m.setenv("MXTPU_PALLAS", "0")
+        assert rnn_impl._attends_by_kernel(q, k) is False
+
+
+def test_cached_attention_binds_keys_as_the_device_lays_them(monkeypatch):
+    """A table the device keeps with ``L`` minor is bound with its last
+    two axes swapped; one kept with ``head_dim`` minor as it is."""
+    from jax.experimental.layout import Layout
+    from mxtpu.ndarray import rnn_impl
+    table = jnp.zeros((2, 2, 1, 2, 256, 64), jnp.bfloat16)
+    assert not rnn_impl._keys_are_minor(table)        # the CPU: row-major
+    for order, minor in (((0, 1, 2, 3, 5, 4), True),
+                         ((0, 1, 2, 3, 4, 5), False),
+                         ((0, 1, 2, 4, 3, 5), False)):
+        monkeypatch.setattr(rnn_impl, "_resident_layout",
+                            lambda x, order=order: Layout(
+                                major_to_minor=order,
+                                tiling=((8, 128), (2, 1))))
+        assert rnn_impl._keys_are_minor(table) is minor
+
+
+@pytest.mark.parametrize("window", [0, 512], ids=["full", "ring"])
+def test_cached_attention_table_form_is_the_planes_program_in_decode(
+        window):
+    """A decode step (one query a row) handed the slot table and
+    ``layer=`` lowers to the program of the same step handed the planes
+    ``kv_cache_read`` slices out — the graph's form before the table was
+    handed whole — metadata aside: the decode programs are what they
+    were."""
+    import re
+    from mxtpu import analysis
+    from mxtpu.ndarray import rnn_impl
+    q = jax.ShapeDtypeStruct((3, 8, 1, 128), jnp.float32)
+    table = jax.ShapeDtypeStruct((2, 2, 3, 2, 640, 128), jnp.bfloat16)
+    step = jax.ShapeDtypeStruct((3,), jnp.float32)
+
+    def whole(q, t, s):
+        return rnn_impl._cached_attention_op(q, t, t, s, window=window,
+                                             layer=1)
+
+    def planes(q, t, s):
+        return rnn_impl._cached_attention_op(
+            q, rnn_impl._kv_cache_read_op(t, layer=1, plane=0),
+            rnn_impl._kv_cache_read_op(t, layer=1, plane=1), s,
+            window=window)
+
+    texts = [re.sub(r", metadata=\{[^}]*\}|HloModule \S+", "",
+                    analysis.lowered_text(f, q, table, step))
+             for f in (whole, planes)]
+    assert texts[0] == texts[1]

@@ -400,6 +400,83 @@ def test_a_call_counts_its_assignments_and_the_experts_it_touched(runner):
         - before["mxtpu_moe_experts_touched_total"] == sum(touched)
 
 
+def _tiled_runner(net, kernel, monkeypatch):
+    """A runner whose bfloat16 tables tile as the attention kernel asks
+    (a full table of 256 columns, a ring of 128, chunks of 16), its
+    programs built with Pallas kernels under the interpreter or not, and
+    the kernel taken whatever the size of the scores."""
+    symbol, params = _export(net)
+    with monkeypatch.context() as m:
+        m.setenv("MXTPU_PALLAS", "interpret" if kernel else "0")
+        m.setattr(rnn_impl, "_SCORES_FOR_KERNEL", 0)
+        r = GenerateRunner(
+            symbol, params, net.state_spec(LANES, 256, kv_dtype="bfloat16",
+                                           max_chunk=16),
+            prompt_buckets=(16,), cache=None, counters=net.counter_spec())
+        r.warmup()
+    return r
+
+
+def test_prefill_attention_by_the_kernel_serves_what_xla_serves(
+        weights, monkeypatch):
+    """Every prefill program's chunk attention is the blockwise kernel
+    where the tables are bfloat16 and tile (``attention_kernel_sites`` 4
+    on each prefill ``compile`` region and entry, 0 on the decode's),
+    and three prompts on the rung of three — one that wraps a ring of
+    128 (window 112) once and crosses a block of 128 full columns, two
+    shorter, whose rows turn scratch rows (step 0, the scratch slot)
+    once they finish — prefilled in chunks of 16 and decoded give the logits of XLA's form to the rounding of
+    the kernel's bfloat16 products, both as near the float32 reference
+    as the tables' bfloat16 lets them be.  ``gen/prefill/call`` carries
+    ``context_tokens``, each row's ``step + length`` summed."""
+    net = _built(dict(CFG, sliding_window=112), weights)
+    wide = dict(CFG, sliding_window=112)
+    profiler.set_state("run")
+    try:
+        kern = _tiled_runner(net, True, monkeypatch)
+        events = profiler.events()
+    finally:
+        profiler.set_state("stop")
+        profiler.dumps(reset=True)
+    xla = _tiled_runner(net, False, monkeypatch)
+    sites = {e["args"]["bucket"]: int(e["args"]["attention_kernel_sites"])
+             for e in events if e["name"] == obs.SPAN_COMPILE}
+    assert sites == {"(1, 16)": 4, "(2, 16)": 4, "(3, 16)": 4,
+                     f"({LANES + 1},)": 0}
+    assert {k: e["attention_kernel_sites"]
+            for k, e in xla._entries.items()} == dict.fromkeys(xla._entries, 0)
+    cut = [250, 40, 97]
+    seqs = [_prompt(n + 2, salt=40 + n) for n in cut]
+    rows = [(lane, s[:n]) for lane, (s, n) in enumerate(zip(seqs, cut))]
+    got, kv = _prefill_rows(kern, kern.new_cache(), rows, bucket=16)
+    profiler.set_state("run")
+    try:
+        want, kv_x = _prefill_rows(xla, xla.new_cache(), rows, bucket=16)
+        events = profiler.events()
+    finally:
+        profiler.set_state("stop")
+        profiler.dumps(reset=True)
+    calls = [e["args"] for e in events if e["name"] == obs.SPAN_PREFILL_CALL]
+    assert [c["context_tokens"] for c in calls] == [
+        sum(min(n, 16 * (c + 1)) for n in cut if n > 16 * c)
+        for c in range(16)]
+    for k in range(2):
+        step = {lane: (s[n + k], n + k)
+                for lane, (s, n) in enumerate(zip(seqs, cut))}
+        g, kv = _decode(kern, kv, step)
+        w, kv_x = _decode(xla, kv_x, step)
+        got += [g[lane] for lane in range(3)]
+        want += [w[lane] for lane in range(3)]
+    refs = [_logits(weights, s, cfg=wide) for s in seqs]
+    ref_rows = [r[n - 1] for r, n in zip(refs, cut)] + [
+        refs[lane][n + k] for k in range(2)
+        for lane, n in enumerate(cut)]
+    gap = max(np.abs(g - w).max() for g, w in zip(got, want))
+    off = max(np.abs(w - r).max() for w, r in zip(want, ref_rows))
+    assert 0 < gap < 1.5e-3 and off < 1.5e-3, (gap, off)
+    assert max(np.abs(g - r).max() for g, r in zip(got, ref_rows)) < 1.5e-3
+
+
 # ---------------------------------------------------------- the batcher
 class _Clock:
     t = 100.0
