@@ -1,8 +1,10 @@
 """Hybrid decoders: recurrent or windowed layers beside full attention,
 dense or routed feed-forward — the ``GraniteMoeHybrid`` family with no
 routed experts (IBM granite-4.0-h), the ``OlmoHybrid`` family (Ai2
-Olmo-Hybrid) and the ``mellum`` family (JetBrains Mellum 2: sliding and
-full attention, every feed-forward a layer of routed experts).
+Olmo-Hybrid), the ``mellum`` family (JetBrains Mellum 2: sliding and
+full attention, every feed-forward a layer of routed experts) and the
+``sdar_moe`` family (JetLM SDAR: full attention under a block-causal
+mask and routed experts, generating by diffusion over blocks).
 
 A decoder whose layer pattern is read from a list.  Every layer is a
 mixer and a feed-forward round a residual stream, with RMS norms placed
@@ -53,8 +55,11 @@ context.  A model declares the tables its kinds of layer need and no
 other.  All are threaded whole through the layers and written in place
 (``kv_cache_write``, ``ssm_conv``, ``ssm_scan``, ``delta_rule``).
 ``logits`` is (B, 1, V): one row a lane, at its last valid position.
-With routed experts a last output ``counts`` (1,) int32 follows the
-tables: experts given a token, summed over the layers
+A block-diffusion model (``diffusion``: block length ``b``) attends
+under the block-causal mask and hands back (B, b, V), each row's last
+``b`` valid positions: the block it denoises.  With routed experts a
+last output ``counts`` (1,) int32 follows the tables: experts given a
+token, summed over the layers
 (:meth:`HybridDecoderModel.counter_spec`).  A full forward over a
 sequence is the same call with ``step`` 0 and fresh tables.
 """
@@ -147,11 +152,12 @@ class GroupedQueryAttention(HybridBlock):
     ``qk_norm`` both).  ``rope`` = (frequencies, scale) rotates queries
     and keys by absolute position (after the norm, keys before they
     are cached); ``window`` > 0 bounds a query's context and makes the
-    block's table a ring of columns."""
+    block's table a ring of columns; ``block`` > 1 replaces the causal
+    mask by the block-causal one (a query sees its own whole block)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  sm_scale, cache_layer=0, qk_norm=None, qk_norm_eps=1e-5,
-                 rope=None, window=0, **kwargs):
+                 rope=None, window=0, block=1, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_heads} query heads do not divide "
@@ -179,6 +185,8 @@ class GroupedQueryAttention(HybridBlock):
         # one it always was
         self._write = {"ring": True} if window else {}
         self._read = {"window": int(window)} if window else {}
+        if block > 1:
+            self._read["block"] = int(block)
 
     def hybrid_forward(self, F, x, step, kv):
         hq, hk, d = self._dims
@@ -380,7 +388,11 @@ class HybridDecoderModel(HybridBlock):
     ``conv``.  ``rope`` maps a kind of attention layer
     (``"full_attention"``, ``"sliding_attention"``) to its
     (frequencies, scale); ``num_experts`` > 0 makes every feed-forward
-    a ``SparseMLP`` of experts ``hidden_size`` wide."""
+    a ``SparseMLP`` of experts ``hidden_size`` wide.  ``diffusion``
+    (``{"block", "mask_id", "steps", "threshold"}``) makes the model a
+    block-diffusion decoder: every attention layer takes the block mask
+    and the logits are each row's last ``block`` positions; the runner
+    and the batcher read the rest (:meth:`diffusion_spec`)."""
 
     ATTENTION = ("attention", "full_attention")
     SLIDING = "sliding_attention"
@@ -396,7 +408,7 @@ class HybridDecoderModel(HybridBlock):
                  logits_scaling=1.0, layout="pre", qk_norm=None,
                  rope=None, sliding_window=0, num_experts=0,
                  experts_per_token=0, norm_topk=True,
-                 tie_embeddings=True, **kwargs):
+                 tie_embeddings=True, diffusion=None, **kwargs):
         super().__init__(**kwargs)
         bad = set(layer_types) - set(self.ATTENTION) - {self.SLIDING} \
             - set(self.RECURRENT)
@@ -418,6 +430,8 @@ class HybridDecoderModel(HybridBlock):
         self._attn = (num_kv_heads, head_dim)
         self._window = int(sliding_window)
         self._top_k = int(experts_per_token) if num_experts else 0
+        self._diffusion = dict(diffusion) if diffusion else None
+        self._block = int(diffusion["block"]) if diffusion else 1
         # the recurrent table's name, a lane's shape in it, and the
         # channels of a lane's convolution window
         self._rec = None
@@ -451,7 +465,8 @@ class HybridDecoderModel(HybridBlock):
                     qk_norm=qk_norm, qk_norm_eps=eps,
                     rope=rope.get(self.SLIDING if sliding
                                   else "full_attention"),
-                    window=self._window if sliding else 0)
+                    window=self._window if sliding else 0,
+                    block=self._block)
                 if sliding:
                     tables, n_win = ("kv_win",), n_win + 1
                 else:
@@ -485,10 +500,10 @@ class HybridDecoderModel(HybridBlock):
     def from_config(cls, cfg):
         """The model of a published ``config.json`` (as a dict), by its
         ``model_type``: ``granitemoehybrid`` (also where the key is
-        absent) that routes to no expert, ``olmo_hybrid``, or
-        ``mellum``.  A configuration cut in depth (``layer_types``
-        shorter than the published list) builds the model's first
-        layers."""
+        absent) that routes to no expert, ``olmo_hybrid``, ``mellum``,
+        or ``sdar_moe``.  A configuration cut in depth (``layer_types``
+        shorter than the published list, or a smaller
+        ``num_hidden_layers``) builds the model's first layers."""
         kind = cfg.get("model_type", "granitemoehybrid")
         if kind == "granitemoehybrid":
             return cls._from_granite(cfg)
@@ -496,8 +511,11 @@ class HybridDecoderModel(HybridBlock):
             return cls._from_olmo_hybrid(cfg)
         if kind == "mellum":
             return cls._from_mellum(cfg)
+        if kind == "sdar_moe":
+            return cls._from_sdar_moe(cfg)
         raise MXNetError(f"HybridDecoderModel: unknown model_type {kind!r} "
-                         f"(granitemoehybrid, olmo_hybrid, mellum)")
+                         f"(granitemoehybrid, olmo_hybrid, mellum, "
+                         f"sdar_moe)")
 
     @classmethod
     def _from_granite(cls, cfg):
@@ -591,6 +609,46 @@ class HybridDecoderModel(HybridBlock):
             norm_topk=bool(cfg.get("norm_topk_prob", True)),
             tie_embeddings=bool(cfg.get("tie_word_embeddings")))
 
+    @classmethod
+    def _from_sdar_moe(cls, cfg):
+        """Full attention in every layer under the block-causal mask, per-
+        head QK-norm and plain rotation, every feed-forward a layer of
+        routed experts (the Qwen3-MoE block).  What a published
+        ``config.json`` leaves to generation is read from the file too:
+        ``block_length``, ``mask_token_id``, ``denoising_steps`` and
+        ``confidence_threshold``."""
+        from ..ndarray.rnn_impl import rope_frequencies
+        if cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers"):
+            raise MXNetError("HybridDecoderModel: an sdar_moe model with "
+                             "dense feed-forward layers")
+        if cfg.get("attention_bias"):
+            raise MXNetError("HybridDecoderModel: an sdar_moe model with "
+                             "attention biases")
+        if cfg.get("use_sliding_window") or cfg.get("rope_scaling"):
+            raise MXNetError("HybridDecoderModel: an sdar_moe model with a "
+                             "sliding window or scaled rotation")
+        block = int(cfg["block_length"])
+        if block < 2:
+            raise MXNetError("HybridDecoderModel: an sdar_moe model needs a "
+                             "block_length of 2 or more")
+        return cls(
+            cfg["vocab_size"], cfg["hidden_size"],
+            cfg["moe_intermediate_size"],
+            ["full_attention"] * int(cfg["num_hidden_layers"]),
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            head_dim=cfg["head_dim"], eps=cfg["rms_norm_eps"],
+            qk_norm="head",
+            rope={"full_attention": (rope_frequencies(
+                cfg["head_dim"], cfg["rope_theta"]), 1.0)},
+            num_experts=cfg["num_experts"],
+            experts_per_token=cfg["num_experts_per_tok"],
+            norm_topk=bool(cfg.get("norm_topk_prob", True)),
+            tie_embeddings=bool(cfg.get("tie_word_embeddings")),
+            diffusion={"block": block,
+                       "mask_id": int(cfg["mask_token_id"]),
+                       "steps": int(cfg["denoising_steps"]),
+                       "threshold": float(cfg["confidence_threshold"])})
+
     @staticmethod
     def _known_kinds(cfg, kinds):
         bad = sorted(set(cfg["layer_types"]) - set(kinds))
@@ -642,6 +700,13 @@ class HybridDecoderModel(HybridBlock):
             return {"device": (), "per_token": {}}
         return {"device": ("moe_experts_touched",),
                 "per_token": {"moe_assignments": self._top_k}}
+
+    def diffusion_spec(self):
+        """What a block-diffusion model tells ``GenerateRunner``: its
+        block length, the mask id, how many passes denoise a block and
+        the confidence that decides a position early; None for a model
+        that generates a token a step."""
+        return dict(self._diffusion) if self._diffusion else None
 
     def named_leaves(self):
         """``{reference leaf name: Parameter}``: the reference's leaves
@@ -702,8 +767,14 @@ class HybridDecoderModel(HybridBlock):
                 touched = rest[-1] if touched is None \
                     else touched + rest[-1]
         # one row a lane leaves the program: the last valid position's
-        last = F.expand_dims(F.SequenceLast(
-            x, length, use_sequence_length=True, axis=1), axis=1)
+        # (a block-diffusion model's: the last block's positions)
+        if self._block > 1:
+            last = F.concat(*[F.expand_dims(F.SequenceLast(
+                x, length - back, use_sequence_length=True, axis=1), axis=1)
+                for back in range(self._block - 1, -1, -1)], dim=1)
+        else:
+            last = F.expand_dims(F.SequenceLast(
+                x, length, use_sequence_length=True, axis=1), axis=1)
         logits = F.FullyConnected(self.final_norm(last),
                                   embed if self._tied else head,
                                   no_bias=True, num_hidden=self._vocab,

@@ -285,7 +285,7 @@ def _capacity_is_minor(table):
 _SCORES_FOR_KERNEL = 1 << 27
 
 
-def _attends_by_kernel(q, k_cache):
+def _attends_by_kernel(q, k_cache, block=1):
     """Whether ``cached_attention`` takes the blockwise kernel: Pallas
     kernels run here, the queries are a chunk (``T > 1``: a decode
     step's one query a row keeps XLA's form and its program), the table
@@ -293,11 +293,13 @@ def _attends_by_kernel(q, k_cache):
     read at twice the bytes for the same bfloat16 products and keeps
     XLA's form — the shapes tile (``T`` a whole number of bfloat16
     sublane tiles, ``L`` of 128-column blocks), and XLA's form would
-    make ``_SCORES_FOR_KERNEL`` bytes of float32 scores or more."""
+    make ``_SCORES_FOR_KERNEL`` bytes of float32 scores or more.  The
+    kernel stops at each row's frontier and knows no block mask, so a
+    block-masked chunk (``block > 1``) keeps XLA's form."""
     from .. import kernels
     B, H, T = q.shape[:3]
     L = k_cache.shape[2]
-    return (T > 1 and kernels.pallas_enabled()
+    return (T > 1 and block == 1 and kernels.pallas_enabled()
             and k_cache.dtype == jnp.bfloat16 and T % 16 == 0
             and L % 128 == 0 and B * H * T * L * 4 >= _SCORES_FOR_KERNEL)
 
@@ -430,7 +432,7 @@ register_op("kv_cache_read", num_inputs=1, differentiable=False,
 
 
 def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
-                         window=0, layer=-1):
+                         window=0, layer=-1, block=1):
     """Decode-step attention over a preallocated KV cache.  ``q``:
     (B, H, T, D) — the T new query tokens of each lane sit at absolute
     positions ``step_b + t``; ``k_cache``/``v_cache``: (B, H_kv, L, D)
@@ -461,6 +463,15 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
     ``window_attention``; with no window the op is the program it
     was.
 
+    ``block`` > 1 is the block-causal mask of a block-diffusion decoder:
+    key position ``l`` attends iff ``l // block <= (step_b + t) //
+    block``, so a query sees every earlier block and its own whole
+    block, and each row's frontier is the end of its query's block.  A
+    caller feeds whole blocks from a block's start, so every column up to
+    that end is written by this call or before it, and columns past a
+    row's valid tokens lie in a later block.  With ``block`` 1 (the
+    causal mask) the op is the program it was.
+
     ``layer`` >= 0 hands the slot table itself (layers, 2, B, H_kv, L,
     D) as ``k_cache`` and ``v_cache``, the same table both, whose planes
     ``[layer, 0]`` and ``[layer, 1]`` are the keys and values: what
@@ -486,7 +497,10 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
                          f"{Hk} key/value heads")
     scale = (1.0 / float(np.sqrt(D))) \
         if (sm_scale is None or sm_scale < 0) else float(sm_scale)
-    window = int(window)
+    window, block = int(window), int(block)
+    if window and block > 1:
+        raise MXNetError("cached_attention: a window and a block mask "
+                         "together")
     if window > L - T + 1:
         raise MXNetError(
             f"cached_attention: a ring of {L} columns holds a window of "
@@ -494,7 +508,7 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
     with jax.named_scope("window_attention" if window
                          else "cached_attention"):
         s = jnp.asarray(step).astype(jnp.int32)
-        if table is not None and _attends_by_kernel(q, k_cache):
+        if table is not None and _attends_by_kernel(q, k_cache, block):
             from ..kernels.flash_attention import cached_attention
             minor = _keys_are_minor(table)
             return cached_attention(
@@ -505,6 +519,9 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
         if window:
             back = (pos_q[:, :, None] - pos_k[None, None, :]) % L
             mask = (back < window) & (back <= pos_q[:, :, None])
+        elif block > 1:
+            mask = pos_k[None, None, :] // block \
+                <= pos_q[:, :, None] // block
         else:
             mask = pos_k[None, None, :] <= pos_q[:, :, None]
         q32 = q.astype(jnp.float32)
@@ -532,7 +549,8 @@ def _cached_attention_op(q, k_cache, v_cache, step, sm_scale=-1.0,
 register_op("cached_attention", num_inputs=4, differentiable=False,
             params=[Param("sm_scale", float, -1.0),
                     Param("window", int, 0, lower=0),
-                    Param("layer", int, -1, lower=-1)],
+                    Param("layer", int, -1, lower=-1),
+                    Param("block", int, 1, lower=1)],
             doc=_cached_attention_op.__doc__)(_cached_attention_op)
 
 

@@ -50,6 +50,16 @@ Three pieces:
   capacity) and deadline-expired requests.  Deterministic in sync
   mode — fake-clock tests drive it step by step.
 
+A block-diffusion model (``GenerateRunner(..., diffusion=...)``) makes
+a step that does not yield one token a lane: each lane holds a block of
+``b`` positions, masked where undecided, and the step program feeds
+every lane's block (``b`` positions, or ``2 b`` on a block's first
+pass, the clean previous block riding in front of it so that its keys
+and values are stored) under the block-causal mask; the host decides
+positions from each one's most likely token and its probability, found
+on the device, and a lane streams its block whole once no position is
+left masked.
+
 Sampling is replay-deterministic: a greedy lane takes its row's first
 maximum, found on the device after a prefill as after a decode step;
 a top-k draw is made on the host, seeded by ``(seed,
@@ -131,7 +141,11 @@ class DeviceLogits:
     100,352.  Indexed as the host array would be, ``logits[row, 0]`` is
     a row that ``sample_token`` draws greedily from without touching
     its numbers; whoever wants the numbers (``np.asarray`` of the whole
-    or of a row: a top-k draw, a test) brings all of them over, once."""
+    or of a row: a top-k draw, a test) brings all of them over, once.
+    A block-diffusion runner's logits are ``(rows, b, V)``, and
+    ``first_maximum`` and ``confidence`` are ``(rows, b)``: each
+    position's most likely token other than the mask and its softmax
+    probability."""
 
     class Row:
         __slots__ = ("first_maximum", "_of", "_slot")
@@ -144,8 +158,10 @@ class DeviceLogits:
             row = self._of.host()[self._slot, 0]
             return row if dtype is None else row.astype(dtype)
 
-    def __init__(self, rows, first_maximum: np.ndarray):
+    def __init__(self, rows, first_maximum: np.ndarray,
+                 confidence: Optional[np.ndarray] = None):
         self.rows, self.first_maximum = rows, first_maximum
+        self.confidence = confidence
         self._host = None
 
     @property
@@ -287,6 +303,13 @@ class GenerateRunner:
         valid tokens times its multiple, known on the host.  Each is a
         fact of the call's region and adds to the counter
         ``mxtpu_<name>_total``.
+    diffusion : dict, optional
+        A block-diffusion graph's ``diffusion_spec()`` (``block``,
+        ``mask_id``, ``steps``, ``threshold``): the decode program then
+        takes ``2 * block`` positions a slot, its logits are each row's
+        last ``block`` positions, and what crosses to the host is each
+        position's most likely token other than ``mask_id`` and its
+        probability (:class:`DeviceLogits`).  Needs a state spec.
     quant_scales : dict, optional — calibrated activation thresholds
         (from a :class:`ModelRunner` ``calibrate()`` over the same
         architecture) arming the int8 trace path; required when
@@ -302,7 +325,8 @@ class GenerateRunner:
                  max_prefill_batch: Optional[int] = None,
                  cache: Any = "auto", amp=None, quant=None,
                  quant_scales: Optional[Dict[str, float]] = None,
-                 counters: Optional[Dict[str, Any]] = None):
+                 counters: Optional[Dict[str, Any]] = None,
+                 diffusion: Optional[Dict[str, Any]] = None):
         import jax
 
         from .. import amp as _amp_mod
@@ -314,6 +338,9 @@ class GenerateRunner:
         counters = counters or {}
         self._device_counters = tuple(counters.get("device", ()))
         self._token_counters = dict(counters.get("per_token", {}))
+        self.diffusion = dict(diffusion) if diffusion else None
+        # positions a slot the decode program takes
+        self._width = 2 * int(diffusion["block"]) if diffusion else 1
         # one KV table as a 6-tuple of ints, or a declared state spec
         self._one_table = not isinstance(kv_spec[0], (tuple, list))
         if self._one_table:
@@ -324,6 +351,9 @@ class GenerateRunner:
                 StateTable(str(n), tuple(int(d) for d in shape),
                            int(axis), jax.numpy.dtype(dt).name)
                 for n, shape, axis, dt in kv_spec)
+        if self.diffusion and self._one_table:
+            raise MXNetError("generate: a block-diffusion graph declares "
+                             "a state spec")
         kv = [t.shape for t in self.state_spec if t.name == "kv"]
         # the declared cache geometry mxmem audits
         self.kv_spec = kv[0] if kv else ()
@@ -570,6 +600,8 @@ class GenerateRunner:
             fp["state"] = [[t.name, list(shape), t.lane_axis, t.dtype]
                            for t, shape in zip(self.state_spec,
                                                self._table_shapes)]
+        if self.diffusion:
+            fp["diffusion"] = sorted(self.diffusion.items())
         if self._amp:
             fp["amp"] = True
         if self._quant:
@@ -729,7 +761,9 @@ class GenerateRunner:
         params) -> (logits (slots,1,V), state') — THE decode step:
         every slot advances one position; inactive slots compute
         ignored rows (masked attention keeps them finite; a row of
-        length 0 leaves its recurrent state as it was)."""
+        length 0 leaves its recurrent state as it was).  A block-
+        diffusion graph's step takes ``2 * block`` positions a slot and
+        hands back (slots, block, V)."""
         import jax
 
         def fn(*args):
@@ -762,7 +796,7 @@ class GenerateRunner:
             rows = (sds((b, s)), sds((b,)))
         elif kind == "decode":
             (n,) = shp
-            rows = (sds((n, 1)), sds((n,)))
+            rows = (sds((n, self._width)), sds((n,)))
         else:
             raise MXNetError(
                 f"generate: unknown executable kind {kind!r}")
@@ -943,7 +977,9 @@ class GenerateRunner:
              "context_tokens": int(np.sum(step) + np.sum(length))})
 
     def decode(self, tokens: np.ndarray, step: np.ndarray, kv,
-               length=None) -> Tuple["DeviceLogits", Any]:
+               length=None, facts: Optional[Dict[str, int]] = None,
+               then: Optional[Callable[["DeviceLogits"], Dict[str, int]]]
+               = None) -> Tuple["DeviceLogits", Any]:
         """THE decode step: ``tokens (slots, 1)`` / ``step (slots,)``
         advance every slot one position; ``length (slots,)`` is 1 for
         a lane that decodes and 0 for an idle one (all 1 if not
@@ -951,30 +987,61 @@ class GenerateRunner:
         :class:`DeviceLogits`, new device state): the logits stay where
         they are, and each slot's first maximum, found by a second
         small executable, is all that crosses to the host — what a
-        greedy lane needs; ``np.asarray`` of them brings the numbers."""
+        greedy lane needs; ``np.asarray`` of them brings the numbers.
+        A block-diffusion runner's step takes ``tokens (slots, 2
+        block)``, each row's first ``length`` valid, whole blocks from
+        a block's start at ``step``, and hands back its last ``block``
+        positions.  ``facts``: counts of the call the caller knows,
+        written on its region beside the runner's own; ``then(logits)``
+        runs inside the region once the call's results are on the host,
+        and the counts it returns are the region's late counts."""
         if length is None:
             length = np.ones((self._slots,), np.float32)
         on = length > 0
+        counts = {"slots": self._slots, "active": int(on.sum()),
+                  "context_tokens": int(step[on].sum())}
+        if self.diffusion:
+            b = int(self.diffusion["block"])
+            pos = step[:, None] + np.arange(self._width)[None, :]
+            fed = np.arange(self._width)[None, :] < length[:, None]
+            # keys each fed position reads: up to its own block's end
+            counts.update(positions=int(length.sum()), attended=int(
+                ((pos // b + 1) * b)[fed].sum()))
+        counts.update(facts or {})
         return self._call(obs.SPAN_DECODE, ("decode", (self._slots,)),
                           self._rows("decode", tokens, step, length), kv,
-                          {"slots": self._slots, "active": int(on.sum()),
-                           "context_tokens": int(step[on].sum())})
+                          counts, then)
 
-    @staticmethod
-    def _first_maximum_of(entry, logits, *counts):
+    def _first_maximum_of(self, entry, logits, *counts):
         """The executable that finds each row's first maximum of
         ``logits (rows, 1, V)`` on the device, ``(rows,)`` int32, with
         the graph's counts (if it has any) behind them in the same
         array, so that one fetch brings both; built at the entry's
         first run (a set-up's first run of the program, never a
-        token's)."""
+        token's).  A block-diffusion runner's finds, for each of the
+        ``(rows, block)`` positions, the first maximum other than the
+        mask id and its softmax probability (over the whole row), the
+        probability's bits as int32 behind the ids."""
         import jax
         import jax.numpy as jnp
         fn = entry.get("first_maximum")
         if fn is None:
-            def first(rows, *counts):
-                at = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
-                return jnp.concatenate((at,) + counts) if counts else at
+            if self.diffusion:
+                mask_id = int(self.diffusion["mask_id"])
+
+                def first(rows, *counts):
+                    r = rows.astype(jnp.float32)
+                    at = jnp.argmax(r.at[..., mask_id].set(-jnp.inf),
+                                    axis=-1).astype(jnp.int32)
+                    top = jnp.take_along_axis(r, at[..., None], axis=-1)
+                    p = jnp.exp(top[..., 0] - jax.nn.logsumexp(r, axis=-1))
+                    return jnp.concatenate(
+                        (at.reshape(-1), jax.lax.bitcast_convert_type(
+                            p, jnp.int32).reshape(-1)) + counts)
+            else:
+                def first(rows, *counts):
+                    at = jnp.argmax(rows[:, 0, :], axis=-1).astype(jnp.int32)
+                    return jnp.concatenate((at,) + counts) if counts else at
 
             fn = entry["first_maximum"] = jax.jit(first).lower(
                 logits, *counts).compile()
@@ -982,15 +1049,20 @@ class GenerateRunner:
 
     def _call(self, name: str, bucket: Tuple,
               host_rows: Sequence[np.ndarray], kv,
-              counts: Dict[str, int]) -> Tuple["DeviceLogits", Any]:
+              counts: Dict[str, int], then=None
+              ) -> Tuple["DeviceLogits", Any]:
         """One executable call, in the region ``name`` with its three
         children: the host rows staged on the device, the call itself
         with the search for each row's first maximum behind it, and
-        what comes back — those maxima, 4 bytes a row.
-        The call writes a closing child only for a graph's late counts
-        (``moe_experts_touched``)."""
+        what comes back — those maxima, 4 bytes a row (a block-diffusion
+        runner's: 8 bytes a position).
+        The call writes a closing child only for late counts: a
+        graph's (``moe_experts_touched``) and those ``then`` returns."""
         import jax
-        tokens = counts.get("tokens", counts.get("active", 0))
+        # the tokens a call routes: a prefill's valid ones, a decode
+        # step's positions (block diffusion) or its active lanes
+        tokens = counts.get("tokens", counts.get(
+            "positions", counts.get("active", 0)))
         counts = dict(counts, **{n: tokens * by for n, by
                                  in self._token_counters.items()})
         # the host work between the children lies inside them: the
@@ -1015,17 +1087,27 @@ class GenerateRunner:
             with self._region(name + obs.SPAN_FETCH):
                 # mxlint: sync-point — deliberate D2H: token ids (and the graph's counts behind them)
                 first = np.asarray(first)
+                rows = logits.shape[0]
+                # ids (and probabilities) a row
+                per = rows * 2 * logits.shape[1] if self.diffusion else rows
                 if self._m_counted:
-                    rows = logits.shape[0]
                     counts.update(zip(self._device_counters,
-                                      (int(c) for c in first[rows:])))
-                    first = first[:rows]
+                                      (int(c) for c in first[per:])))
+                    first = first[:per]
                     if self._obs:
                         for n, m in self._m_counted.items():
                             m.inc(counts[n])
-                out = DeviceLogits(logits, first)
+                if self.diffusion:
+                    half = per // 2
+                    out = DeviceLogits(
+                        logits, first[:half].reshape(rows, -1),
+                        first[half:per].view(np.float32).reshape(rows, -1))
+                else:
+                    out = DeviceLogits(logits, first)
             if self._m_counted:
                 rg.set(**{n: counts[n] for n in self._device_counters})
+            if then is not None:
+                rg.set(**then(out))
         return out, kv
 
     # -- introspection / contracts ----------------------------------------
@@ -1114,9 +1196,16 @@ class GenerateRunner:
 
 class _Lane:
     """One in-flight generation: the lane's cache frontier (tokens
-    written so far) and the last sampled token (next decode input)."""
+    written so far) and the last sampled token (next decode input).  A
+    block-diffusion lane's frontier is its block's start (every earlier
+    position clean and stored, or riding in ``prev``), and it carries
+    the block: its tokens (the mask id where undecided), which of them
+    are decided, the passes made over it, the clean previous block whose
+    keys and values the block's first pass stores (None once stored),
+    and how many positions are the request's conditioning."""
 
-    __slots__ = ("req", "frontier", "last_token", "t_last")
+    __slots__ = ("req", "frontier", "last_token", "t_last", "block",
+                 "decided", "passes", "prev", "given")
 
     def __init__(self, req: GenerateRequest, frontier: int,
                  last_token: int, t_last: float):
@@ -1124,6 +1213,11 @@ class _Lane:
         self.frontier = frontier
         self.last_token = last_token
         self.t_last = t_last
+        self.block: Optional[List[int]] = None
+        self.decided: Optional[List[bool]] = None
+        self.passes = 0
+        self.prev: Optional[List[int]] = None
+        self.given = 0
 
 
 class GenerateBatcher:
@@ -1138,6 +1232,15 @@ class GenerateBatcher:
     KV capacity / deadline).  No wall time, no threads — fake-clock
     tests drive it deterministically; the server wraps it in a
     stepping thread.
+
+    Over a block-diffusion runner (``runner.diffusion``) a step is a
+    pass: a prefill stores the prompt's whole blocks and emits nothing;
+    each lane then denoises the block that holds the prompt's tail, and
+    the blocks after it, ``steps`` passes at most a block, and streams a
+    block's tokens in order once none is masked.  A pass decides every
+    masked position whose probability passes ``threshold``, or, where
+    that is fewer, the most probable ones up to the masked count spread
+    evenly over the passes left; greedy only.
 
     Lock order: ``_step_lock`` (one stepper at a time) -> ``_cond``
     (queue + lane table); executions run OUTSIDE ``_cond`` so submit
@@ -1200,6 +1303,16 @@ class GenerateBatcher:
         self._m_lanes = obs.gauge(
             "mxtpu_gen_lanes_active",
             "Lanes occupied at the last decode step.")
+        self._diffusion = runner.diffusion
+        self._block = int(runner.diffusion["block"]) \
+            if runner.diffusion else 1
+        if self._diffusion:
+            self._m_decided = obs.counter(
+                "mxtpu_gen_diffusion_decided_total",
+                "Positions a block-diffusion pass decided.")
+            self._m_committed = obs.counter(
+                "mxtpu_gen_blocks_committed_total",
+                "Clean blocks whose keys and values a pass stored.")
 
     # -- submit side ------------------------------------------------------
     def submit(self, prompt: Sequence[int], *,
@@ -1225,6 +1338,17 @@ class GenerateBatcher:
                 f"generate: prompt+prefix ({need}) fills the KV "
                 f"capacity ({self.runner.max_len}) — nothing left to "
                 f"generate")
+        if self._diffusion:
+            b = self._block
+            if top_k > 1:
+                raise MXNetError("generate: a block-diffusion model "
+                                 "decides greedily (top_k 1)")
+            # a pass writes two blocks' columns from its block's start
+            if need - need % b + 2 * b > self.runner.max_len:
+                raise MXNetError(
+                    f"generate: prompt+prefix ({need}) leaves no room "
+                    f"for a block of {b} in the KV capacity "
+                    f"({self.runner.max_len})")
         mt = int(max_tokens if max_tokens is not None
                  else self.default_max_tokens)
         if mt <= len(prefix):
@@ -1350,10 +1474,12 @@ class GenerateBatcher:
                           if l is not None]
             if self._obs:
                 self._m_lanes.set(len(active))
-            rows = self._decode_rows(active) if active else None
+            rows = (self._block_rows if self._diffusion else
+                    self._decode_rows)(active) if active else None
         if active:
-            self._decode_locked(active, rows, emissions, finished,
-                                completions)
+            decode = self._decode_blocks_locked if self._diffusion \
+                else self._decode_locked
+            decode(active, rows, emissions, finished, completions)
         self._fire(emissions, self._step_no)
         if completions:
             with self._region(obs.SPAN_COMPLETE, step=self._step_no,
@@ -1446,7 +1572,10 @@ class GenerateBatcher:
         b = runner.batch_rung_for(len(pairs))
         full = [r.prompt + r.prefix for _, r in pairs]
         need = [len(f) for f in full]
-        chunks = max(1, math.ceil(max(need) / s))
+        # a block-diffusion lane prefills its whole blocks; the block
+        # that holds the tail is denoised
+        fill = [n - n % self._block for n in need]
+        chunks = math.ceil(max(fill) / s)
         if self._obs:
             self._m_rung.labels(rows=b, bucket=s).inc()
         # one event for the group; trace_of finds it under each of its
@@ -1471,9 +1600,9 @@ class GenerateBatcher:
                     length = np.zeros((b,), np.float32)
                     lidx = np.full((b,), runner.scratch_slot, np.float32)
                     for row, (lane, r) in enumerate(pairs):
-                        if base >= need[row]:
+                        if base >= fill[row]:
                             continue  # this row finished in an earlier chunk
-                        valid = min(s, need[row] - base)
+                        valid = min(s, fill[row] - base)
                         tokens[row, :valid] = full[row][base:base + valid]
                         # step 0 starts the lane's recurrent state from
                         # zero; a later chunk carries it on; the padded
@@ -1483,15 +1612,17 @@ class GenerateBatcher:
                         lidx[row] = lane
                 logits, self._kv = runner.prefill(tokens, step, lidx,
                                                   self._kv, length)
-                if c in ends:
+                if c in ends and not self._diffusion:
                     kept[c] = logits
-            with self._region(obs.SPAN_SAMPLE, step=self._step_no,
-                              lanes=len(pairs)):
-                # need[row]: the absolute position of the 1st new token
-                firsts = [sample_token(kept[ends[row]][row, 0],
-                                       position=need[row], seed=r.seed,
-                                       top_k=r.top_k)
-                          for row, (_, r) in enumerate(pairs)]
+            firsts = None   # a block-diffusion prefill emits nothing
+            if not self._diffusion:
+                with self._region(obs.SPAN_SAMPLE, step=self._step_no,
+                                  lanes=len(pairs)):
+                    # need[row]: the absolute position of the 1st new token
+                    firsts = [sample_token(kept[ends[row]][row, 0],
+                                           position=need[row], seed=r.seed,
+                                           top_k=r.top_k)
+                              for row, (_, r) in enumerate(pairs)]
             with self._region(obs.SPAN_COMMIT, step=self._step_no), \
                     self._cond:
                 if self._closed:
@@ -1506,10 +1637,29 @@ class GenerateBatcher:
                             r._fail(_lost_for(r, err), now)
                             finished.append(r)
                     return
-                self._commit_first_tokens_locked(
-                    pairs, need, firsts, emissions, finished,
-                    completions)
+                if self._diffusion:
+                    self._seat_blocks_locked(pairs, need, fill)
+                else:
+                    self._commit_first_tokens_locked(
+                        pairs, need, firsts, emissions, finished,
+                        completions)
                 self._cond.notify_all()
+
+    def _seat_blocks_locked(self, pairs, need, fill) -> None:
+        """Seat each block-diffusion joiner in its lane (under
+        ``_cond``): its first block starts at the prompt's last whole
+        block, holds the prompt's tail, decided, and the mask id in the
+        positions after it.  Nothing is emitted; the first token's gap
+        counts from the submit."""
+        b, mask_id = self._block, int(self._diffusion["mask_id"])
+        for row, (lane, r) in enumerate(pairs):
+            tail = (r.prompt + r.prefix)[fill[row]:]
+            ln = _Lane(r, frontier=fill[row], last_token=-1,
+                       t_last=r.t_submit)
+            ln.block = tail + [mask_id] * (b - len(tail))
+            ln.decided = [True] * len(tail) + [False] * (b - len(tail))
+            ln.given = need[row]
+            self._lanes[lane] = ln
 
     def _commit_first_tokens_locked(self, pairs, need, firsts,
                                     emissions, finished, completions
@@ -1549,6 +1699,109 @@ class GenerateBatcher:
             length[i] = 1
         return tokens, steps, length
 
+    def _block_rows(self, active: List[Tuple[int, _Lane]]):
+        """A block-diffusion pass's host rows: each lane's block from its
+        start, behind the clean previous block on the block's first pass
+        (the fold: its keys and values are stored by this pass, and
+        under the block mask its rows cannot see the block after it);
+        idle slots feed nothing."""
+        b = self._block
+        slots = self.runner.max_lanes + 1
+        tokens = np.zeros((slots, 2 * b), np.float32)
+        steps = np.zeros((slots,), np.float32)
+        length = np.zeros((slots,), np.float32)
+        for i, lane in active:
+            if lane.prev is not None:
+                tokens[i] = lane.prev + lane.block
+                steps[i], length[i] = lane.frontier - b, 2 * b
+            else:
+                tokens[i, :b] = lane.block
+                steps[i], length[i] = lane.frontier, b
+        return tokens, steps, length
+
+    def _decide(self, lane: _Lane, token: np.ndarray,
+                confidence: np.ndarray) -> int:
+        """One pass's decisions over a lane's block: every masked
+        position whose probability passes the threshold, or, where that
+        is fewer, the most probable (the earlier position first among
+        equals) up to the masked count spread evenly over the passes
+        left.  Returns how many positions it decided."""
+        steps = int(self._diffusion["steps"])
+        masked = [j for j, d in enumerate(lane.decided) if not d]
+        need = -(-len(masked) // max(1, steps - lane.passes))
+        sure = [j for j in masked
+                if confidence[j] > self._diffusion["threshold"]]
+        if len(sure) < need:
+            sure = sorted(masked, key=lambda j: (-confidence[j], j))[:need]
+        for j in sure:
+            lane.block[j] = int(token[j])
+            lane.decided[j] = True
+        lane.passes += 1
+        lane.prev = None
+        return len(sure)
+
+    def _decode_blocks_locked(self, active: List[Tuple[int, _Lane]], rows,
+                              emissions, finished, completions) -> None:
+        """ONE block-diffusion pass over the whole slot table (``rows``:
+        :meth:`_block_rows`), then, inside its region, each lane's
+        decisions and the streaming of every block that has no masked
+        position left, in order, cut at ``max_tokens``; a finished lane
+        is released, any other goes on to its next block."""
+        b, mask_id = self._block, int(self._diffusion["mask_id"])
+        folded = sum(1 for _, lane in active if lane.prev is not None)
+        done: List[Tuple[int, _Lane, str]] = []
+
+        def decide(logits) -> Dict[str, int]:
+            decided = blocks = 0
+            with self._region(obs.SPAN_SAMPLE, step=self._step_no,
+                              lanes=len(active)):
+                t_emit = self._clock()
+                for i, lane in active:
+                    decided += self._decide(lane, logits.first_maximum[i],
+                                            logits.confidence[i])
+                    if not all(lane.decided):
+                        continue
+                    blocks += 1
+                    r = lane.req
+                    reason = None
+                    dt = t_emit - lane.t_last
+                    for p, tok in enumerate(lane.block, lane.frontier):
+                        if p < lane.given or r.emitted >= r.max_tokens:
+                            continue
+                        r.tokens.append(tok)
+                        emissions.append((r, tok, r.emitted - 1,
+                                          len(r.tokens) == 1, dt))
+                        dt = 0.0
+                        if r.eos_id is not None and tok == r.eos_id:
+                            reason = "eos"
+                            break
+                    lane.t_last = t_emit
+                    if reason is None and r.emitted >= r.max_tokens:
+                        reason = "length"
+                    # the next block's passes write two blocks' columns
+                    # from its start
+                    if reason is None and \
+                            lane.frontier + 3 * b > self.runner.max_len:
+                        reason = "length"
+                    if reason is not None:
+                        done.append((i, lane, reason))
+                        continue
+                    lane.prev, lane.frontier = lane.block, lane.frontier + b
+                    lane.block = [mask_id] * b
+                    lane.decided, lane.passes = [False] * b, 0
+            if self._obs:
+                self._m_decided.inc(decided)
+                self._m_committed.inc(folded)
+            return {"decided": decided, "emitted_blocks": blocks}
+
+        if self._kv is None:
+            self._kv = self.runner.new_cache()
+        tokens, steps, length = rows
+        _, self._kv = self.runner.decode(tokens, steps, self._kv, length,
+                                         facts={"committed": folded},
+                                         then=decide)
+        self._release_locked(done, finished, completions)
+
     def _decode_locked(self, active: List[Tuple[int, _Lane]], rows,
                        emissions, finished, completions) -> None:
         """ONE decode dispatch over the whole slot table (``rows``:
@@ -1581,6 +1834,13 @@ class GenerateBatcher:
                 reason = self._finish_reason(r, lane)
                 if reason is not None:
                     done.append((i, lane, reason))
+        self._release_locked(done, finished, completions)
+
+    def _release_locked(self, done: List[Tuple[int, _Lane, str]],
+                        finished, completions) -> None:
+        """After a step (``_step_lock`` held): count it and free the
+        lanes of ``done`` (``(lane index, lane, finish reason)``) under
+        ``_cond``."""
         with self._region(obs.SPAN_COMMIT, step=self._step_no), \
                 self._cond:
             self._steps += 1

@@ -16,6 +16,8 @@ never at import, so every worker collects the same tests.
 and see the CPU here, so the tests steer them to the TPU answer by
 monkeypatch — the program grows no option for it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -316,14 +318,15 @@ def test_recurrent_state_kernel_compiles_in_place_on_v5e(
 
 
 def _described_runner(monkeypatch, topo, one_chip, config, inputs, cut=None,
-                      **runner_kw):
+                      depth=None, **runner_kw):
     """A ``GenerateRunner`` of the model of ``benchmark/configs/<config>``
-    at its published widths (``cut``: a slice of its layers) on the
-    described chip: nothing can be put on such a device, so the weights
-    are bfloat16 shapes and ``jax.device_put`` hands shapes back.
+    at its published widths (``cut``: a slice of its layers; ``depth``:
+    its first layers, where the file counts them) on the described chip:
+    nothing can be put on such a device, so the weights are bfloat16
+    shapes and ``jax.device_put`` hands shapes back.
     ``runner_kw['spec']`` is called with the net for the state spec,
-    ``runner_kw['counters']`` likewise where given.  Returns the
-    runner."""
+    ``runner_kw['counters']`` and ``runner_kw['diffusion']`` likewise
+    where given.  Returns the runner."""
     import json
     import os
     from mxtpu import symbol as sym_mod
@@ -334,6 +337,8 @@ def _described_runner(monkeypatch, topo, one_chip, config, inputs, cut=None,
         cfg = json.load(f)
     if cut is not None:
         cfg["layer_types"] = cfg["layer_types"][cut]
+    if depth is not None:
+        cfg["num_hidden_layers"] = depth
     net = HybridDecoderModel.from_config(cfg)
     out = net(*[sym_mod.var(f"data{i}") for i in range(inputs)])
 
@@ -351,8 +356,9 @@ def _described_runner(monkeypatch, topo, one_chip, config, inputs, cut=None,
     monkeypatch.setattr(GenerateRunner, "_as_np",
                         staticmethod(lambda v: v.asnumpy()))
     spec = runner_kw.pop("spec")(net)
-    if "counters" in runner_kw:
-        runner_kw["counters"] = runner_kw["counters"](net)
+    for key in ("counters", "diffusion"):
+        if key in runner_kw:
+            runner_kw[key] = runner_kw[key](net)
     return GenerateRunner(
         sym_mod.Group(list(out)),
         {p.name: Leaf(p.shape) for p in net.collect_params().values()},
@@ -574,7 +580,7 @@ def test_mellum_prefill_attention_stops_at_each_frontier_on_v5e(
     text, mem = analysis.executable_artifact(entry["compiled"])
     structs = r._structs(bucket)
     with monkeypatch.context() as m:
-        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k, block=1: False)
         xla_text, xla_mem = analysis.compiled_artifact(
             r._prefill_pure(), *structs, r._param_structs,
             donate_argnums=(len(structs) - 1,))
@@ -599,6 +605,53 @@ def test_mellum_prefill_attention_stops_at_each_frontier_on_v5e(
 
     taken = decode_text()
     with monkeypatch.context() as m:
-        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k, block=1: False)
         assert decode_text() == taken
     assert "cached_attention_fwd" not in taken
+
+
+@pytest.fixture
+def sdar_stage(topo, one_chip, monkeypatch):
+    """A ``GenerateRunner`` of the block-diffusion cell's model at its
+    published widths, cut to two layers, 48 lanes over 2,560 positions,
+    on the described chip (``_described_runner``)."""
+    return _described_runner(
+        monkeypatch, topo, one_chip, "sdar_30b_a3b_chat.json", 4, depth=2,
+        spec=lambda net: net.state_spec(48, 2560, kv_dtype="bfloat16"),
+        counters=lambda net: net.counter_spec(),
+        diffusion=lambda net: net.diffusion_spec(), prompt_buckets=(256,),
+        max_prefill_batch=1)
+
+
+@pytest.mark.parametrize("bucket", [("decode", (49,)), ("prefill", (1, 256))],
+                         ids=["pass", "prefill-1x256"])
+def test_a_block_diffusion_pass_compiles_for_v5e(
+        bucket, sdar_stage, on_tpu, chip_layouts, no_persistent_cache):
+    """The block-diffusion cell's pass (49 slots of 8 positions: a clean
+    block and the block it denoises) and one prefill call (a row of 256)
+    at the published widths — hidden 2,048, 32 query over 4 key/value
+    heads of 128, 128 experts of 768 of which a position takes 8, a
+    vocabulary of 151,936, bfloat16 — two of the six layers, the model's
+    own graph through ``GenerateRunner``'s own program.  The experts'
+    products are the grouped-matmul kernel, the donated table is aliased
+    to the result, the block-masked attention keeps XLA's form (the
+    chunk kernel knows no block mask), and the pass's logits are 4
+    positions a slot, not 8."""
+    from mxtpu import analysis
+    r = sdar_stage
+    fn = r._prefill_pure() if bucket[0] == "prefill" else r._decode_pure()
+    structs = r._structs(bucket)
+    assert structs[0].shape == ((49, 8) if bucket[0] == "decode"
+                                else (1, 256))
+    text, mem = analysis.compiled_artifact(
+        fn, *structs, r._param_structs, donate_argnums=(len(structs) - 1,))
+    tables = sum(r.state_bytes().values())
+    assert tables == 2 * 2 * 49 * 4 * 2560 * 128 * 2
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < 1.0e9, mem
+    grouped = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/moe/experts/" in line]
+    assert len(grouped) == 4 and "ragged-dot" not in text
+    assert "cached_attention_fwd" not in text
+    rows = 49 if bucket[0] == "decode" else 1
+    assert re.search(rf"f32\[{rows},4,151936\]", text)

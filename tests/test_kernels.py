@@ -588,7 +588,7 @@ def _as_the_chip_feeds(q, k, v, step, window):
         return real(spec, bf(a), bf(b), **kw)
 
     with pytest.MonkeyPatch.context() as m:
-        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k, block=1: False)
         m.setattr(jnp, "einsum", einsum)
         return rnn_impl._cached_attention_op(q, k, v, step, window=window)
 
@@ -665,7 +665,7 @@ def test_cached_attention_kernel_matches_the_xla_form(monkeypatch, case,
             q, jnp.swapaxes(table, -1, -2) if keys_minor else table, step,
             1 / np.sqrt(D), 1, window=window, keys_minor=keys_minor)
     with monkeypatch.context() as m:
-        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k, block=1: False)
         xla = rnn_impl._cached_attention_op(q, k, v, step, window=window)
     assert got.shape == q.shape and got.dtype == q.dtype
     got, xla = np.asarray(got), np.asarray(xla)
@@ -696,7 +696,7 @@ def test_cached_attention_planes_alone_keep_the_xla_form(monkeypatch):
                                               window=512, layer=1)
     assert sites[0] == 1
     with pytest.MonkeyPatch.context() as m:
-        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k: False)
+        m.setattr(rnn_impl, "_attends_by_kernel", lambda q, k, block=1: False)
         xla = rnn_impl._cached_attention_op(q, table, table, step,
                                             window=512, layer=1)
     np.testing.assert_array_equal(np.asarray(alone), np.asarray(xla))
