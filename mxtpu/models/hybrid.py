@@ -114,29 +114,61 @@ class GatedMLP(HybridBlock):
 
 class SparseMLP(HybridBlock):
     """``num_experts`` SwiGLU experts of ``hidden_size``, of which each
-    token takes ``top_k`` by a softmax router (renormalised over the
-    chosen where ``norm_topk``); no bias, no shared expert, no capacity:
-    no token is dropped (``routed_experts``).  Leaves stacked on the
-    expert axis: ``router`` (units, E), ``w_in`` (E, units, 2 hidden)
-    gate over up, ``w_out`` (E, hidden, units).  Takes the rows'
-    ``length`` (padded tokens are routed nowhere) and hands back the
-    count of experts touched beside the output."""
+    token takes ``top_k`` by a router (``score``: softmax, or a sigmoid
+    a logit; renormalised over the chosen where ``norm_topk``, then
+    times ``route_scale``); no capacity: no token is dropped
+    (``routed_experts``).  ``selection_bias`` adds a leaf ``bias`` (E,)
+    to the scores that choose and not to the weights; ``shared_size`` >
+    0 adds a shared SwiGLU expert every token passes (``shared_in``
+    (units, 2 shared_size), ``shared_out``).  ``held`` = (first, count)
+    makes the layer this device's share of an expert-parallel one: the
+    router scores all ``num_experts`` and the layer holds ``count`` of
+    them.  Leaves stacked on the expert axis: ``router`` (units, E),
+    ``w_in`` (held, units, 2 hidden) gate over up, ``w_out`` (held,
+    hidden, units).  Takes the rows' ``length`` (padded tokens are
+    routed nowhere) and hands back the count of held experts touched
+    (and, with ``held``, of the assignments that landed on them) beside
+    the output."""
 
     def __init__(self, units, hidden_size, num_experts, top_k,
-                 norm_topk=True, **kwargs):
+                 norm_topk=True, score="softmax", route_scale=1.0,
+                 selection_bias=False, shared_size=0, held=None, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"top_k": int(top_k), "norm_topk": bool(norm_topk)}
+        # attributes a plain layer's op is not given: its graph is the
+        # one it always was
+        if score != "softmax":
+            self._attrs["score"] = score
+        if route_scale != 1.0:
+            self._attrs["route_scale"] = float(route_scale)
+        if held is not None:
+            self._attrs.update(held_first=int(held[0]),
+                               held_count=int(held[1]))
+        if selection_bias:
+            self._attrs["bias"] = True
+        if shared_size:
+            self._attrs["shared"] = True
+        stacked = int(held[1]) if held is not None else num_experts
         self.router = self.params.get(
             "router", shape=(units, num_experts), init="normal")
+        if selection_bias:
+            self.bias = self.params.get("bias", shape=(num_experts,),
+                                        init="zeros")
         self.w_in = self.params.get(
-            "w_in", shape=(num_experts, units, 2 * hidden_size),
-            init="normal")
+            "w_in", shape=(stacked, units, 2 * hidden_size), init="normal")
         self.w_out = self.params.get(
-            "w_out", shape=(num_experts, hidden_size, units), init="normal")
+            "w_out", shape=(stacked, hidden_size, units), init="normal")
+        if shared_size:
+            self.shared_in = self.params.get(
+                "shared_in", shape=(units, 2 * shared_size), init="normal")
+            self.shared_out = self.params.get(
+                "shared_out", shape=(shared_size, units), init="normal")
 
     def hybrid_forward(self, F, x, length, router=None, w_in=None,
-                       w_out=None):
-        return F.routed_experts(x, router, w_in, w_out, length,
+                       w_out=None, bias=None, shared_in=None,
+                       shared_out=None):
+        extra = [t for t in (bias, shared_in, shared_out) if t is not None]
+        return F.routed_experts(x, router, w_in, w_out, length, *extra,
                                 **self._attrs)
 
 
@@ -153,11 +185,14 @@ class GroupedQueryAttention(HybridBlock):
     and keys by absolute position (after the norm, keys before they
     are cached); ``window`` > 0 bounds a query's context and makes the
     block's table a ring of columns; ``block`` > 1 replaces the causal
-    mask by the block-causal one (a query sees its own whole block)."""
+    mask by the block-causal one (a query sees its own whole block).
+    ``gate`` multiplies the heads' outputs by ``sigmoid(x W_gate)``
+    before ``o`` (``W_gate``: a leaf of ``num_heads * head_dim`` outputs
+    over ``units``; scope ``attn_gate``)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  sm_scale, cache_layer=0, qk_norm=None, qk_norm_eps=1e-5,
-                 rope=None, window=0, block=1, **kwargs):
+                 rope=None, window=0, block=1, gate=False, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_heads} query heads do not divide "
@@ -171,6 +206,9 @@ class GroupedQueryAttention(HybridBlock):
         self.k = _dense(num_kv_heads * head_dim, units)
         self.v = _dense(num_kv_heads * head_dim, units)
         self.o = _dense(units, num_heads * head_dim)
+        self._gated = bool(gate)
+        if self._gated:
+            self.gate = _dense(num_heads * head_dim, units)
         self._qk_norm = qk_norm
         if qk_norm:
             per = 1 if qk_norm == "head" else None
@@ -221,6 +259,8 @@ class GroupedQueryAttention(HybridBlock):
                                  **self._read)
         out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                         shape=(0, -1, hq * d))
+        if self._gated:
+            out = F.attn_gate(out, self.gate(x))
         return self.o(out), kv
 
 
@@ -343,9 +383,11 @@ class HybridDecoderLayer(HybridBlock):
     ``tables`` names the state tables the mixer takes, in its order
     (``("kv",)``, ``("kv_win",)``, or the recurrent table and
     ``"conv"``): the layer is called with those and hands them back,
-    ``(x, *tables)``; a ``SparseMLP`` for ``mlp`` adds its count of
-    experts touched at the end.  ``layout`` puts each norm on its
-    sublayer's input (``"pre"``) or on its output (``"post"``)."""
+    ``(x, *tables)``; a ``SparseMLP`` for ``mlp`` adds its counts at
+    the end.  ``layout`` puts each norm on its sublayer's input
+    (``"pre"``), on its output (``"post"``), or one on each
+    (``"sandwich"``: four norms a layer, ``norm1`` and ``norm2`` on the
+    inputs, ``post_norm1`` and ``post_norm2`` on the outputs)."""
 
     def __init__(self, tables, mixer, units, mlp, residual, eps,
                  layout="pre", **kwargs):
@@ -353,28 +395,35 @@ class HybridDecoderLayer(HybridBlock):
         self.tables = tuple(tables)
         self._attention = len(self.tables) == 1
         self._residual = float(residual)
-        self._post = layout == "post"
+        self._pre = layout in ("pre", "sandwich")
+        self._post = layout in ("post", "sandwich")
         self.sparse = isinstance(mlp, SparseMLP)
         self.norm1 = RMSNorm(units, eps)
         self.mixer = mixer
         self.norm2 = RMSNorm(units, eps)
         self.mlp = mlp
+        # the post layout's one norm a sublayer is norm1 / norm2
+        self._out = (self.norm1, self.norm2)
+        if layout == "sandwich":
+            self.post_norm1 = RMSNorm(units, eps)
+            self.post_norm2 = RMSNorm(units, eps)
+            self._out = (self.post_norm1, self.post_norm2)
 
     def hybrid_forward(self, F, x, step, length, *state):
-        h = x if self._post else self.norm1(x)
+        h = self.norm1(x) if self._pre else x
         if self._attention:
             h, *state = self.mixer(h, step, *state)
         else:
             h, *state = self.mixer(h, step, length, *state)
-        x = x + (self.norm1(h) if self._post else h) * self._residual
-        h = x if self._post else self.norm2(x)
+        x = x + (self._out[0](h) if self._post else h) * self._residual
+        h = self.norm2(x) if self._pre else x
         counts = ()
         if self.sparse:
             h, touched = self.mlp(h, length)
             counts = (touched,)
         else:
             h = self.mlp(h)
-        x = x + (self.norm2(h) if self._post else h) * self._residual
+        x = x + (self._out[1](h) if self._post else h) * self._residual
         return (x,) + tuple(state) + counts
 
 
@@ -387,8 +436,16 @@ class HybridDecoderModel(HybridBlock):
     recurrent layer planes ``[j]`` of the recurrent table and of
     ``conv``.  ``rope`` maps a kind of attention layer
     (``"full_attention"``, ``"sliding_attention"``) to its
-    (frequencies, scale); ``num_experts`` > 0 makes every feed-forward
-    a ``SparseMLP`` of experts ``hidden_size`` wide.  ``diffusion``
+    (frequencies, scale); a kind without a table takes no position.
+    ``num_experts`` > 0 makes every feed-forward past the first
+    ``dense_layers`` (gated MLPs of ``dense_hidden_size``) a
+    ``SparseMLP`` of experts ``hidden_size`` wide, routed by
+    ``router_score`` with ``route_scale``, a ``selection_bias`` and a
+    shared expert of ``shared_expert_size`` where given;
+    ``expert_share`` = (first, count) holds ``count`` of the
+    ``num_experts`` routed over (one device's share of an
+    expert-parallel layer).  ``attention_gate`` gates every attention
+    layer's output.  ``diffusion``
     (``{"block", "mask_id", "steps", "threshold"}``) makes the model a
     block-diffusion decoder: every attention layer takes the block mask
     and the logits are each row's last ``block`` positions; the runner
@@ -408,7 +465,11 @@ class HybridDecoderModel(HybridBlock):
                  logits_scaling=1.0, layout="pre", qk_norm=None,
                  rope=None, sliding_window=0, num_experts=0,
                  experts_per_token=0, norm_topk=True,
-                 tie_embeddings=True, diffusion=None, **kwargs):
+                 tie_embeddings=True, diffusion=None, attention_gate=False,
+                 dense_layers=0, dense_hidden_size=None,
+                 router_score="softmax", route_scale=1.0,
+                 selection_bias=False, shared_expert_size=0,
+                 expert_share=None, **kwargs):
         super().__init__(**kwargs)
         bad = set(layer_types) - set(self.ATTENTION) - {self.SLIDING} \
             - set(self.RECURRENT)
@@ -418,7 +479,7 @@ class HybridDecoderModel(HybridBlock):
         if len(recurrent) > 1:
             raise MXNetError(f"one recurrent kind of layer a model, not "
                              f"{recurrent}")
-        if layout not in ("pre", "post"):
+        if layout not in ("pre", "post", "sandwich"):
             raise MXNetError(f"unknown block layout {layout!r}")
         if self.SLIDING in layer_types and sliding_window < 1:
             raise MXNetError("sliding_attention layers need a "
@@ -430,6 +491,8 @@ class HybridDecoderModel(HybridBlock):
         self._attn = (num_kv_heads, head_dim)
         self._window = int(sliding_window)
         self._top_k = int(experts_per_token) if num_experts else 0
+        self._share = tuple(expert_share) if expert_share and num_experts \
+            else None
         self._diffusion = dict(diffusion) if diffusion else None
         self._block = int(diffusion["block"]) if diffusion else 1
         # the recurrent table's name, a lane's shape in it, and the
@@ -455,7 +518,7 @@ class HybridDecoderModel(HybridBlock):
         rope = rope or {}
         self.layers = nn.HybridSequential()
         n_attn = n_win = n_rec = 0
-        for kind in self.layer_types:
+        for i, kind in enumerate(self.layer_types):
             if kind in self.ATTENTION or kind == self.SLIDING:
                 sliding = kind == self.SLIDING
                 mixer = GroupedQueryAttention(
@@ -466,7 +529,7 @@ class HybridDecoderModel(HybridBlock):
                     rope=rope.get(self.SLIDING if sliding
                                   else "full_attention"),
                     window=self._window if sliding else 0,
-                    block=self._block)
+                    block=self._block, gate=attention_gate)
                 if sliding:
                     tables, n_win = ("kv_win",), n_win + 1
                 else:
@@ -484,9 +547,17 @@ class HybridDecoderModel(HybridBlock):
                     cache_layer=n_rec)
                 tables = ("delta", "conv")
                 n_rec += 1
-            mlp = SparseMLP(units, hidden_size, num_experts,
-                            experts_per_token, norm_topk) if num_experts \
-                else GatedMLP(units, hidden_size)
+            if i < dense_layers:
+                mlp = GatedMLP(units, dense_hidden_size or hidden_size)
+            elif num_experts:
+                mlp = SparseMLP(units, hidden_size, num_experts,
+                                experts_per_token, norm_topk,
+                                score=router_score, route_scale=route_scale,
+                                selection_bias=selection_bias,
+                                shared_size=shared_expert_size,
+                                held=self._share)
+            else:
+                mlp = GatedMLP(units, hidden_size)
             self.layers.add(HybridDecoderLayer(
                 tables, mixer, units, mlp, residual_multiplier, eps,
                 layout=layout))
@@ -501,8 +572,8 @@ class HybridDecoderModel(HybridBlock):
         """The model of a published ``config.json`` (as a dict), by its
         ``model_type``: ``granitemoehybrid`` (also where the key is
         absent) that routes to no expert, ``olmo_hybrid``, ``mellum``,
-        or ``sdar_moe``.  A configuration cut in depth (``layer_types``
-        shorter than the published list, or a smaller
+        ``sdar_moe`` or ``afmoe``.  A configuration cut in depth
+        (``layer_types`` shorter than the published list, or a smaller
         ``num_hidden_layers``) builds the model's first layers."""
         kind = cfg.get("model_type", "granitemoehybrid")
         if kind == "granitemoehybrid":
@@ -513,9 +584,11 @@ class HybridDecoderModel(HybridBlock):
             return cls._from_mellum(cfg)
         if kind == "sdar_moe":
             return cls._from_sdar_moe(cfg)
+        if kind == "afmoe":
+            return cls._from_afmoe(cfg)
         raise MXNetError(f"HybridDecoderModel: unknown model_type {kind!r} "
                          f"(granitemoehybrid, olmo_hybrid, mellum, "
-                         f"sdar_moe)")
+                         f"sdar_moe, afmoe)")
 
     @classmethod
     def _from_granite(cls, cfg):
@@ -649,6 +722,64 @@ class HybridDecoderModel(HybridBlock):
                        "steps": int(cfg["denoising_steps"]),
                        "threshold": float(cfg["confidence_threshold"])})
 
+    @classmethod
+    def _from_afmoe(cls, cfg):
+        """Sliding and full attention in the published pattern (arcee's
+        Trinity): per-head QK-norm, a rotary table for the sliding
+        layers alone (the full ones take no position), every attention
+        output gated by ``sigmoid(x W_gate)``; four RMS norms a layer
+        (``sandwich``); the first ``num_dense_layers`` feed-forwards a
+        gated MLP of ``intermediate_size``, the rest routed experts by a
+        sigmoid a logit with a selection bias, renormalised
+        (``route_norm``) and scaled (``route_scale``), beside
+        ``num_shared_experts`` shared experts; the embedding times
+        ``sqrt(hidden_size)`` where ``mup_enabled``.  ``expert_share``
+        (``{"of": E, "first": f}``) makes ``num_experts`` the experts
+        this device holds of the E routed over."""
+        import math
+
+        from ..ndarray.rnn_impl import rope_frequencies
+        cls._known_kinds(cfg, ("sliding_attention", "full_attention"))
+        if cfg.get("score_func", "sigmoid") not in ("sigmoid", "softmax"):
+            raise MXNetError(f"HybridDecoderModel: an afmoe router that "
+                             f"scores by {cfg['score_func']!r}")
+        if max(cfg.get("n_group", 1), cfg.get("topk_group", 1)) != 1:
+            raise MXNetError("HybridDecoderModel: an afmoe model that "
+                             "routes by groups of experts")
+        if cfg.get("rope_scaling") or cfg.get("attention_bias"):
+            raise MXNetError("HybridDecoderModel: an afmoe model with "
+                             "scaled rotation or attention biases")
+        share = cfg.get("expert_share")
+        routed = int(share["of"]) if share else int(cfg["num_experts"])
+        held = (int(share["first"]), int(cfg["num_experts"])) \
+            if share else None
+        if held and not 0 <= held[0] <= routed - held[1]:
+            raise MXNetError(f"HybridDecoderModel: experts {held[0]} to "
+                             f"{held[0] + held[1] - 1} held of {routed}")
+        return cls(
+            cfg["vocab_size"], cfg["hidden_size"],
+            cfg["moe_intermediate_size"], cfg["layer_types"],
+            cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            head_dim=cfg["head_dim"], eps=cfg["rms_norm_eps"],
+            embedding_multiplier=math.sqrt(cfg["hidden_size"])
+            if cfg.get("mup_enabled") else 1.0,
+            layout="sandwich", qk_norm="head",
+            rope={"sliding_attention": (rope_frequencies(
+                cfg["head_dim"], cfg["rope_theta"]), 1.0)},
+            sliding_window=cfg["sliding_window"], num_experts=routed,
+            experts_per_token=cfg["num_experts_per_tok"],
+            norm_topk=bool(cfg.get("route_norm", True)),
+            tie_embeddings=bool(cfg.get("tie_word_embeddings")),
+            attention_gate=True,
+            dense_layers=int(cfg.get("num_dense_layers", 0)),
+            dense_hidden_size=cfg["intermediate_size"],
+            router_score=cfg.get("score_func", "sigmoid"),
+            route_scale=float(cfg.get("route_scale", 1.0)),
+            selection_bias=True,
+            shared_expert_size=int(cfg.get("num_shared_experts", 0))
+            * cfg["moe_intermediate_size"],
+            expert_share=held)
+
     @staticmethod
     def _known_kinds(cfg, kinds):
         bad = sorted(set(cfg["layer_types"]) - set(kinds))
@@ -695,10 +826,13 @@ class HybridDecoderModel(HybridBlock):
         ``device``: the names of the graph's ``counts`` output's
         entries (found on the device, one int32 each a call);
         ``per_token``: counts that are a multiple of a call's valid
-        tokens, known on the host."""
+        tokens, known on the host.  A model that holds a share of its
+        experts counts, beside the experts touched, the assignments
+        that landed on them (``moe_assignments_held``)."""
         if not self._top_k:
             return {"device": (), "per_token": {}}
-        return {"device": ("moe_experts_touched",),
+        held = ("moe_assignments_held",) if self._share else ()
+        return {"device": ("moe_experts_touched",) + held,
                 "per_token": {"moe_assignments": self._top_k}}
 
     def diffusion_spec(self):
@@ -718,6 +852,8 @@ class HybridDecoderModel(HybridBlock):
             if isinstance(m, GroupedQueryAttention):
                 out.update({p + "q": m.q.weight, p + "k": m.k.weight,
                             p + "v": m.v.weight, p + "o": m.o.weight})
+                if m._gated:
+                    out[p + "gate"] = m.gate.weight
                 if m._qk_norm:
                     out.update({p + "q_norm": m.q_norm.gamma,
                                 p + "k_norm": m.k_norm.gamma})
@@ -733,11 +869,18 @@ class HybridDecoderModel(HybridBlock):
                 out.update({
                     p + "conv_w": m.conv_weight, p + "a_log": m.a_log,
                     p + "dt_bias": m.dt_bias, p + "o_norm": m.norm_gamma})
+            if layer._out[0] is not layer.norm1:
+                out[p + "post_norm1"] = layer.post_norm1.gamma
             out[p + "norm2"] = layer.norm2.gamma
+            if layer._out[1] is not layer.norm2:
+                out[p + "post_norm2"] = layer.post_norm2.gamma
             if layer.sparse:
-                out.update({p + "router": layer.mlp.router,
-                            p + "w_in": layer.mlp.w_in,
-                            p + "w_out": layer.mlp.w_out})
+                mlp = layer.mlp
+                out.update({p + "router": mlp.router, p + "w_in": mlp.w_in,
+                            p + "w_out": mlp.w_out})
+                out.update({p + n: getattr(mlp, n) for n in
+                            ("bias", "shared_in", "shared_out")
+                            if hasattr(mlp, n)})
             else:
                 out[p + "mlp_in"] = layer.mlp.w_in.weight
                 out[p + "mlp_out"] = layer.mlp.w_out.weight

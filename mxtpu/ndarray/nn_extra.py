@@ -734,26 +734,46 @@ register_op("_contrib_MoEFFN", num_inputs=6, num_outputs=2,
             aliases=("MoEFFN",))(_contrib_moe_ffn)
 
 
-def _routed_experts_op(data, router, w_in, w_out, length, top_k=2,
-                       norm_topk=True):
+def _routed_experts_op(data, router, w_in, w_out, length, *extra, top_k=2,
+                       norm_topk=True, score="softmax", route_scale=1.0,
+                       held_first=0, held_count=0, bias=False, shared=False):
     """Sparse SwiGLU feed-forward of a served decoder: top-k routing
     with no capacity and no dropped token (``parallel.moe
     .routed_experts``).  ``data``: (B, T, D), of which row b's first
     ``length_b`` tokens are valid (the rest are routed nowhere);
-    ``router``: (D, E); ``w_in``: (E, D, 2 F) gate over up; ``w_out``:
-    (E, F, D).  Returns ``(y (B, T, D) in data's dtype, touched (1,)
-    int32)``: how many experts this call gave a token."""
+    ``router``: (D, E); ``w_in``: (E', D, 2 F) gate over up; ``w_out``:
+    (E', F, D).  After the lengths come, where ``bias``, the selection
+    bias (E,), and, where ``shared``, a shared expert's ``shared_in``
+    (D, 2 F') and ``shared_out`` (F', D).  ``held_count`` > 0 makes the
+    layer this device's share of an expert-parallel one: experts
+    ``held_first ..`` of the E routed over (E' = ``held_count``).
+    Returns ``(y (B, T, D) in data's dtype, counts int32)``: how many
+    held experts this call gave a token, (1,), or with a share that and
+    the assignments that landed on held experts, (2,)."""
     from ..parallel.moe import routed_experts  # lazy: avoids an import cycle
     valid = jnp.arange(data.shape[1], dtype=jnp.int32)[None, :] \
         < jnp.asarray(length).astype(jnp.int32)[:, None]
-    y, touched = routed_experts(data, router, w_in, w_out, valid,
-                                top_k=int(top_k),
-                                renormalise=bool(norm_topk))
-    return y.astype(data.dtype), touched.reshape(1)
+    if len(extra) != int(bool(bias)) + 2 * int(bool(shared)):
+        raise MXNetError(f"routed_experts: {len(extra)} inputs after the "
+                         f"lengths where bias={bias} and shared={shared}")
+    y, counts = routed_experts(
+        data, router, w_in, w_out, valid, top_k=int(top_k),
+        renormalise=bool(norm_topk), score=score,
+        bias=extra[0] if bias else None, scale=float(route_scale),
+        shared=tuple(extra[-2:]) if shared else None,
+        held=(int(held_first), int(held_count)) if held_count else None)
+    return y.astype(data.dtype), counts.reshape(-1)
 
 
-register_op("routed_experts", num_inputs=5, num_outputs=2,
+register_op("routed_experts", num_inputs=-1, num_outputs=2,
             differentiable=False,
             params=[Param("top_k", int, 2, lower=1),
-                    Param("norm_topk", bool, True)],
+                    Param("norm_topk", bool, True),
+                    Param("score", str, "softmax",
+                          enum=("softmax", "sigmoid")),
+                    Param("route_scale", float, 1.0),
+                    Param("held_first", int, 0, lower=0),
+                    Param("held_count", int, 0, lower=0),
+                    Param("bias", bool, False),
+                    Param("shared", bool, False)],
             doc=_routed_experts_op.__doc__)(_routed_experts_op)

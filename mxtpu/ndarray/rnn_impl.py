@@ -649,6 +649,18 @@ register_op("rms_norm", num_inputs=2,
             doc=_rms_norm_op.__doc__)(_rms_norm_op)
 
 
+def _attn_gate_op(x, gate):
+    """An attention layer's output gate: ``x * sigmoid(gate)``, in
+    float32 whatever ``x`` is, under the scope ``attn_gate``."""
+    with jax.named_scope("attn_gate"):
+        return (x.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+
+
+register_op("attn_gate", num_inputs=2,
+            doc=_attn_gate_op.__doc__)(_attn_gate_op)
+
+
 def _gated_rms_norm_op(y, z, weight, eps=1e-5, group=0,
                        norm_before_gate=False):
     """A mixer's output gate and norm.  As Mamba-2 has them:

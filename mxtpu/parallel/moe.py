@@ -22,8 +22,10 @@ NCCL/MPI frameworks hand-code.  Tokens over capacity are dropped
 Switch semantics.
 
 The serving path routes otherwise (``topk_router``,
-``routed_experts``): softmax over the experts, the ``top_k`` largest a
-token, renormalised, NO capacity and no dropped token.  Tokens are
+``routed_experts``): softmax (or a sigmoid a logit) over the experts,
+the ``top_k`` largest a token (after a selection bias, where there is
+one), renormalised, NO capacity and no dropped token; a device may hold
+a share of the experts (``held``) beside a shared expert.  Tokens are
 sorted by expert and the experts' products are grouped matrix products
 over the experts held (``_grouped_dot``: on the TPU the megablox
 grouped-matmul Pallas kernel, which walks the groups' tiles of rows and
@@ -160,21 +162,38 @@ class MoEFFN:
                        jitter=jitter)
 
 
-def topk_router(x2d, router_w, top_k: int, *, renormalise: bool = True):
+def topk_router(x2d, router_w, top_k: int, *, renormalise: bool = True,
+                score: str = "softmax", bias=None, scale: float = 1.0):
     """Top-k routing with nothing dropped: ``(weights (T, k), experts
     (T, k) int32)``.  ``x2d``: (T, D); ``router_w``: (D, E).  The
-    router's product and its softmax are float32 at HIGHEST precision
+    router's product and its scores are float32 at HIGHEST precision
     whatever the inputs are (E multiply-adds a token and feature: its
     cost is nothing, and a rounded router picks other experts at
-    near-ties); the ``top_k`` largest probabilities, the lower index
-    first among equals, divided by their sum where ``renormalise``."""
+    near-ties).  ``score``: ``"softmax"`` over the experts, or
+    ``"sigmoid"`` of each logit alone.  The ``top_k`` largest of
+    ``score + bias`` are chosen (the lower index first among equals;
+    ``bias`` (E,) moves the choice and never the weights), each weighs
+    its own score, divided by the chosen ones' sum where
+    ``renormalise``, then times ``scale``."""
     logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
                         router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, int(top_k))
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"topk_router: unknown score {score!r}")
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(probs, int(top_k))
+    else:
+        _, top_e = jax.lax.top_k(probs + bias.astype(jnp.float32),
+                                 int(top_k))
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
     if renormalise:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if scale != 1.0:
+        top_p = top_p * jnp.float32(scale)
     return top_p, top_e.astype(jnp.int32)
 
 
@@ -209,31 +228,64 @@ def _grouped_dot(rows, w, sizes):
                interpret=kernels.interpret_mode())
 
 
+def _swiglu(rows, w_in, w_out):
+    """``(silu(g) * u) W_out`` with ``[g, u] = rows W_in``: inputs in the
+    weights' dtype, accumulated in float32."""
+    h = jnp.dot(rows.astype(w_in.dtype), w_in,
+                preferred_element_type=jnp.float32)
+    half = h.shape[-1] // 2
+    act = (jax.nn.silu(h[:, :half]) * h[:, half:]).astype(w_out.dtype)
+    return jnp.dot(act, w_out, preferred_element_type=jnp.float32)
+
+
 def routed_experts(x, router_w, w_in, w_out, valid=None, *, top_k: int,
-                   renormalise: bool = True):
+                   renormalise: bool = True, held=None,
+                   score: str = "softmax", bias=None, scale: float = 1.0,
+                   shared=None):
     """A sparse SwiGLU feed-forward: every token through its ``top_k``
-    experts, weighted and summed; no bias, no shared expert, no
-    capacity.  ``x``: (..., D); ``router_w``: (D, E); ``w_in``: (E, D,
-    2 F), each expert's gate over its up projection; ``w_out``: (E, F,
-    D); ``valid``: (...) bool, False for a padded token, which is
-    routed nowhere: it is in no expert's group, reads no weight, and
-    its output is zero.  Returns ``(y (..., D) float32, touched)``,
-    ``touched`` the int32 count of experts with a token.
+    experts, weighted and summed; no bias in a product, no capacity.
+    ``x``: (..., D); ``router_w``: (D, E); ``w_in``: (E', D, 2 F), each
+    held expert's gate over its up projection; ``w_out``: (E', F, D);
+    ``valid``: (...) bool, False for a padded token, which is routed
+    nowhere: it is in no expert's group, reads no weight, and its routed
+    output is zero.  Routing is ``topk_router``'s (``score``, ``bias``,
+    ``renormalise``, ``scale``) over all E experts.
+
+    ``held`` = (first, count): this device's share of an expert-parallel
+    layer, experts ``first .. first + count - 1`` (``w_in`` and
+    ``w_out`` hold those, E' = count; default: all E).  The weights are
+    renormalised over all ``top_k`` chosen before the share is taken,
+    so the shares' outputs add up to the whole layer's; an assignment to
+    an expert held elsewhere goes nowhere, as a padded token's does.
+    ``shared`` = (w_in (D, 2 F'), w_out (F', D)): a shared expert that
+    every token passes, added to the routed sum (scope ``moe/shared``).
+
+    Returns ``(y (..., D) float32, counts)``: ``counts`` the int32
+    count of held experts given a token (``touched``) or, with
+    ``held``, ``(2,)`` int32: that count and the assignments that
+    landed on held experts.
 
     The ``T x top_k`` assignments are sorted by expert (scope
     ``moe/dispatch``); the two products run grouped over the experts'
     runs of rows (``moe/experts``: inputs in the weights' dtype,
     accumulated in float32); the rows go back to token order, are
     weighted and summed (``moe/combine``).  Every assignment of every
-    valid token is in the sum under any imbalance, all tokens on one
-    expert included: a group is as long as its expert's tokens are
-    many."""
+    valid token to a held expert is in the sum under any imbalance, all
+    tokens on one expert included: a group is as long as its expert's
+    tokens are many."""
     lead, D = x.shape[:-1], x.shape[-1]
-    E, k = router_w.shape[-1], int(top_k)
+    k = int(top_k)
+    first, E = (0, router_w.shape[-1]) if held is None \
+        else (int(held[0]), int(held[1]))
     x2d = x.reshape(-1, D)
     with jax.named_scope("moe/route"):
         weights, experts = topk_router(x2d, router_w, k,
-                                       renormalise=renormalise)
+                                       renormalise=renormalise, score=score,
+                                       bias=bias, scale=scale)
+        if held is not None:
+            # an expert held elsewhere is nobody here: past every group
+            experts = experts - first
+            experts = jnp.where((experts >= 0) & (experts < E), experts, E)
         if valid is not None:
             # expert E is nobody: past every group
             experts = jnp.where(valid.reshape(-1, 1), experts, E)
@@ -259,4 +311,9 @@ def routed_experts(x, router_w, w_in, w_out, valid=None, *, top_k: int,
         y = jnp.sum(jnp.where((experts < E)[..., None],
                               out * weights[..., None], 0.0), axis=1)
         touched = jnp.sum(sizes > 0, dtype=jnp.int32)
-    return y.reshape(lead + (D,)), touched
+        counts = touched if held is None else jnp.stack(
+            [touched, jnp.sum(sizes, dtype=jnp.int32)])
+    if shared is not None:
+        with jax.named_scope("moe/shared"):
+            y = y + _swiglu(x2d, *shared)
+    return y.reshape(lead + (D,)), counts

@@ -681,3 +681,44 @@ def test_the_block_diffusion_programs_count_their_kernel_sites_on_v5e(
         with cached_call_sites() as seen:
             analysis.lowered_text(fn, *r._structs(bucket), r._param_structs)
         assert seen[0] == sites, bucket
+
+
+@pytest.mark.parametrize("bucket", [("decode", (49,)), ("prefill", (1, 256))],
+                         ids=["decode", "prefill-1x256"])
+def test_an_expert_share_beside_a_shared_expert_compiles_for_v5e(
+        bucket, topo, one_chip, on_tpu, chip_layouts, no_persistent_cache,
+        monkeypatch):
+    """The expert-share cell's decode step (49 slots) and one prefill
+    call (a row of 256) at the published widths — hidden 3,072, 48 query
+    over 8 key/value heads of 128, the gate, four norms a layer, 32 held
+    of 256 sigmoid-routed experts of 3,072 of which a token takes 4, a
+    shared expert, a window of 4,096 on a ring of 4,352 beside a full
+    table of 10,240 positions, bfloat16 — two of the five layers (the
+    dense sliding one and the routed full one), the model's own graph
+    through ``GenerateRunner``'s own program.  The held experts' products
+    are the grouped-matmul kernel over 32 groups (not 256), the donated
+    tables are aliased to the results, and the gate and the shared expert
+    lie under their scopes."""
+    from mxtpu import analysis
+    r = _described_runner(
+        monkeypatch, topo, one_chip, "trinity_large_preview.json", 5,
+        cut=slice(1, 3),
+        spec=lambda net: net.state_spec(48, 10240, kv_dtype="bfloat16",
+                                        max_chunk=256),
+        counters=lambda net: net.counter_spec(), prompt_buckets=(256,),
+        max_prefill_batch=1)
+    fn = r._prefill_pure() if bucket[0] == "prefill" else r._decode_pure()
+    structs = r._structs(bucket)
+    text, mem = analysis.compiled_artifact(
+        fn, *structs, r._param_structs, donate_argnums=(len(structs) - 1,))
+    tables = sum(r.state_bytes().values())
+    assert tables == 49 * 2 * 8 * (10240 + 4352) * 128 * 2
+    assert mem["alias_size_in_bytes"] == tables
+    assert mem["temp_size_in_bytes"] < 0.2e9, mem
+    grouped = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/moe/experts/" in line]
+    assert len(grouped) == 2 and "ragged-dot" not in text
+    assert re.search(r"bf16\[32,3072,6144\]", text)
+    for scope in ("attn_gate", "moe/shared", "moe/route", "rope",
+                  "window_attention"):
+        assert "/" + scope + "/" in text, scope

@@ -234,8 +234,8 @@ def test_the_block_layout_is_read_from_the_configuration(net, weights):
     assert np.abs(_eager_last(pre, seq, 9) - want).max() > 1e-2
     np.testing.assert_allclose(_eager_last(net, seq, 9), want, atol=TOL,
                                rtol=0)
-    with pytest.raises(mx.base.MXNetError, match="sandwich"):
-        HybridDecoderModel(97, 64, 128, kinds, 4, 4, layout="sandwich")
+    with pytest.raises(mx.base.MXNetError, match="'mid'"):
+        HybridDecoderModel(97, 64, 128, kinds, 4, 4, layout="mid")
 
 
 def test_qk_norm_is_over_all_heads_and_under_its_scope(net, weights):
